@@ -66,11 +66,13 @@ from .lockcheck import Violation
 MAX_VIOLATIONS = 200
 MAX_DONATION_RECORDS = 4096
 
-# the loggers jax_log_compiles raises compile records on (jax 0.4.x):
-# pxla emits "Compiling <name> with global shapes and types ...", and
-# dispatch emits the tracing/lowering chatter we suppress
+# the loggers jax_log_compiles raises compile records on: pxla emits
+# "Compiling jit(<name>) with global shapes and types ..." (the module
+# name; the api wrapper is stripped so counts key on the function's
+# own name), and dispatch emits the tracing/lowering chatter we
+# suppress
 _COMPILE_LOGGERS = ("jax._src.interpreters.pxla", "jax._src.dispatch")
-_COMPILING_RE = re.compile(r"^Compiling ([^\s]+)")
+_COMPILING_RE = re.compile(r"^Compiling (?:\w+\()?([^\s()]+)")
 _CHATTER_PREFIXES = ("Finished tracing + transforming",
                      "Finished jaxpr to MLIR",
                      "Finished XLA compilation")

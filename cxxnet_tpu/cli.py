@@ -1261,4 +1261,6 @@ class LearnTask:
 def main(argv: Optional[List[str]] = None) -> int:
     if argv is None:
         argv = sys.argv[1:]
+    from .parallel import place_compile_cache
+    place_compile_cache()
     return LearnTask().run(argv)

@@ -226,8 +226,31 @@ def _embed_prompt(params, p, emb, dt, toks, width):
     return h
 
 
-def _stack_prefill(st, lp, h, B, sl, e, dt, platform):
+def prefill_attend_impl(st, platform: str, sl: int, e: int) -> str:
+    """What a prefill over ``sl`` slots of stack ``st`` attends with on
+    ``platform``: ``pallas-flat`` (the zero-relayout flash kernel),
+    ``pallas`` (generic flash) or ``xla`` (the exact attend). The one
+    decision ``_stack_prefill`` acts on, public so an export can record
+    it per program (``programs[].attend_impl`` in the artifact meta)."""
+    from .ops import flash_attention as fa
+    nh = st.nhead
+    d = e // nh
+    impl = fa.resolve_impl(st.attn_impl, platform, sl)
+    # honor the stack's attn_flat=off escape hatch exactly like
+    # the training dispatch (layers._block_fn) does
+    if impl == "pallas" and getattr(st, "attn_flat", "auto") != "off" \
+            and (fa.supports_flat(sl, nh, d)
+                 or fa.flat_blocked_plan(sl, nh, d)):
+        return "pallas-flat"
+    return impl
+
+
+def _stack_prefill(st, lp, h, B, sl, e, dt, platform, mesh=None):
     """Prompt-wide pass that ALSO returns per-layer K/V.
+
+    ``mesh`` (a mesh-carrying export's): the flash kernels then run
+    per device on its own rows (``pallas_env.per_shard`` — XLA cannot
+    partition a Mosaic kernel).
 
     Mirrors _block_fn's dense block, UNROLLED over depth (the
     training recipe's own finding: full unroll beats the scan's
@@ -246,17 +269,14 @@ def _stack_prefill(st, lp, h, B, sl, e, dt, platform):
     the attend. ``blend`` passes the full S (its cache is indexed
     by absolute position)."""
     from .ops import flash_attention as fa
+    from .ops import pallas_env
     nh = st.nhead
     d = e // nh
 
-    impl = fa.resolve_impl(st.attn_impl, platform, sl)
-    # honor the stack's attn_flat=off escape hatch exactly like
-    # the training dispatch (layers._block_fn) does
-    flat = impl == "pallas" \
-        and getattr(st, "attn_flat", "auto") != "off" and bool(
-            fa.supports_flat(sl, nh, d)
-            or fa.flat_blocked_plan(sl, nh, d))
+    impl = prefill_attend_impl(st, platform, sl, e)
+    flat = impl == "pallas-flat"
     interp = platform != "tpu"
+    rows = pallas_env.rows_spec(mesh)
     nlayer = lp["wqkv"].shape[0]
     ks, vs = [], []
     for li in range(nlayer):
@@ -265,8 +285,10 @@ def _stack_prefill(st, lp, h, B, sl, e, dt, platform):
         qkv = jnp.einsum("bse,fe->bsf", x,
                          layer_p["wqkv"].astype(dt))
         if flat:
-            out4 = fa.flash_attention_flat(qkv, nh, causal=True,
-                                           interpret=interp)
+            out4 = pallas_env.per_shard(
+                mesh, lambda qkv: fa.flash_attention_flat(
+                    qkv, nh, causal=True, interpret=interp),
+                (rows,), rows)(qkv)
             kv4 = qkv.reshape(B, sl, 3, nh, d)
             k = kv4[:, :, 1].transpose(0, 2, 1, 3)
             v = kv4[:, :, 2].transpose(0, 2, 1, 3)
@@ -276,8 +298,10 @@ def _stack_prefill(st, lp, h, B, sl, e, dt, platform):
                 2, 0, 3, 1, 4)
             q, k, v = qkv4[0], qkv4[1], qkv4[2]
             if impl == "pallas":
-                out = fa.flash_attention(q, k, v, causal=True,
-                                         interpret=interp)
+                out = pallas_env.per_shard(
+                    mesh, lambda q, k, v: fa.flash_attention(
+                        q, k, v, causal=True, interpret=interp),
+                    (rows, rows, rows), rows)(q, k, v)
             else:
                 # f32 score accumulation + d^-0.5 scale, matching
                 # ops.ring_attention.attention (the exact attend)
@@ -412,7 +436,7 @@ def program_cost(net, p, kind: str, rows: int = 0, width: int = 0,
 
 
 def build_prefill(net, p, temperature: float, B: int, W: int,
-                  platform: str = "cpu"):
+                  platform: str = "cpu", mesh=None):
     """Build the jitted PREFILL half of the split decode:
 
         (params, toks (B, W) int32, lens (B,) int32, rng)
@@ -436,7 +460,7 @@ def build_prefill(net, p, temperature: float, B: int, W: int,
         ks, vs = [], []
         for si, st in zip(p["stacks"], stacks):
             h, k, v = _stack_prefill(st, params[si], h, B, W, e, dt,
-                                     platform)
+                                     platform, mesh)
             ks.append(k)
             vs.append(v)
         last = jnp.take_along_axis(
@@ -596,7 +620,7 @@ def build_tail_prefill(net, p, temperature: float, B: int, W: int,
 
 def build_step(net, p, temperature: float, B: int, P: int, Sl: int,
                block: int, platform: str = "cpu", steps: int = 1,
-               kv: str = "native", attend: str = "gather"):
+               kv: str = "native", attend: str = "gather", mesh=None):
     """Build the jitted DECODE STEP over a paged KV pool — ``steps``
     tokens per call (multi-step scheduling):
 
@@ -657,7 +681,14 @@ def build_step(net, p, temperature: float, B: int, P: int, Sl: int,
     Slots not bound to a request point their whole block table at pool
     block 0 — the reserved TRASH block (serve/kvpool.py never hands it
     out) — so their writes land somewhere harmless and their sampled
-    token is ignored by the engine."""
+    token is ignored by the engine.
+
+    ``mesh`` (a mesh-carrying export's): slots and the pool's block dim
+    are split over its ``data`` axis, each slice of pages serving its
+    own slots. The compiled Pallas attend then runs per device on its
+    slice (``pallas_env.per_shard`` — XLA cannot partition a Mosaic
+    kernel), with the block table rebased from pool-wide page ids to
+    the slice's own."""
     if kv not in ("native", "int8"):
         raise ValueError("kv must be 'native' or 'int8', got %r" % kv)
     if attend not in ("gather", "fused"):
@@ -674,10 +705,30 @@ def build_step(net, p, temperature: float, B: int, P: int, Sl: int,
     dt = net.compute_dtype
     e = emb.param.num_hidden
     nh, d = uniform_heads_or_reason(net, p)
+    npools = 4 if kv == "int8" else 2
+    impl = "xla"                       # the gather attend is plain XLA
     if attend == "fused":
         from .ops import paged_attend as pga
-        impl = "pallas" if platform == "tpu" else "xla"
-    npools = 4 if kv == "int8" else 2
+        from .ops import pallas_env
+        impl, interp = pga.resolve_impl(None, platform != "tpu")
+        rows = pallas_env.rows_spec(mesh)
+        # only the Mosaic kernel needs the per-shard form: XLA
+        # partitions its own gather/dot form (and keeps the bitwise
+        # guarantee the CPU tests pin)
+        kmesh = mesh if impl == "pallas" else None
+        split = kmesh is not None and len(rows) > 0
+
+        def attend_fn(fn, layer):
+            """``fn(q, *pools, bt, bias, layer, ...)`` per shard."""
+            def local(q, bt, bias, *pools):
+                if split:
+                    # pool-wide page ids -> this slice's own
+                    bt = bt - jax.lax.axis_index(rows[0]) \
+                        * pools[0].shape[0]
+                return fn(q, *pools, bt, bias, layer, attend_slots=Sl,
+                          impl=impl, interpret=interp)
+            return pallas_env.per_shard(
+                kmesh, local, (rows,) * (3 + npools), rows)
 
     def one(params, pools, bt, lens, stepv, last, rng):
         pos = lens + stepv                 # absolute embed position
@@ -727,10 +778,8 @@ def build_step(net, p, temperature: float, B: int, P: int, Sl: int,
                     pool_vs = pool_vs.at[b_ids, li, :, offs].set(
                         vs_new)
                     pools = (pool_k, pool_v, pool_ks, pool_vs)
-                    out = pga.paged_attend_q8(
-                        q, pool_k, pool_v, pool_ks, pool_vs, bt, bias,
-                        li, attend_slots=Sl, impl=impl,
-                        interpret=platform != "tpu")
+                    out = attend_fn(pga.paged_attend_q8, li)(
+                        q, bt, bias, pool_k, pool_v, pool_ks, pool_vs)
                 else:
                     pool_k, pool_v = pools
                     pool_k = pool_k.at[b_ids, li, :, offs, :].set(
@@ -739,10 +788,8 @@ def build_step(net, p, temperature: float, B: int, P: int, Sl: int,
                         v_new.astype(pool_v.dtype))
                     pools = (pool_k, pool_v)
                     if attend == "fused":
-                        out = pga.paged_attend(
-                            q, pool_k, pool_v, bt, bias, li,
-                            attend_slots=Sl, impl=impl,
-                            interpret=platform != "tpu")
+                        out = attend_fn(pga.paged_attend, li)(
+                            q, bt, bias, pool_k, pool_v)
                     else:
                         k_c = pool_k[bt, li].transpose(0, 2, 1, 3, 4) \
                             .reshape(B, nh, Sp, d)[:, :, :Sl]
@@ -783,7 +830,11 @@ def build_step(net, p, temperature: float, B: int, P: int, Sl: int,
         B, int(steps),
         "_fused" if attend == "fused" else "",
         "_q8" if kv == "int8" else "")
-    return jax.jit(step)
+    fn = jax.jit(step)
+    # what this program attends with, for the export to record
+    # (``rungs[].attend_impl`` in the artifact meta)
+    fn.attend_impl = impl
+    return fn
 
 
 def build(net, p, max_new: int, temperature: float, B: int, S: int,

@@ -47,8 +47,12 @@ from ..obs import trace as _trace
 def _decode_task(idx, label, buf):
     """Decode one encoded image object into a DataInst — the unit of
     work shipped to pool workers. Top-level (picklable) so the process
-    mode can reference it; imports stay inside so spawned workers load
-    only numpy + cv2, not jax. The span puts each decode on its worker
+    mode can reference it; imports stay inside so a spawned worker
+    loads numpy, cv2 and this package's jax-free modules (config,
+    graph, io, analysis, obs, metrics) and NEVER jax: a chip belongs to
+    one process, and a worker that initialised a backend would take it
+    from its parent or hang (tests/test_prefetch.py pins the import
+    set). The span puts each decode on its worker
     thread's trace lane (a spawned process has no tracer installed, so
     there it is the disabled one-branch path)."""
     from .image import DataInst, _decode_image

@@ -197,12 +197,15 @@ class ApplyContext:
     # layer by model.py; mirrors Network.analytic_model_flops skip_dx)
     needs_input_grad: bool = True
 
-    def add_pallas_flops(self, kernel: str, fwd: float,
-                         bwd: float = 0.0) -> None:
+    def add_pallas_flops(self, kernel: str, fwd: float, bwd: float,
+                         interpret: bool) -> None:
         """Record one Pallas kernel's analytic (fwd, bwd) hardware flops
-        for this trace. ``bwd`` should be 0 outside training traces."""
+        for this trace. ``bwd`` should be 0 outside training traces.
+        ``interpret`` is the flag the caller hands that kernel, so the
+        record says which form the traced program holds."""
         self.pallas_flops.append({"kernel": kernel, "fwd": float(fwd),
-                                  "bwd": float(bwd)})
+                                  "bwd": float(bwd),
+                                  "interpret": bool(interpret)})
 
 
 def _mat(x: jnp.ndarray) -> jnp.ndarray:
@@ -1117,12 +1120,13 @@ class ConvolutionLayer(Layer):
             khw = kernel.shape[2] * kernel.shape[3]
             fhw = 2.0 * n * oh * ow * co * kernel.shape[1] * khw
             bwd_mult = 2.0 if ctx.needs_input_grad else 1.0
+            interp = ctx.platform != "tpu"
             ctx.add_pallas_flops("conv_pallas", fhw,
-                                 bwd_mult * fhw if ctx.train else 0.0)
+                                 bwd_mult * fhw if ctx.train else 0.0,
+                                 interp)
             out = conv_pallas(x, kernel.astype(ctx.compute_dtype),
                               stride=stride, pad=(pad_y, pad_x),
-                              groups=g,
-                              interpret=ctx.platform != "tpu"
+                              groups=g, interpret=interp
                               ).astype(jnp.float32)
         elif impl == "split" and g > 1:
             # per-group convs + channel concat: same math as
@@ -1443,11 +1447,11 @@ class LRNLayer(Layer):
             # visibility, negligible against any MXU term
             elems = float(np.prod(x.shape))
             fhw = elems * (2.0 * self.nsize + 20.0)
+            interp = ctx.platform != "tpu"
             ctx.add_pallas_flops("lrn_pallas", fhw,
-                                 2.0 * fhw if ctx.train else 0.0)
+                                 2.0 * fhw if ctx.train else 0.0, interp)
             return [lrn_pallas(x, self.nsize, self.alpha, self.beta,
-                               self.knorm,
-                               interpret=ctx.platform != "tpu")]
+                               self.knorm, interpret=interp)]
         salpha = self.alpha / self.nsize
         lo = self.nsize // 2
         hi = self.nsize - 1 - lo
@@ -1769,11 +1773,12 @@ class AttentionLayer(Layer):
         nh, d = self.nhead, e // self.nhead
         dt = ctx.compute_dtype
         impl = fa.resolve_impl(self.attn_impl, ctx.platform, s)
+        interp = ctx.platform != "tpu"
 
         def record_flash():
             fhw, bhw = fa.analytic_flops(b, nh, s, d, bool(self.causal))
             ctx.add_pallas_flops("flash_attention", fhw,
-                                 bhw if ctx.train else 0.0)
+                                 bhw if ctx.train else 0.0, interp)
         x = inputs[0].reshape(b, s, e).astype(dt)
         qkv = jnp.einsum("bse,fe->bsf", x, params["wqkv"].astype(dt))
         qkv = qkv.reshape(b, s, 3, nh, d).transpose(2, 0, 3, 1, 4)
@@ -1788,7 +1793,7 @@ class AttentionLayer(Layer):
                 out = ulysses.sharded_ulysses(
                     mesh, q, k, v, seq_axis=axis,
                     causal=bool(self.causal), impl=impl,
-                    interpret=ctx.platform != "tpu")
+                    interpret=interp)
             elif self.attn_impl == "pallas":
                 raise ValueError(
                     "attention: attn_impl=pallas composes with "
@@ -1802,10 +1807,15 @@ class AttentionLayer(Layer):
                                            causal=bool(self.causal))
         elif impl == "pallas":
             # flash attention: VMEM-blocked online softmax, O(s*d) memory
-            # (cxxnet_tpu/ops/flash_attention.py)
+            # (cxxnet_tpu/ops/flash_attention.py); on a mesh each device
+            # attends its own batch rows (pallas_env.per_shard)
+            from .ops import pallas_env
             record_flash()
-            out = fa.flash_attention(q, k, v, bool(self.causal),
-                                     interpret=ctx.platform != "tpu")
+            rows = pallas_env.rows_spec(mesh)
+            out = pallas_env.per_shard(
+                mesh, lambda q, k, v: fa.flash_attention(
+                    q, k, v, bool(self.causal), interpret=interp),
+                (rows, rows, rows), rows)(q, k, v)
         else:
             out = ra.attention(q, k, v, causal=bool(self.causal))
         out = out.transpose(0, 2, 1, 3).reshape(b, s, e)
@@ -1957,8 +1967,13 @@ class TransformerStackLayer(Layer):
 
     def _block_fn(self, dt, interpret=True, mesh=None, seq_axis=None,
                   use_flash=False):
+        from .ops import pallas_env
         from .ops import ring_attention as ra
         nh, causal = self.nhead, bool(self.causal)
+        # the local flash kernels run per device on its own batch rows
+        # (a Mosaic kernel cannot be partitioned by XLA); mesh is None
+        # inside the pipeline's own shard_map
+        rows = pallas_env.rows_spec(mesh)
         seq_sharded = (mesh is not None and seq_axis is not None
                        and mesh.shape.get(seq_axis, 1) > 1)
         # under seq sharding only an EXPLICIT pallas selects
@@ -2013,8 +2028,10 @@ class TransformerStackLayer(Layer):
                     # (3, b, h, s, d) relayouts on either pass.
                     # Single-block s takes the fused backward; longer
                     # s the r5 blocked flat kernels (flat_blocked_plan)
-                    att = fa.flash_attention_flat(
-                        qkv, nh, causal, interpret=interpret)
+                    att = pallas_env.per_shard(
+                        mesh, lambda qkv: fa.flash_attention_flat(
+                            qkv, nh, causal, interpret=interpret),
+                        (rows,), rows)(qkv)
                     h = h + jnp.einsum("bse,fe->bsf", att,
                                        lp["wo"].astype(dt))
                     x = rmsnorm(h, lp["norm2"] if moe else None)
@@ -2037,8 +2054,10 @@ class TransformerStackLayer(Layer):
             elif use_flash:
                 # VMEM-blocked online-softmax kernel: O(s*d) memory
                 from .ops import flash_attention as fa
-                att = fa.flash_attention(qkv[0], qkv[1], qkv[2], causal,
-                                         interpret=interpret)
+                att = pallas_env.per_shard(
+                    mesh, lambda q, k, v: fa.flash_attention(
+                        q, k, v, causal, interpret=interpret),
+                    (rows, rows, rows), rows)(qkv[0], qkv[1], qkv[2])
             else:
                 att = ra.attention(qkv[0], qkv[1], qkv[2], causal=causal)
             att = att.transpose(0, 2, 1, 3).reshape(b, s, e)
@@ -2085,6 +2104,7 @@ class TransformerStackLayer(Layer):
         from .ops import flash_attention as fa
         use_flash = fa.resolve_impl(self.attn_impl, ctx.platform,
                                     s) == "pallas"
+        interp = ctx.platform != "tpu"
         # analytic hardware flops of the flash kernels XLA cannot count
         # (opaque custom_call AND a scan body it would count only once):
         # flash runs in every block unless seq sharding fell back to
@@ -2100,10 +2120,10 @@ class TransformerStackLayer(Layer):
             bwd_hw = bhw + (fhw if self.remat else 0.0)
             ctx.add_pallas_flops(
                 "flash_attention", fhw * self.nlayer,
-                bwd_hw * self.nlayer if ctx.train else 0.0)
+                bwd_hw * self.nlayer if ctx.train else 0.0, interp)
         # the pipeline path reshards x to P(data) in its shard_map
         # in_specs, so only the scan path runs seq-parallel attends
-        block = self._block_fn(dt, interpret=ctx.platform != "tpu",
+        block = self._block_fn(dt, interpret=interp,
                                mesh=None if pipe > 1 else mesh,
                                seq_axis=getattr(ctx, "seq_axis", None),
                                use_flash=use_flash)

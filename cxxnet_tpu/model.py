@@ -18,6 +18,7 @@ Semantics preserved from the reference:
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -48,7 +49,7 @@ class Network:
         self.modules: List[L.Layer] = []
         self.node_shapes: List[Optional[Tuple[int, ...]]] = (
             [None] * net_cfg.num_nodes)
-        self.mesh = None       # set by the trainer for sequence parallelism
+        self.mesh = None       # the trainer's mesh, when it spans > 1 device
         self.seq_axis: Optional[str] = None
         # the jit target platform, set by the trainer from its devices;
         # gates compiled-vs-interpreted Pallas kernels
@@ -150,6 +151,20 @@ class Network:
         return params[li]
 
     # ------------------------------------------------------------------
+    @contextlib.contextmanager
+    def traced_on(self, mesh):
+        """Within the context ``apply`` traces for ``mesh`` instead of
+        the trainer's: an export's own, None for a single-device
+        artifact. The layers build their shard_maps (``pallas_env.
+        per_shard``, sequence parallelism) from the mesh they are
+        handed, and a program traced over the training mesh can only
+        be called on that many devices."""
+        prev, self.mesh = self.mesh, mesh
+        try:
+            yield
+        finally:
+            self.mesh = prev
+
     def apply(self, params, data: jnp.ndarray,
               extra_data: Sequence[jnp.ndarray] = (),
               labels: Optional[List[jnp.ndarray]] = None,

@@ -17,6 +17,7 @@ from __future__ import annotations
 import ctypes
 import os
 import subprocess
+import sys
 import threading
 from typing import List, Optional
 
@@ -89,24 +90,36 @@ def _build() -> bool:
         return False
 
 
+def _load():
+    """-> (lib or None, why): the one attempt ``get_lib`` makes."""
+    if os.environ.get("CXXNET_TPU_NO_NATIVE"):
+        return None, "CXXNET_TPU_NO_NATIVE is set"
+    if not os.path.exists(_LIB_PATH) and not _build():
+        return None, "%s is missing and `make -C native` failed" \
+            % _LIB_PATH
+    try:
+        lib = ctypes.CDLL(_LIB_PATH)
+        _configure(lib)
+        return lib, _LIB_PATH
+    except OSError as e:
+        return None, "loading %s failed: %s" % (_LIB_PATH, e)
+
+
 def get_lib():
     """The loaded native library, building it on first use; None if
-    unavailable (no toolchain / build failure — callers fall back)."""
+    unavailable (no toolchain / build failure — callers fall back to
+    the Python readers). Says ONCE on stderr which loader is in use,
+    so a slow feed is never silently the fallback."""
     global _lib, _tried
     with _lock:
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        if os.environ.get("CXXNET_TPU_NO_NATIVE"):
-            return None
-        if not os.path.exists(_LIB_PATH) and not _build():
-            return None
-        try:
-            lib = ctypes.CDLL(_LIB_PATH)
-            _configure(lib)
-            _lib = lib
-        except OSError:
-            _lib = None
+        _lib, why = _load()
+        sys.stderr.write(
+            "cxxnet_tpu.native: %s\n"
+            % ("native loader in use (%s)" % why if _lib is not None
+               else "Python loader in use (%s)" % why))
         return _lib
 
 

@@ -36,7 +36,7 @@ cost model (``serving.py`` exports record analytic flops+bytes per
 program into artifact meta; engines register their callee's table at
 init). ``summary()`` then reports, per program shape, the window's
 wall-ms median/mean, achieved FLOP/s, MFU against
-:func:`calibrated_peak`, and bytes/s — the roofline unit the ROADMAP
+:func:`device_peak`, and bytes/s — the roofline unit the ROADMAP
 autoscaling item needs beside attrib's top_waste. Events whose shape
 resolves no cost entry still count (wall only) and surface in the
 explicit ``uncosted`` list, never silently.
@@ -44,13 +44,13 @@ explicit ``uncosted`` list, never silently.
 MFU basis and its honest caveats: the cost model counts
 matmul-dominant MODEL flops (the ``Layer.analytic_flops`` /
 PaLM-appendix definition — no flash recompute, causal attention at
-the useful half), and the peak is a MEASURED large-matmul rate
-(``CXXNET_DEVICE_PEAK_FLOPS`` overrides), not a datasheet number. On
-a shared CPU rig both sides wobble with tenant load, so MFU here is a
-relative regression unit, not an absolute hardware-utilization claim
-(docs/observability.md). Peak calibration jit-compiles one matmul:
-call :func:`calibrated_peak` BEFORE arming the jitcheck sentinel;
-``summary()`` itself never compiles (it reads the cached peak only).
+the useful half), the peak is the device's PUBLISHED bf16 rate
+(``parallel.DEVICE_PEAKS``, keyed by ``device_kind``;
+``CXXNET_DEVICE_PEAK_FLOPS`` overrides), and the wall is HOST wall
+around a dispatch, not device time. A CPU has no table entry, so a CPU
+run reports no MFU at all (docs/observability.md). ``summary()``
+never initialises a backend (it reads the cached peak only): call
+:func:`device_peak` once at start-up.
 
 Module seam (the obs/attrib.py pattern): ``enable()`` installs a
 process-global profiler (inheriting the module-level cost table, so
@@ -156,7 +156,7 @@ class ProgramProfiler:
         """Per-phase lifetime totals plus the ring window's
         per-program view: a program is one (site, phase, rung, bucket,
         width, shard) shape — wall-ms median/mean, flops joined from
-        the cost table, achieved FLOP/s, MFU vs the calibrated peak,
+        the cost table, achieved FLOP/s, MFU vs the device peak,
         bytes/s. ``top`` bounds the program table (ranked by summed
         wall), ``bottom`` the worst-MFU list. Never measures the peak
         itself (see module docstring) — reads the cached value only."""
@@ -166,7 +166,7 @@ class ProgramProfiler:
             window = list(self._ring)
             recorded = self.recorded
             costs = dict(self._costs)
-        peak = calibrated_peak(measure=False)
+        peak = device_peak(lookup=False)
 
         def mfu_of(flops: float, wall_ms: float) -> Optional[float]:
             if not peak or wall_ms <= 0 or flops <= 0:
@@ -291,7 +291,7 @@ def enable(capacity: int = 8192) -> ProgramProfiler:
 def disable() -> None:
     """Drop the global profiler: dispatch sites go back to the single
     ``is None`` branch, exactly the off cost. The module-level cost
-    table and calibrated peak survive for the next enable()."""
+    table and device peak survive for the next enable()."""
     global _active
     _active = None
 
@@ -329,24 +329,23 @@ def clear_costs() -> None:
 
 
 # ----------------------------------------------------------------------
-# device peak calibration (the MFU denominator)
+# device peak (the MFU denominator)
 
 def set_peak(flops: Optional[float]) -> None:
     """Pin the device peak FLOP/s (None un-pins; the next
-    ``calibrated_peak(measure=True)`` re-measures)."""
+    ``device_peak(lookup=True)`` reads the table again)."""
     global _PEAK
     _PEAK = None if flops is None else float(flops)
 
 
-def calibrated_peak(measure: bool = True) -> Optional[float]:
+def device_peak(lookup: bool = True) -> Optional[float]:
     """The MFU denominator: ``CXXNET_DEVICE_PEAK_FLOPS`` env override,
-    else a cached one-shot measured large-matmul rate (f32, best of
-    3) — a MEASURED practical peak, not a datasheet number, which on a
-    shared CPU rig makes MFU a relative regression unit rather than an
-    absolute utilization claim. ``measure=False`` never compiles
-    (returns None until something calibrated) — the scrape-safe read
-    ``summary()`` uses, because the measurement jit-compiles one
-    matmul and must happen before the jitcheck sentinel arms."""
+    else the device's PUBLISHED bf16 peak from the one table keyed by
+    ``device_kind`` (``parallel.DEVICE_PEAKS``). A CPU has no entry by
+    design and gets no MFU (None); an accelerator kind the table lacks
+    raises. ``lookup=False`` never touches the backend (returns None
+    until something looked the peak up) — the scrape-safe read
+    ``summary()`` uses."""
     global _PEAK
     if _PEAK is not None:
         return _PEAK
@@ -357,32 +356,12 @@ def calibrated_peak(measure: bool = True) -> Optional[float]:
             return _PEAK
         except ValueError:
             pass
-    if not measure:
+    if not lookup:
         return None
-    _PEAK = _measure_peak()
+    from ..parallel import device_peaks
+    peaks = device_peaks()
+    _PEAK = peaks["bf16_flops_per_s"] if peaks else None
     return _PEAK
-
-
-def _measure_peak(n: int = 512, trials: int = 3) -> Optional[float]:
-    try:
-        import jax
-        import jax.numpy as jnp
-
-        x = jnp.ones((n, n), jnp.float32)
-        f = jax.jit(lambda a, b: a @ b)
-        f(x, x).block_until_ready()           # compile outside clocks
-        best = None
-        for _ in range(trials):
-            t0 = time.perf_counter()
-            f(x, x).block_until_ready()
-            dt = time.perf_counter() - t0
-            if best is None or dt < best:
-                best = dt
-        if not best or best <= 0:
-            return None
-        return 2.0 * n * n * n / best
-    except Exception:
-        return None
 
 
 # ----------------------------------------------------------------------
@@ -415,11 +394,11 @@ def bind_registry(registry, labels: Optional[dict] = None):
     g_mfu = registry.gauge(
         "cxxnet_profile_mfu",
         "model flops utilization per phase (cost-model flops over "
-        "costed wall, vs the calibrated device peak)",
+        "costed wall, vs the published device peak)",
         names + ("phase",))
     g_peak = registry.gauge(
         "cxxnet_profile_peak_flops",
-        "calibrated device peak FLOP/s (the MFU denominator)", names)
+        "published device peak FLOP/s (the MFU denominator)", names)
 
     def pull():
         a = _active

@@ -7,12 +7,10 @@ addresses logical cache slot ``j`` through its block table as page
 ``bt[s, j // bs]`` offset ``j % bs``. Until r12 the step program
 attended by MATERIALIZING a gathered contiguous cache per layer
 (``pool[bt, li].transpose(...).reshape(...)[:, :, :Sl]``) and running
-the slot attend on it — on TPU that is the named next bottleneck
-(ROADMAP: the XLA lowering moves the cache at ~31% of HBM rate, and
-the gather copy doubles the traffic the ~87%-streaming step pays), and
-it is the reason the BENCH_r05 fused kernels could not serve the
-continuous scheduler: they read (B, nh, Sl, d) caches, not block
-tables.
+the slot attend on it — the gather copy doubles the traffic a
+cache-streaming step pays, and the contiguous fused kernels
+(ops/decode_attend.py) could not serve the continuous scheduler: they
+read (B, nh, Sl, d) caches, not block tables.
 
 This module is the kernel family that reads the block table directly:
 
@@ -42,13 +40,14 @@ absmax scale planes riding beside the K/V pages (the ``_quant8``
 scheme from generate.py, scattered at prefill by
 ``serving.scatter_prefill_kv`` and written per token by the step
 program): the scales factor out of both d-contractions, so dequant is
-algebraic and only the streamed bytes change — the int8 win the slot
-layout already proved (BENCH_r05 int8 decode 23.8k tok/s) finally fed
-by the paged path.
+algebraic and only the streamed bytes change.
 
 Tested on CPU through the ``pallas_env`` interpret seam
 (tests/test_paged_attend.py: trash-page, partial-last-page and
-non-contiguous-page-order edge cases).
+non-contiguous-page-order edge cases), compiled for a described v5e at
+gpt2_small widths (tests/test_chip_compile.py), and run on the chip
+against the XLA form by ``chip_smoke.py``. Its time and roofline share
+are not measured.
 """
 
 from __future__ import annotations
@@ -69,10 +68,13 @@ def _interpret() -> bool:
     return pallas_env.interpret()
 
 
-def _resolve_impl(impl, interpret):
-    """"pallas" | "xla"; None picks pallas only where it compiles
-    natively (the interpret seam says the jit targets TPU) — the
-    interpreted kernel is a test vehicle, not a serving path."""
+def resolve_impl(impl=None, interpret=None):
+    """-> (impl, interpret): "pallas" | "xla"; None picks pallas only
+    where it compiles natively (the interpret seam says the jit targets
+    TPU) — the interpreted kernel is a test vehicle, not a serving
+    path. Public so ``generate.build_step`` resolves once per program,
+    hands the answer to every attend in it and records it on the
+    program (``rungs[].attend_impl`` in the artifact meta)."""
     if interpret is None:
         interpret = _interpret()
     if impl is None:
@@ -252,7 +254,7 @@ def paged_attend(q, pool_k, pool_v, bt, bias, layer, attend_slots=None,
     never enters the softmax — callers MUST mask those positions in
     ``bias`` too, which is what keeps the pallas and xla forms
     answer-equivalent."""
-    impl, interpret = _resolve_impl(impl, interpret)
+    impl, interpret = resolve_impl(impl, interpret)
     B, nh, d, bs, nblk = _check_shapes(q, pool_k, pool_v, bt, bias,
                                        layer)
     if scale is None:
@@ -278,7 +280,7 @@ def paged_attend_q8(q, pool_k, pool_v, pool_ks, pool_vs, bt, bias,
     K/V pages: K's scale multiplies the scores, V's folds into the
     softmax weights (the decode_attend_q8 algebra — scales factor out
     of both d-contractions), so only the streamed K/V bytes change."""
-    impl, interpret = _resolve_impl(impl, interpret)
+    impl, interpret = resolve_impl(impl, interpret)
     B, nh, d, bs, nblk = _check_shapes(q, pool_k, pool_v, bt, bias,
                                        layer)
     if pool_ks.shape != pool_k.shape[:4] \

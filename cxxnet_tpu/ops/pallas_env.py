@@ -40,15 +40,32 @@ def interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def shard_map_nocheck_kwargs(shard_map_fn) -> dict:
-    """Kwargs that disable shard_map's replication checker, across jax
-    versions (check_vma in new jax, check_rep in older). pallas_call
-    outputs carry no varying-mesh-axes annotation, so any shard_map body
-    that may run a Pallas kernel needs the checker off."""
-    import inspect
-    params = inspect.signature(shard_map_fn).parameters
-    if "check_vma" in params:
-        return {"check_vma": False}
-    if "check_rep" in params:
-        return {"check_rep": False}
-    return {}
+# shard_map kwargs that turn its replication checker off: pallas_call
+# outputs carry no varying-mesh-axes annotation, so any shard_map body
+# that may run a Pallas kernel needs the checker off.
+SHARD_MAP_NOCHECK = {"check_vma": False}
+
+
+def per_shard(mesh, fn, in_specs, out_specs):
+    """``fn`` run on each device's shard: the form a Pallas kernel must
+    take inside a program that spans several devices. XLA partitions
+    its own ops across a mesh but refuses a Mosaic kernel ("Mosaic
+    kernels cannot be automatically partitioned. Please wrap the call
+    in a shard_map"), and only the TPU compiler says so — interpret
+    mode and the XLA twins on a CPU mesh partition fine. ``mesh`` None
+    or of one device returns ``fn`` itself."""
+    if mesh is None or mesh.size == 1:
+        return fn
+    from jax import shard_map
+    return shard_map(fn, mesh=mesh, in_specs=in_specs,
+                     out_specs=out_specs, **SHARD_MAP_NOCHECK)
+
+
+def rows_spec(mesh, axis: str = "data"):
+    """PartitionSpec of an array whose leading dim is rows/batch split
+    over ``axis`` (replicated over every other mesh axis); fully
+    replicated where the mesh has no such axis."""
+    from jax.sharding import PartitionSpec as P
+    if mesh is not None and mesh.shape.get(axis, 1) > 1:
+        return P(axis)
+    return P()

@@ -98,15 +98,12 @@ def sharded_pipeline(mesh: Mesh, block_fn: Callable, stacked_params, x,
     ``contains_pallas``: the block runs a Pallas kernel (e.g. flash
     attention), whose outputs the shard_map replication checker cannot
     annotate — the checker is turned off for such blocks."""
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     kw = {}
     if contains_pallas:
-        from .pallas_env import shard_map_nocheck_kwargs
-        kw = shard_map_nocheck_kwargs(shard_map)
+        from .pallas_env import SHARD_MAP_NOCHECK
+        kw = SHARD_MAP_NOCHECK
     data = data_axis if data_axis in mesh.shape else None
     pspec = jax.tree.map(lambda _: P(pipe_axis), stacked_params)
     xspec = P(data)

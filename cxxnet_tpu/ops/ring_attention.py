@@ -146,10 +146,7 @@ def sharded_attention(mesh: Mesh, q, k, v, seq_axis: str = "seq",
                       causal: bool = False) -> jnp.ndarray:
     """shard_map ring_attention over ``mesh``'s seq axis; batch stays on
     the data axis if present. Inputs are global (b, h, s, d) arrays."""
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     data = "data" if "data" in mesh.shape else None
     spec = P(data, None, seq_axis, None)

@@ -70,10 +70,7 @@ def sharded_ulysses(mesh: Mesh, q, k, v, seq_axis: str = "seq",
                     interpret=None) -> jnp.ndarray:
     """shard_map ulysses_attention over ``mesh``'s seq axis; global
     (b, h, s, d) in and out (mirror of ring_attention.sharded_attention)."""
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     data = "data" if "data" in mesh.shape else None
     spec = P(data, None, seq_axis, None)
@@ -82,7 +79,7 @@ def sharded_ulysses(mesh: Mesh, q, k, v, seq_axis: str = "seq",
                            interpret=interpret)
     kw = {}
     if impl == "pallas":
-        from .pallas_env import shard_map_nocheck_kwargs
-        kw = shard_map_nocheck_kwargs(shard_map)
+        from .pallas_env import SHARD_MAP_NOCHECK
+        kw = SHARD_MAP_NOCHECK
     return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
                      out_specs=spec, **kw)(q, k, v)

@@ -55,8 +55,10 @@ slot-occupancy gauge, and dummy-slot-step counters (serve/stats.py).
 from __future__ import annotations
 
 import queue as _qmod
+import sys
 import threading
 import time
+import traceback
 from typing import List, Optional, Sequence
 
 import numpy as np
@@ -1265,17 +1267,33 @@ class ContinuousDecodeEngine:
                           lps, T, s if self.dp > 1 else -1, wall)
 
     def _loop(self) -> None:
-        while True:
+        """Scheduler thread body. Device calls fail their own requests
+        (``_prefill_dispatch`` / ``_decode_step``); anything ELSE that
+        raises here is a scheduler bug, and the thread is the boundary:
+        it records the traceback, closes admission and fails every
+        request in flight with the error — a dead scheduler must reach
+        its clients as errors, not as requests that wait out their
+        timeouts."""
+        try:
+            while True:
+                with self._cond:
+                    while not self._closed and not self._q \
+                            and not self._ready and self._nlive == 0:
+                        self._cond.wait(0.05)
+                    if self._closed:
+                        return
+                if self._q and (self.prefill_split
+                                or self._nlive == 0):
+                    self._prefill_dispatch()
+                if self._nlive or self._ready:
+                    self._decode_step()
+        except Exception as e:
+            sys.stderr.write("serve-continuous scheduler died:\n%s"
+                             % traceback.format_exc())
             with self._cond:
-                while not self._closed and not self._q \
-                        and not self._ready and self._nlive == 0:
-                    self._cond.wait(0.05)
-                if self._closed:
-                    return
-            if self._q and (self.prefill_split or self._nlive == 0):
-                self._prefill_dispatch()
-            if self._nlive or self._ready:
-                self._decode_step()
+                self._closed = True
+                self._cond.notify_all()
+            self._fail_everything(e)
 
     # ------------------------------------------------------------------
     def drain(self, timeout: float = 10.0) -> int:
@@ -1309,23 +1327,20 @@ class ContinuousDecodeEngine:
                            {"failed": n})
         return n
 
-    def close(self, timeout: float = 10.0) -> None:
-        with self._cond:
-            self._closed = True
-            self._cond.notify_all()
-        if self._started:
-            self._thread.join(timeout)
+    def _fail_everything(self, error: Exception) -> None:
+        """Fail every queued, parked, bound and otherwise live request
+        with ``error`` and give their pages back. Runs with the
+        scheduler thread gone (``close`` after the join, or the thread
+        itself on its way out)."""
         with self._cond:
             while self._q:
                 row = self._q.popleft()
                 self._release_row(row)
-                self._finish_req(row.req,
-                                 error=RuntimeError("engine closed"))
+                self._finish_req(row.req, error=error)
         while self._ready:
             row = self._ready.popleft()
             self._release_row(row)
-            self._finish_req(row.req,
-                             error=RuntimeError("engine closed"))
+            self._finish_req(row.req, error=error)
         for i, row in enumerate(self._slots):
             # rows a drain failed while they sat in a lane: the
             # scheduler thread is gone, so their pages reap here
@@ -1333,12 +1348,19 @@ class ContinuousDecodeEngine:
                 self._release_row(row)
                 self._slots[i] = None
                 self._nlive -= 1
-                self._finish_req(row.req,
-                                 error=RuntimeError("engine closed"))
+                self._finish_req(row.req, error=error)
         with self._live_lock:
             leftovers = list(self._live)
         for req in leftovers:
-            self._finish_req(req, error=RuntimeError("engine closed"))
+            self._finish_req(req, error=error)
+
+    def close(self, timeout: float = 10.0) -> None:
+        with self._cond:
+            self._closed = True
+            self._cond.notify_all()
+        if self._started:
+            self._thread.join(timeout)
+        self._fail_everything(RuntimeError("engine closed"))
         if self.prefix is not None:
             # every row reference is gone; the trie's own page
             # references go back too, so a drained engine leaves the

@@ -148,16 +148,11 @@ def make_serving_mesh(data_parallel: int = 1, model_parallel: int = 1,
     """Build an export/serving mesh over the first
     ``data_parallel * model_parallel`` local devices (the CLI's
     ``export_mesh`` knob and the bench legs go through here)."""
-    import jax
-
     from . import parallel
     n = int(data_parallel) * int(model_parallel)
     if n < 1:
         raise ValueError("mesh needs at least one device")
-    try:
-        devs = jax.devices(platform) if platform else jax.devices()
-    except RuntimeError:
-        devs = jax.devices()
+    devs = parallel.platform_devices(platform)
     if len(devs) < n:
         raise MeshMismatchError(
             "a %dx%d (data x model) mesh needs %d device(s); this "
@@ -173,17 +168,12 @@ def resolve_mesh(mmeta):
     the expected vs available topology. Called at artifact LOAD — a
     topology that cannot carry the mesh must fail attributably before
     the first dispatch, not as an XLA device-count error inside it."""
-    import jax
-
     from . import parallel
     axes = [str(a) for a in mmeta["axes"]]
     shape = [int(x) for x in mmeta["shape"]]
     need = int(np.prod(shape))
     platform = mmeta.get("platform")
-    try:
-        devs = jax.devices(platform) if platform else jax.devices()
-    except RuntimeError:
-        devs = jax.devices()
+    devs = parallel.platform_devices(platform)
     if len(devs) < need:
         raise MeshMismatchError(
             "artifact carries a mesh %s over %d %s device(s); this "
@@ -411,7 +401,10 @@ def export_model(trainer, path: str,
     in_dtype = np.uint8 if net.input_norm is not None else np.float32
 
     def forward(data):
-        values, _ = net.apply(params, data, train=False)
+        # the artifact's mesh, not the training mesh the weights came
+        # from: a mesh-free artifact is a one-device program
+        with net.traced_on(mesh):
+            values, _ = net.apply(params, data, train=False)
         return values[net.out_node]
 
     from .parallel import mesh_platform
@@ -892,6 +885,7 @@ def export_decode_step(trainer, path: str, max_new: int = 32,
         if dp > 1:
             buckets = [b for b in _shard_ladder(buckets, dp) if b <= B]
     nh, d = G.uniform_heads_or_reason(net, plan)
+    e_hidden = net.modules[plan["embed"]].param.num_hidden
     params = jax.tree.map(
         lambda w: trainer._fetch_global(w) if w is not None else None,
         trainer.params)
@@ -950,7 +944,7 @@ def export_decode_step(trainer, path: str, max_new: int = 32,
         for w in widths:
             for r in rows:
                 fn = G.build_prefill(net, plan, float(temperature),
-                                     r, w, platform)
+                                     r, w, platform, mesh)
 
                 def pre(toks, lens, key, _fn=fn):
                     return _fn(params, toks, lens, key)
@@ -968,6 +962,10 @@ def export_decode_step(trainer, path: str, max_new: int = 32,
                                     width=w)
                 entry = {"kind": "prefill", "rows": r,
                          "width": w, "bytes": len(blob),
+                         "attend_impl": sorted({
+                             G.prefill_attend_impl(
+                                 net.modules[si], platform, w, e_hidden)
+                             for si in plan["stacks"]}),
                          "flops": pc["flops"],
                          "bytes_streamed": pc["bytes"]}
                 xc = _xla_cost(jpre, *pre_sds)
@@ -1007,12 +1005,12 @@ def export_decode_step(trainer, path: str, max_new: int = 32,
                 mesh_sh["tail_out"][kvd] = [
                     _spec_to_json(s.spec) for s in pre_out]
             for b in buckets:
-                fn = G.build_step(net, plan, float(temperature), b, P,
-                                  Sl, kv_block, platform,
-                                  steps=step_tokens, kv=kvd,
-                                  attend=paged_attend)
+                fn_step = G.build_step(
+                    net, plan, float(temperature), b, P, Sl, kv_block,
+                    platform, steps=step_tokens, kv=kvd,
+                    attend=paged_attend, mesh=mesh)
 
-                def stp(*a, _fn=fn):
+                def stp(*a, _fn=fn_step):
                     return _fn(params, *a)
 
                 # pool buffers (pages AND scale planes) donated: the
@@ -1090,6 +1088,11 @@ def export_decode_step(trainer, path: str, max_new: int = 32,
             rungs.append({
                 "kv_dtype": kvd,
                 "attend_kernel": attend_kernel_name(paged_attend, kvd),
+                # what build_step resolved the step programs' attend
+                # to: ``pallas`` (the compiled paged kernel) or ``xla``
+                # (the fused form's merged-dot fallback, the gather
+                # attend); one answer for every bucket of a rung
+                "attend_impl": fn_step.attend_impl,
                 "pool_dtype": "int8" if kvd == "int8" else pool_dt.name,
                 "scale_dtype": "float32" if kvd == "int8" else None,
                 # bytes ONE slot's attend streams per decoded token
@@ -1469,14 +1472,21 @@ class ExportedStepDecoder:
             else:
                 dt = jnp.dtype(self.meta["pool_dtype"])
                 bufs = (jnp.zeros(shape, dt), jnp.zeros(shape, dt))
+            import jax
             if self.mesh is not None:
                 # mesh pool: the block dim splits across the data
                 # axis — each mesh slice owns its page slice, the
                 # geometry the host allocator mirrors per shard
-                import jax
-                bufs = tuple(jax.device_put(a, self._msh["pool"])
+                return tuple(jax.device_put(a, self._msh["pool"])
                              for a in bufs)
-            return bufs
+            # COMMITTED to the device the zeros landed on: every
+            # program's pool outputs are committed, and committedness
+            # is part of jit's cache key — an uncommitted fresh pool
+            # (engine start, pool-integrity reset after a fault) made
+            # the donating scatter/step jits compile a second time in
+            # steady state
+            return tuple(jax.device_put(a, next(iter(a.devices())))
+                         for a in bufs)
 
     def pre_call(self, rows: int, width: int):
         """The (``rows``, ``width``) prefill program behind the

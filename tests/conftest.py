@@ -11,8 +11,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 os.environ["JAX_PLATFORMS"] = "cpu"
 
-# floor for persistent-cache writes (this env var IS honored at import;
-# the cache-dir one is not on this jax version — see below)
+# floor for persistent-cache writes, should the cache ever be turned on
 os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "1.0")
 
 from cxxnet_tpu.parallel import force_host_cpu
@@ -43,6 +42,12 @@ force_host_cpu(8)
 # saved, so the suite now always compiles fresh: correctness of the
 # run beats ~3 minutes of wall time. (A fresh-cache full run measured
 # 739s vs 536s warm on the 2-core rig, inside the tier-1 budget.)
+# The env var carries the same setting into every subprocess a test
+# spawns (`python -m cxxnet_tpu`, the C demo, tools): cli.main places
+# the cache in <checkout>/.jax-cache (parallel.place_compile_cache),
+# and a spawned run must not start the accrual described above.
+os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "0"
+
 import jax
 
 jax.config.update("jax_enable_compilation_cache", False)

@@ -1,0 +1,139 @@
+"""The main path's Pallas kernels must compile for the v5e chip at
+gpt2_small widths (examples/transformer/gpt2_small.conf: 12 heads of
+d 64, seq 512, 128-slot KV pages, 12 layers).
+
+No chip is attached here: the TPU compiler is installed and compiles
+for a DESCRIBED ``v5e:2x2`` device, which raises what the chip's
+compiler would raise (a slice off the tiling, too much fast memory, a
+program over the device's memory). Interpret mode — the judge of every
+other kernel test — shows none of that. Nothing runs, so these say
+nothing about results or times; ``chip_smoke.py`` is what runs on the
+chip.
+
+The topology is described inside a module-scoped fixture (never at
+import, never in conftest.py, not autouse): only the worker that is
+handed this file loads the TPU library. The compiles run in this
+process with the persistent cache off around them — an entry compiled
+for a described device cannot be read back without a chip.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+NH, D, E = 12, 64, 768
+PAGE, LAYERS = 128, 12
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import os
+
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    old = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield SingleDeviceSharding(topo.devices[0])
+    finally:
+        jax.config.update("jax_enable_compilation_cache", old)
+        compilation_cache.reset_cache()
+
+
+def _compile(fn, one_chip, *shapes):
+    """Compile ``fn`` for the described chip; -> the compiled text,
+    which must hold a Mosaic kernel (not an XLA rewrite of it)."""
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+def _paged_shapes(B=8, seqs=5):
+    blocks = 1 + 4 * B * seqs
+    pool = ((blocks, LAYERS, NH, PAGE, D), jnp.bfloat16)
+    return (((B, NH, D), jnp.bfloat16), pool, pool,
+            ((B, seqs), np.int32), ((B, seqs * PAGE), np.float32))
+
+
+def test_paged_attend_compiles(one_chip):
+    from cxxnet_tpu.ops import paged_attend as pga
+    q, pk, pv, bt, bias = _paged_shapes()
+    _compile(lambda q, pk, pv, bt, bias: pga.paged_attend(
+        q, pk, pv, bt, bias, 3, attend_slots=512, impl="pallas",
+        interpret=False), one_chip, q, pk, pv, bt, bias)
+
+
+def test_paged_attend_q8_compiles(one_chip):
+    from cxxnet_tpu.ops import paged_attend as pga
+    q, pk, _, bt, bias = _paged_shapes()
+    pool8 = (pk[0], jnp.int8)
+    scale = (pk[0][:4], jnp.float32)
+    _compile(lambda q, pk, pv, ks, vs, bt, bias: pga.paged_attend_q8(
+        q, pk, pv, ks, vs, bt, bias, 3, attend_slots=512, impl="pallas",
+        interpret=False),
+        one_chip, q, pool8, pool8, scale, scale, bt, bias)
+
+
+def test_flash_attention_fwd_bwd_compiles(one_chip):
+    from cxxnet_tpu.ops import flash_attention as fa
+    x = ((16, NH, 512, D), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v, causal=True,
+                                  interpret=False).astype(
+                                      jnp.float32).sum()
+
+    _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), one_chip,
+             x, x, x)
+
+
+@pytest.mark.parametrize("seq,batch", [(512, 16), (2048, 4)])
+def test_flash_attention_flat_fwd_bwd_compiles(one_chip, seq, batch):
+    """seq 512 takes the single-block fused-backward kernels, seq 2048
+    the blocked flat kernels — both on the (b, s, 3e) projection
+    layout the training stack feeds them."""
+    from cxxnet_tpu.ops import flash_attention as fa
+    assert fa.supports_flat(seq, NH, D) or fa.flat_blocked_plan(
+        seq, NH, D)
+
+    def loss(qkv):
+        return fa.flash_attention_flat(qkv, NH, causal=True,
+                                       interpret=False).astype(
+                                           jnp.float32).sum()
+
+    _compile(jax.value_and_grad(loss), one_chip,
+             ((batch, seq, 3 * E), jnp.bfloat16))
+
+
+def test_decode_attend_compiles(one_chip):
+    from cxxnet_tpu.ops import decode_attend as da
+    B, Sl = 128, 640
+    cache = ((B, NH, Sl, D), jnp.bfloat16)
+    _compile(lambda q, k, v, bias: da.decode_attend(
+        q, k, v, bias, interpret=False), one_chip,
+        ((B, NH, D), jnp.bfloat16), cache, cache,
+        ((B, Sl), np.float32))
+
+
+def test_lrn_fwd_bwd_compiles(one_chip):
+    """AlexNet's first LRN (256 x 96 x 27 x 27): not on the LM path,
+    but the one conv-net kernel ``lrn_impl = pallas`` can select."""
+    from cxxnet_tpu.ops import lrn as lrn_op
+
+    def loss(x):
+        return lrn_op.lrn(x, 5, 1e-3, 0.75, 1.0,
+                          interpret=False).astype(jnp.float32).sum()
+
+    _compile(jax.value_and_grad(loss), one_chip,
+             ((256, 96, 27, 27), jnp.bfloat16))

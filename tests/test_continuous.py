@@ -491,6 +491,32 @@ def test_engine_drain_fails_stragglers(lm):
         eng.pool.assert_empty()
 
 
+def test_scheduler_bug_fails_requests_instead_of_hanging(lm, capsys):
+    """An exception on the scheduler thread OUTSIDE a device call (a
+    host-side bug) must reach every client as that error, at once, and
+    close admission — not leave requests waiting out their timeouts."""
+    eng = ContinuousDecodeEngine(serving.load_exported(lm["step_path"]),
+                                 warmup=False, start=False)
+
+    def boom():
+        raise KeyError("scheduler bug (test)")
+
+    eng._bind_ready = boom
+    try:
+        req = eng.submit_tokens(lm["toks"][:1], lm["lens"][:1])
+        eng.start()
+        t0 = time.monotonic()
+        with pytest.raises(KeyError, match="scheduler bug"):
+            req.result(20)
+        assert time.monotonic() - t0 < 10
+        with pytest.raises(RuntimeError, match="closed"):
+            eng.submit_tokens(lm["toks"][:1], lm["lens"][:1])
+        assert "scheduler died" in capsys.readouterr().err
+    finally:
+        eng.close()
+        eng.pool.assert_empty()
+
+
 def test_engine_concurrent_join_leave_lockcheck(lm):
     from cxxnet_tpu.analysis import lockcheck
     m = lockcheck.enable(held_warn_s=5.0)

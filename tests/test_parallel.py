@@ -64,6 +64,88 @@ def test_device_config_parsing():
         parallel.select_devices("cpu:17")
 
 
+@pytest.mark.parametrize("dev", ["tpu", "gpu", "tpu:0"])
+def test_named_platform_that_is_absent_is_an_error(dev):
+    """``dev`` naming a platform this (CPU-only) process lacks must
+    raise, naming what was asked and what exists — never hand back the
+    CPU (``gpu`` in reference configs means the accelerator)."""
+    with pytest.raises(RuntimeError) as ei:
+        parallel.select_devices(dev)
+    msg = str(ei.value)
+    assert "'tpu'" in msg and "cpu" in msg
+
+
+def test_no_dev_key_means_the_default_backend():
+    assert parallel.select_devices(None) == jax.devices()
+    assert parallel.select_devices("") == jax.devices()
+    assert parallel.select_devices("cpu") == jax.devices("cpu")
+    tr = Trainer()                      # no dev key set at all
+    assert tr.dev is None
+
+
+def test_trainer_with_missing_platform_fails_naming_it():
+    with pytest.raises(RuntimeError, match="'tpu'"):
+        make_trainer(dev="tpu")
+
+
+class _FakeDevice:
+    def __init__(self, platform, kind):
+        self.platform, self.device_kind = platform, kind
+
+
+def test_device_peaks_one_table_keyed_by_device_kind():
+    """v5e's published peaks; a CPU gets no utilisation figure; an
+    accelerator kind the table lacks raises instead of defaulting."""
+    v5e = parallel.device_peaks(_FakeDevice("tpu", "TPU v5 lite"))
+    assert v5e["bf16_flops_per_s"] == 197e12
+    assert v5e["int8_ops_per_s"] == 393e12
+    assert v5e["hbm_bytes_per_s"] == 819e9 and v5e["hbm_bytes"] == 16e9
+    assert parallel.device_peaks(_FakeDevice("cpu", "cpu")) is None
+    assert parallel.device_peaks() is None          # this process: CPU
+    with pytest.raises(KeyError, match="TPU v9"):
+        parallel.device_peaks(_FakeDevice("tpu", "TPU v9"))
+
+
+def test_compile_cache_helper_env_wins_else_fixed_checkout_path(
+        monkeypatch):
+    """Where JAX_COMPILATION_CACHE_DIR is set nothing is set in code;
+    where it is not, the cache goes to <checkout>/.jax-cache, resolved
+    from the package's own path (never a temporary name)."""
+    import os
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", "/sentinel/dir")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+        assert parallel.place_compile_cache() == "/some/dir"
+        assert jax.config.jax_compilation_cache_dir == "/sentinel/dir"
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        repo = os.path.dirname(os.path.dirname(
+            os.path.abspath(parallel.__file__)))
+        want = os.path.join(repo, ".jax-cache")
+        assert parallel.place_compile_cache() == want
+        assert parallel.place_compile_cache() == want   # same every call
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_virtual_mesh_gets_a_thread_pool_wider_than_its_devices(
+        monkeypatch):
+    """XLA's CPU client gives N virtual devices max(cores, N) threads,
+    and a run-ahead train loop on devices == cores then starves the
+    in-process all-reduce of a participant (XLA aborts after 40 s).
+    ``force_host_cpu`` asks for 4 threads a device through XLA's own
+    PJRT_NPROC, and leaves a value the caller set alone. (Only the
+    environment is judged: this process's backend is already up.)"""
+    import os
+    monkeypatch.delenv("PJRT_NPROC", raising=False)
+    parallel.force_host_cpu(8)
+    assert int(os.environ["PJRT_NPROC"]) == max(os.cpu_count(), 32)
+    monkeypatch.setenv("PJRT_NPROC", "12")
+    parallel.force_host_cpu(8)
+    assert os.environ["PJRT_NPROC"] == "12"
+
+
 def test_tensor_parallel_mesh():
     tr = make_trainer(model_parallel=2)
     assert dict(tr.mesh.shape) == {"data": 4, "model": 2}

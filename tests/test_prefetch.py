@@ -151,6 +151,25 @@ def test_pool_process_mode_matches_thread_mode():
         it.close()
 
 
+def test_decode_worker_imports_stay_jax_free():
+    """A spawned decode worker unpickles ``prefetch._decode_task`` and
+    runs it: that import set must never hold jax — a worker that
+    initialised a backend would take the chip from its parent."""
+    import os
+    import subprocess
+    import sys
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    r = subprocess.run(
+        [sys.executable, "-c",
+         "import sys; import cxxnet_tpu.io.prefetch; "
+         "from cxxnet_tpu.io.image import DataInst, _decode_image; "
+         "print(sorted(m for m in sys.modules "
+         "if m.split('.')[0] in ('jax', 'jaxlib', 'libtpu')))"],
+        capture_output=True, text=True, timeout=120, cwd=repo)
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert r.stdout.strip() == "[]", r.stdout
+
+
 def test_imgbin_pipeline_deterministic_across_worker_counts(tmp_path):
     """The full imgbin chain (pool + random augment + batcher) emits
     bitwise-identical batches for prefetch_worker 0 and 3: parallel

@@ -11,7 +11,7 @@ Pins the contracts docs/observability.md states:
   registered after the events still costs them) but at RECORD time
   for per-phase totals;
 * the module seam is a true no-op when off; the cost table and the
-  calibrated peak survive enable/disable cycles;
+  device peak survive enable/disable cycles;
 * the serving engines record at their four dispatch layers with the
   exact keys serving.profile_cost_table registers;
 * ``REQUEST_PHASES`` is one vocabulary across obs/profile.py,
@@ -202,16 +202,18 @@ def test_costs_and_peak_survive_enable_cycles(no_profile):
     assert s["peak_flops"] == 5.0e8
 
 
-def test_calibrated_peak_env_override_and_no_measure(no_profile):
+def test_device_peak_env_override_and_no_lookup(no_profile):
     profile.set_peak(None)
     os.environ["CXXNET_DEVICE_PEAK_FLOPS"] = "7e9"
     try:
-        assert profile.calibrated_peak(measure=False) == 7e9
+        assert profile.device_peak(lookup=False) == 7e9
     finally:
         del os.environ["CXXNET_DEVICE_PEAK_FLOPS"]
         profile.set_peak(None)
-    # measure=False never compiles: with nothing calibrated it is None
-    assert profile.calibrated_peak(measure=False) is None
+    # lookup=False never touches the backend: with nothing looked up it
+    # is None — and a CPU has no table entry, so a lookup is None too
+    assert profile.device_peak(lookup=False) is None
+    assert profile.device_peak() is None
 
 
 # ----------------------------------------------------------------------

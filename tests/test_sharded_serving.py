@@ -254,6 +254,31 @@ def test_mesh_mismatch_raises_attributed_error_at_load(fwd_arts,
     assert "export_mesh" in msg          # remediation named too
 
 
+def test_serving_mesh_on_a_missing_platform_raises():
+    """``make_serving_mesh(platform="tpu")`` on a CPU-only process is
+    an error naming the platform, not a mesh of CPU devices."""
+    with pytest.raises(RuntimeError, match="'tpu'"):
+        serving.make_serving_mesh(4, platform="tpu")
+    # no platform named: the default backend's devices
+    assert serving.make_serving_mesh(4).devices.size == 4
+
+
+def test_mesh_artifact_recorded_for_a_missing_platform_raises(fwd_arts,
+                                                              tmp_path):
+    """An artifact whose meta records a platform this process lacks
+    fails at LOAD naming it — it does not realize its mesh on the CPU."""
+    _, dp4 = fwd_arts
+    path = str(tmp_path / "for_tpu.export")
+    shutil.copy(dp4, path)
+    with open(dp4 + ".meta") as f:
+        meta = json.load(f)
+    meta["mesh"] = dict(meta["mesh"], platform="tpu")
+    with open(path + ".meta", "w") as f:
+        json.dump(meta, f)
+    with pytest.raises(RuntimeError, match="'tpu'"):
+        serving.load_exported(path)
+
+
 def test_v1_single_device_artifact_loads_unchanged(fwd_arts):
     single, _ = fwd_arts
     m = serving.load_exported(single)
@@ -280,6 +305,66 @@ def test_forward_logits_bitwise_dp4_vs_per_shard_bucket(fwd_arts):
     ref = np.concatenate([np.asarray(m1.call_exact(x[i:i + 2]))
                           for i in range(0, 8, 2)])
     assert np.array_equal(out4, ref)
+
+
+@pytest.mark.parametrize("export_mesh", [None, 4])
+def test_forward_export_of_a_mesh_trainer_traces_its_own_mesh(
+        tmp_path, export_mesh):
+    """A data-parallel trainer hands its mesh to the net, and flash
+    attention then runs per shard of THAT mesh (pallas_env.per_shard).
+    A forward export must trace for the artifact's mesh instead: a
+    mesh-free artifact from an 8-device trainer is a one-device program
+    that serves any batch, and an ``export_mesh=4`` one spans four
+    devices, each shard running the one-device program on its rows."""
+    from jax import export as jexport
+
+    def trainer(dev):
+        tr = Trainer()
+        text = models.tiny_lm(seq_len=128, vocab=16, embed=128,
+                              nlayer=1, nhead=2)
+        for k, v in cfg_mod.parse_string(text.replace(
+                "causal = 1", "causal = 1\n  attn_impl = pallas")):
+            tr.set_param(k, v)
+        for k, v in (("batch_size", "8"), ("dev", dev), ("seed", "0")):
+            tr.set_param(k, v)
+        tr.init_model()
+        return tr
+
+    tr8 = trainer("cpu")
+    assert tr8.n_devices == 8 and tr8.net.mesh is tr8.mesh
+    path = str(tmp_path / "fwd.export")
+    serving.export_model(
+        tr8, path, batch_ladder=[2, 8], platforms=["cpu"],
+        mesh=serving.make_serving_mesh(export_mesh)
+        if export_mesh else None)
+    assert tr8.net.mesh is tr8.mesh          # restored after the trace
+    with open(path + ".meta") as f:
+        meta = json.load(f)
+    with open(path, "rb") as f:
+        blobs = [f.read(n) for n in meta["ladder_blob_bytes"]]
+    assert [jexport.deserialize(bytearray(b)).nr_devices
+            for b in blobs] == [export_mesh or 1] * len(blobs)
+    m = serving.load_exported(path)
+    rs = np.random.RandomState(2)
+    x = rs.randint(0, 16, (8, 1, 128, 1)).astype(np.float32)
+    out = np.asarray(m.call_exact(x))
+    if export_mesh is None:
+        # what a one-device trainer of the same seed exports
+        ref_path = str(tmp_path / "ref.export")
+        serving.export_model(trainer("cpu:0"), ref_path,
+                             batch_ladder=[2, 8], platforms=["cpu"])
+        ref = np.asarray(serving.load_exported(ref_path).call_exact(x))
+        assert np.array_equal(out, ref)
+        assert m(x[:3]).shape[0] == 3        # a short batch, padded
+    else:
+        # bucket 8 over 4 shards = the one-device 2-bucket per shard
+        ref_path = str(tmp_path / "ref.export")
+        serving.export_model(tr8, ref_path, batch_ladder=[2, 8],
+                             platforms=["cpu"])
+        m1 = serving.load_exported(ref_path)
+        ref = np.concatenate([np.asarray(m1.call_exact(x[i:i + 2]))
+                              for i in range(0, 8, 2)])
+        assert np.array_equal(out, ref)
 
 
 def test_decode_step_mesh_meta_geometry(step_arts):
@@ -310,6 +395,37 @@ def test_generate_driver_bitwise_dp4_vs_single(step_arts):
     toks, lens = _prompts()
     out_m = dm.generate(toks, lens, seed=0)
     out_s = ds.generate(toks, lens, seed=0)
+    assert np.array_equal(out_m, out_s)
+
+
+def test_mesh_step_runs_the_pallas_attend_per_shard(tmp_path,
+                                                    monkeypatch):
+    """On a TPU the step's attend is a Mosaic kernel, which XLA cannot
+    partition: under a mesh it runs per device on its own slots and its
+    own slice of pages, with the block table rebased from pool-wide
+    page ids to the slice's (generate.build_step). Forced here onto the
+    INTERPRETED kernel over the host mesh: 2 lanes a shard must decode
+    exactly what a single-device artifact with 2 lanes decodes."""
+    from cxxnet_tpu.ops import paged_attend as pga
+    real = pga.resolve_impl
+    monkeypatch.setattr(
+        pga, "resolve_impl",
+        lambda impl=None, interpret=None: ("pallas", True)
+        if impl is None else real(impl, interpret))
+    tr = _lm_trainer(8)
+    dp4 = str(tmp_path / "dp4_pallas.export")
+    single = str(tmp_path / "single_pallas.export")
+    serving.export_decode_step(
+        tr, dp4, max_new=4, temperature=0.0, prompt_len=12,
+        platforms=["cpu"], mesh=serving.make_serving_mesh(4))
+    serving.export_decode_step(
+        tr, single, max_new=4, temperature=0.0, prompt_len=12,
+        batch_size=2, platforms=["cpu"])
+    with open(dp4 + ".meta") as f:
+        assert json.load(f)["rungs"][0]["attend_impl"] == "pallas"
+    toks, lens = _prompts(n=8)
+    out_m = serving.load_exported(dp4).generate(toks, lens, seed=0)
+    out_s = serving.load_exported(single).generate(toks, lens, seed=0)
     assert np.array_equal(out_m, out_s)
 
 

@@ -14,8 +14,11 @@ same sweep prints the real fan-out. Writes docs/io_sweep_r3.json.
 (ops/paged_attend.py — what the continuous serving engine actually
 runs, so BENCH kernel comparisons keep covering the serving path):
 gather-xla vs fused-paged vs fused-paged-q8 at serving pool shapes
-across context lengths, interleaved in the same weather window
-(BASELINE.md protocol). Writes docs/paged_kernel_sweep.json.
+across context lengths, interleaved per trial so host noise hits
+every variant equally. Writes the file ``--out`` names (default
+chiprun_out/paged_kernel_sweep.json; the CPU-backend sweep once kept
+under docs/ was deleted in PR 21 — it timed the XLA fallback, not the
+kernel).
 
 Usage: python tools/decode_sweep.py [--images 480] [--side 256]
        python tools/decode_sweep.py --kernels [--contexts 256,512,1024]
@@ -97,7 +100,7 @@ def kernel_sweep(args):
     """--kernels: the paged decode-attend kernel microbench. One
     jitted per-layer attend per variant (the serving step runs L x
     step_tokens of these back to back), best-of-N with variants
-    interleaved per trial so shared-host weather hits them equally."""
+    interleaved per trial so host noise hits them equally."""
     import jax
     import jax.numpy as jnp
 
@@ -174,8 +177,7 @@ def kernel_sweep(args):
            "note": "per-layer attend only (the step runs layers x "
                    "step_tokens of these); XLA forms on this host — "
                    "the pallas form needs a TPU. Interleaved "
-                   "best-of-%d, BASELINE.md weather protocol."
-                   % args.trials}
+                   "best-of-%d." % args.trials}
     with open(args.out, "w") as f:
         json.dump(doc, f, indent=1)
     print(json.dumps(doc))
@@ -199,7 +201,8 @@ def main():
     args = ap.parse_args()
     if args.kernels:
         args.out = args.out or os.path.join(
-            REPO, "docs", "paged_kernel_sweep.json")
+            REPO, "chiprun_out", "paged_kernel_sweep.json")
+        os.makedirs(os.path.dirname(args.out), exist_ok=True)
         return kernel_sweep(args)
     args.out = args.out or os.path.join(
         REPO, "docs", "io_sweep_r3.json")

@@ -5,9 +5,9 @@ record per-device compiled memory, and bracket the predicted v5e
 weak-scaling efficiency against the ICI roofline
 (cxxnet_tpu.parallel.collective_report / scaling_prediction).
 
-Multi-chip hardware is not available on this rig (BASELINE.md); these
-are the numbers that CAN be produced honestly without it — measured
-from the compiled programs, not asserted. Writes
+These are the numbers that can be produced without multi-chip
+hardware — read from the compiled programs, not asserted; a prediction,
+not a measurement of any chip. Writes
 docs/multichip_r5.json and prints one JSON line per config.
 
 Run: JAX_PLATFORMS=cpu python tools/multichip_report.py

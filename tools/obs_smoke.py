@@ -24,7 +24,7 @@ tools/goodput_report.py --json).
 
 Leg 4 (profile): the program profiler (obs/profile.py) runs armed
 beside the attribution ledger across the same legs, with the device
-peak calibrated up front. The live-trainer engine's events are
+peak looked up up front. The live-trainer engine's events are
 UNCOSTED (no export meta — they must appear in the explicit uncosted
 list); an export_model sub-leg then serves the exported artifact so
 COSTED events exist, and the summary must show events > 0, every
@@ -306,10 +306,9 @@ def main() -> int:
         obs_trace.start(trace_path)
         attrib.enable()
         profile.enable()
-        # calibrate the MFU denominator up front — the measurement
-        # jit-compiles one matmul, which must not land inside an armed
-        # jitcheck window (none here, but the bench discipline holds)
-        profile.calibrated_peak()
+        # look the MFU denominator up once (summary() never touches
+        # the backend itself); on a CPU there is none and no MFU
+        profile.device_peak()
         tr = _tiny_trainer()
         _train_leg(td, tr)
         _serve_leg(tr)

@@ -15,7 +15,7 @@ Three sources for the profile summary, first match wins:
 
 The report answers the roofline question the attribution ledger only
 frames: per program shape (site phase/rung bucket width), the window's
-wall-ms median, achieved FLOP/s and MFU against the calibrated device
+wall-ms median, achieved FLOP/s and MFU against the published device
 peak, plus the bottom-MFU shapes and the explicit uncosted list. On a
 shared CPU rig MFU is a RELATIVE regression unit, not an absolute
 utilization claim (docs/observability.md).
@@ -157,7 +157,7 @@ def human(s, source):
     out.append("  %d events lifetime%s, %.1f ms wall"
                % (s.get("events", 0), win, s.get("wall_ms", 0.0)))
     peak = s.get("peak_flops")
-    out.append("  peak %sFLOP/s (calibrated)%s" % (
+    out.append("  peak %sFLOP/s (published)%s" % (
         _fmt_flops(peak),
         "" if s.get("mfu") is None
         else ", overall MFU %.4f" % s["mfu"]))

@@ -2,7 +2,8 @@
 for the LM / ViT encoder paths (VERDICT r3 #1).
 
 Same measurement protocol as perf_lab (fenced full-step windows,
-variants interleaved in the same weather window, best-of-N); variants
+variants interleaved so host noise hits each equally, best-of-N);
+variants
 are (name, netconfig-text, batch, kind) tuples so LM and ViT recipes
 can ride one harness. gpt2-class trainers hold ~5 GB HBM each with
 activations — probe at most 2-3 resident at once (docs/performance.md
@@ -21,7 +22,6 @@ sys.path.insert(0, __file__.rsplit("/", 2)[0])
 
 from tools.perf_lab import build, time_steps  # noqa: E402
 
-PEAK_FLOPS = 197e12
 
 
 def lm_batches(batch, seq, vocab, n=3):
@@ -57,6 +57,8 @@ def run(variants, iters, trials, warmup, fuse=1):
             best[name] = min(best[name], ms)
         sys.stderr.write("trial %d: %s\n" % (
             t, {k: round(v, 2) for k, v in best.items()}))
+    from cxxnet_tpu.parallel import device_peaks
+    peaks = device_peaks()        # None on a CPU: no MFU is printed
     for name, tr, _, per_step in variants:
         ms = best[name]
         try:
@@ -69,8 +71,9 @@ def run(variants, iters, trials, warmup, fuse=1):
             "step_ms": round(ms, 3),
             "per_sec": round(per_step / ms * 1000.0, 1),
             "model_flops": mf,
-            "mfu": round(mf / (ms / 1000.0) / PEAK_FLOPS, 4)
-            if mf else None}))
+            "mfu": round(mf / (ms / 1000.0)
+                         / peaks["bf16_flops_per_s"], 4)
+            if mf and peaks else None}))
     return best
 
 
