@@ -634,7 +634,6 @@ class LearnTask:
         # publish the train-loop telemetry into the global registry
         # (the telemetry_port endpoint and any in-process scraper read
         # the same numbers the round summary prints)
-        from .obs import trace as obs_trace
         from .obs.registry import get_registry, watch_steptimer
         self._obs_hooks.append(
             watch_steptimer(self.timer, registry=get_registry()))
@@ -657,13 +656,11 @@ class LearnTask:
             # a float()/np.asarray() here would serialize the loop)
             if isinstance(group, StagedBatch):
                 n = group.fused or 1
-                with self.trace.step(n), \
-                        obs_trace.span("train.dispatch", "train"):
+                with self.trace.step(n):
                     self.trainer.update_fused(group)
             else:
                 n = len(group)
-                with self.trace.step(n), \
-                        obs_trace.span("train.dispatch", "train"):
+                with self.trace.step(n):
                     if n == 1:
                         self.trainer.update(group[0])
                     else:

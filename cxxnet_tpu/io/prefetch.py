@@ -328,6 +328,7 @@ class DevicePrefetchIterator:
     def _produce(self, q, gen) -> None:
         from ..trainer import GroupStager
         tr = self.trainer
+        _trace.name_os_thread()     # its line in a profiler capture
         try:
             self.base.before_first()
             use_groups = self.fuse > 1 and self.group_staging != 0
@@ -345,6 +346,8 @@ class DevicePrefetchIterator:
                             # partial fill; the buffers themselves are
                             # safe to overwrite (stage/flush fence)
             pend = []
+            step = 0    # the batch's ordinal in the round: the staged
+                        # batch carries it to feed.get and trainer.update
             while True:
                 if gen != self._gen:
                     # before_first superseded this epoch: stop decoding
@@ -362,13 +365,16 @@ class DevicePrefetchIterator:
                     break
                 batch = self.base.value
                 t0 = time.perf_counter()
-                with _trace.span("feed.stage", "feed"):
+                with _trace.span("feed.stage", "feed", {"step": step}):
                     if gs is not None:
                         gs.add(batch)   # copies now; base may reuse
                         staged = gs.stage() if gs.full else None
                     else:
                         staged = tr.stage(batch)
+                    if staged is not None:
+                        staged.step = step  # a group: its last batch's
                 self.stage_busy.add_busy(time.perf_counter() - t0)
+                step += 1
                 if gs is not None:
                     if staged is not None:
                         self._put(q, staged)
@@ -415,8 +421,10 @@ class DevicePrefetchIterator:
         if self._queue is None:
             self.before_first()
         t0 = time.perf_counter()
-        with _trace.span("feed.get", "feed"):
+        with _trace.span("feed.get", "feed") as sp:
             item = self._queue.get()
+            if sp is not _trace.NOOP_SPAN:
+                sp.note(step=getattr(item, "step", None))
         self.get_wait.add_wait(time.perf_counter() - t0)
         if item is None or isinstance(item, ProducerFailure):
             self._thread.join()

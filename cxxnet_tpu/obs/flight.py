@@ -48,7 +48,7 @@ from .registry import _safe_list
 
 # ring entry layout (plain tuple, no class — append cost is the point):
 #   (ph, name, cat, t0, t1, ident, thread_name, args, fid)
-# ph: "X" span, "i" instant, "s"/"t"/"f" flow, "C" counter
+# ph: "X" span, "i" instant, "s"/"t"/"f" flow
 # t0/t1: perf_counter seconds (t0 == t1 for point events)
 
 
@@ -56,9 +56,9 @@ class FlightRecorder:
     """Bounded ring of trace events with retroactive window dumps.
 
     Duck-types the :class:`obs.trace.Tracer` event-sink surface
-    (``span`` / ``complete`` / ``instant`` / ``counter`` /
-    ``flow_start`` / ``flow_step`` / ``flow_end``) so the trace
-    module's fanout can treat tracer and recorder uniformly.
+    (``span`` / ``complete`` / ``instant`` / ``flow_start`` /
+    ``flow_step`` / ``flow_end``) so the trace module's fanout can
+    treat tracer and recorder uniformly.
     """
 
     def __init__(self, max_events: int = 65536) -> None:
@@ -101,10 +101,6 @@ class FlightRecorder:
         now = time.perf_counter()
         self._emit("i", name, cat, now, now, args, None)
 
-    def counter(self, name: str, values, cat: str = "app") -> None:
-        now = time.perf_counter()
-        self._emit("C", name, cat, now, now, dict(values), None)
-
     def flow_start(self, name: str, fid: int, cat: str = "flow") -> None:
         now = time.perf_counter()
         self._emit("s", name, cat, now, now, None, int(fid))
@@ -120,6 +116,11 @@ class FlightRecorder:
     # -- introspection --------------------------------------------------
     def __len__(self) -> int:
         return len(self._ring)
+
+    def clear(self) -> None:
+        """Drop what the ring holds (``recorded`` keeps counting): the
+        profiler sink's ring starts each session empty."""
+        self._ring.clear()
 
     def _snapshot(self) -> List[tuple]:
         """Copy the ring without blocking appenders (the shared
@@ -157,8 +158,6 @@ class FlightRecorder:
                 ev["s"] = "t"
                 if args:
                     ev["args"] = args
-            elif ph == "C":
-                ev["args"] = dict(args or {})
             else:                       # s/t/f flow events
                 ev["id"] = int(fid)
                 if ph == "f":

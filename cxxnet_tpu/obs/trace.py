@@ -1,13 +1,27 @@
 """Structured span tracing: Chrome trace-event JSON with thread lanes.
 
 One tracer serves the whole process. Call sites use the module-level
-helpers (``span`` / ``instant`` / ``counter`` / ``flow_*``); with no
-tracer installed each helper is one module-global read, one branch,
+helpers (``span`` / ``instant`` / ``flow_*``); with no sink installed
+and no profiler session live each helper is one
+``TraceAnnotation.is_enabled()``, one module-global read, one branch,
 and a shared no-op singleton — **zero allocation per call** — so the
 instrumentation stays in the hot paths permanently (decode workers,
-the device-prefetch producer, the dispatch-ahead train loop, the
-serving engine's dispatch/completion threads) and costs nothing until
-``trace_out=`` turns it on.
+the device-prefetch producer, the train step's dispatch, the serving
+engine's dispatch/completion threads) and costs nothing until
+``trace_out=`` or a ``jax.profiler`` session turns it on.
+
+Three sinks share the seam: the ``Tracer`` below (``trace_out=``, a
+Chrome-JSON file of the whole run), the flight recorder (obs/flight.py,
+an always-on bounded ring) and the **profiler sink**, live exactly
+while a ``jax.profiler`` session is, whoever started it (``profile=1``,
+a benchmark harness, an operator's capture). While live, every span is
+also a ``jax.profiler.TraceAnnotation`` of its own name, with its args
+as metadata, so it lands in the ``.xplane.pb`` on its own thread's
+line, on the device trace's clock by construction; and it is kept in a
+ring on ``perf_counter`` that ``profile_spans()`` returns once the
+session has ended. JAX's compile events arrive through the same seam
+as ``compile.*`` spans, and are counted whether or not a sink is live
+(``compile_events()``).
 
 Output is the Chrome trace-event format (load the file in
 ``chrome://tracing`` or https://ui.perfetto.dev, or summarize with
@@ -32,9 +46,12 @@ from __future__ import annotations
 
 import contextlib
 import json
+import numbers
 import os
+import sys
 import threading
 import time
+from collections import deque
 from typing import Dict, List, Optional
 
 
@@ -78,6 +95,12 @@ class _Span:
         self._tr.complete(self.name, self.cat, self._t0,
                           time.perf_counter(), self.args)
         return False
+
+    def note(self, **args) -> None:
+        """Args known only once the span is open (the batch a
+        ``feed.get`` received). Guard the call with ``is not
+        NOOP_SPAN``: the no-op singleton has no such method."""
+        self.args = dict(self.args or (), **args)
 
 
 class Tracer:
@@ -148,12 +171,6 @@ class Tracer:
             ev["args"] = args
         self._emit(ev)
 
-    def counter(self, name: str, values: Dict[str, float],
-                cat: str = "app") -> None:
-        self._emit({"ph": "C", "name": name, "cat": cat, "pid": 0,
-                    "tid": self._tid(), "ts": self._ts(),
-                    "args": dict(values)})
-
     def _flow(self, ph: str, name: str, fid: int, cat: str) -> None:
         # flow ids are caller-owned (the serving engine uses its
         # process-wide request sequence) — one id space, one arrow
@@ -218,8 +235,10 @@ class Tracer:
 # Two independently-installable sinks share the seam: the TRACER
 # (trace_out=, full-run file) and the FLIGHT RECORDER (obs/flight.py,
 # always-on bounded ring). ``_sink`` caches their composition —
-# None / the one active sink / a _Fanout over both — so every helper
-# still pays exactly one module-global read and one branch when
+# None / the one active sink / a _Fanout over both. The PROFILER SINK
+# (further down) is installed by nobody: it wraps that composition
+# for as long as a jax.profiler session is live, so every helper pays
+# one ``is_enabled()``, one module-global read and one branch when
 # everything is off, and call sites that cached ``active()`` to avoid
 # per-event overhead use ``sink()`` the same way.
 
@@ -250,10 +269,6 @@ class _Fanout:
         self.a.instant(name, cat, args)
         self.b.instant(name, cat, args)
 
-    def counter(self, name, values, cat="app") -> None:
-        self.a.counter(name, values, cat)
-        self.b.counter(name, values, cat)
-
     def flow_start(self, name, fid, cat="flow") -> None:
         self.a.flow_start(name, fid, cat)
         self.b.flow_start(name, fid, cat)
@@ -275,6 +290,262 @@ def _recompose() -> None:
         _sink = _active
     else:
         _sink = _Fanout(_active, _flight)
+    if _prof is not None:
+        _prof.inner = _sink
+
+
+# ----------------------------------------------------------------------
+# the profiler sink: the program's spans inside a jax.profiler capture
+
+PROFILE_RING_EVENTS = 65536
+
+_TA = None              # jax.profiler.TraceAnnotation, once jax is loaded
+_prof = None            # the _ProfilerSink, made when jax is first seen
+_session_seen = False   # the live session has been seen (its ring is fresh)
+_tls = threading.local()    # .named: OS thread named; .phase: see phase
+_attach_lock = threading.Lock()
+
+
+def _plain(args) -> dict:
+    """Numbers and strings as they are, anything else by its type's
+    name: the ring outlives the objects a span was handed (a ring that
+    pinned a device buffer would show in the process's peak memory),
+    and str() of a device array would wait for the device."""
+    if not args:
+        return {}
+    return {k: v if isinstance(v, (str, numbers.Number))
+            else type(v).__name__
+            for k, v in args.items() if v is not None}
+
+
+class _ProfSpan(_Span):
+    """A span that is also a TraceAnnotation from open to close."""
+
+    __slots__ = ("_ta",)
+
+    def __enter__(self):
+        self.args = _plain(self.args)
+        self._ta = _TA(self.name, **self.args)
+        self._ta.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        t1 = time.perf_counter()
+        self._ta.__exit__(exc_type, exc, tb)
+        self._tr.closed(self.name, self.cat, self._t0, t1, self.args)
+        return False
+
+    def note(self, **args) -> None:
+        args = _plain(args)
+        self.args.update(args)
+        self._ta.set_metadata(**args)
+
+
+class _ProfilerSink:
+    """The sink of a live jax.profiler session, over whatever else is
+    installed (``inner``: None, the tracer, the flight recorder or the
+    _Fanout of both). It wraps the others, where _Fanout stands beside
+    them, because it alone has to know when a span OPENS."""
+
+    __slots__ = ("inner", "ring")
+
+    def __init__(self, inner) -> None:
+        from .flight import FlightRecorder
+        self.inner = inner
+        self.ring = FlightRecorder(PROFILE_RING_EVENTS)
+
+    def span(self, name: str, cat: str = "app",
+             args: Optional[dict] = None) -> _ProfSpan:
+        return _ProfSpan(self, name, cat, args)
+
+    def closed(self, name, cat, t0, t1, args) -> None:
+        self.ring.complete(name, cat, t0, t1, args)
+        if self.inner is not None:
+            self.inner.complete(name, cat, t0, t1, args)
+
+    def complete(self, name, cat, t0, t1, args=None) -> None:
+        # reported after the fact, so there was no start to annotate:
+        # the capture gets a marker at the end that carries the length
+        # (the ring gets the true interval)
+        args = _plain(args)
+        with _TA(name, dur_us=(t1 - t0) * 1e6, **args):
+            pass
+        self.closed(name, cat, t0, t1, args)
+
+    def instant(self, name, cat="app", args=None) -> None:
+        with _TA(name, **_plain(args)):
+            pass
+        if self.inner is not None:
+            self.inner.instant(name, cat, args)
+
+    def flow_start(self, name, fid, cat="flow") -> None:
+        if self.inner is not None:
+            self.inner.flow_start(name, fid, cat)
+
+    def flow_step(self, name, fid, cat="flow") -> None:
+        if self.inner is not None:
+            self.inner.flow_step(name, fid, cat)
+
+    def flow_end(self, name, fid, cat="flow") -> None:
+        if self.inner is not None:
+            self.inner.flow_end(name, fid, cat)
+
+
+def _attach_jax():
+    """-> jax's TraceAnnotation once the process has imported jax, else
+    None. This module never imports jax itself: decode workers import
+    it and must stay jax-free (io/prefetch.py). The first sight of jax
+    also registers the one compile-event listener."""
+    global _TA, _prof, _compile_seconds, _compiles
+    ta = getattr(sys.modules.get("jax.profiler"), "TraceAnnotation", None)
+    monitoring = sys.modules.get("jax.monitoring")
+    if ta is None or monitoring is None:
+        return None
+    with _attach_lock:
+        if _TA is None:
+            from .registry import get_registry
+            reg = get_registry()
+            _compile_seconds = reg.counter(
+                "cxxnet_compile_seconds_total",
+                "seconds in JAX's compile events as they fire (trace: "
+                "nested traces counted in their parents' too)", ("phase",))
+            _compiles = reg.counter(
+                "cxxnet_compiles_total",
+                "JAX compile events by phase (backend: executables "
+                "built or read from the cache)", ("phase",))
+            monitoring.register_event_duration_secs_listener(_on_compile)
+            # made now, not at a session's first span: that span is on
+            # somebody's timed path
+            _prof = _ProfilerSink(_sink)
+            _TA = ta
+    return ta
+
+
+def name_os_thread() -> None:
+    """The profiler labels a host line by the OS name the thread had at
+    its first event of any kind, and Python 3.12 hands its thread names
+    to nobody: give this thread's to the OS (15 characters), or its
+    line reads ``python``. ``_profiling`` does it at a thread's first
+    span of a session; a thread that calls into XLA between spans does
+    it as it starts (io/prefetch.py). The main thread keeps its name,
+    which is the process's own (``ps``, ``pkill``)."""
+    _tls.named = True
+    t = threading.current_thread()
+    if t is threading.main_thread() or not sys.platform.startswith("linux"):
+        return
+    try:
+        import ctypes
+        ctypes.CDLL(None).prctl(15, t.name.encode()[:15], 0, 0, 0)
+    except (OSError, AttributeError):
+        pass        # the line stays ``python``; the ring has the name
+
+
+def _profiling() -> bool:
+    """True while a jax.profiler session is live. A session first seen
+    empties the ring (a session that starts and ends between two span
+    calls is never seen: there was nothing to put in it)."""
+    global _session_seen
+    ta = _TA or _attach_jax()
+    if ta is None or not ta.is_enabled():
+        if _session_seen:
+            _session_seen = False
+        return False
+    if not _session_seen:
+        with _attach_lock:
+            if not _session_seen:
+                _prof.ring.clear()
+                _session_seen = True
+    if not getattr(_tls, "named", False):
+        name_os_thread()
+    return True
+
+
+def profile_spans() -> List[tuple]:
+    """The spans of the newest profiler session, oldest first:
+    ``(name, cat, t0, t1, thread, args)`` on ``perf_counter``, args
+    numbers and strings only. Read it once the session has ended."""
+    if _prof is None:
+        return []
+    return [(name, cat, t0, t1, tname, args)
+            for ph, name, cat, t0, t1, _, tname, args, _
+            in _prof.ring.events_last(float("inf")) if ph == "X"]
+
+
+# ----------------------------------------------------------------------
+# compile events: which program phase compiled, tracing on or off
+
+COMPILE_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    # fires for a compilation and for a persistent-cache read alike
+    "/jax/core/compile/backend_compile_duration": "backend",
+    # lies inside the backend event of the same executable
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_read",
+}
+_compile_log: deque = deque(maxlen=4096)
+_compile_seconds = _compiles = None     # registry counters, made with
+                                        # the listener (_attach_jax)
+
+
+class phase:
+    """``span()`` for a phase of the program that may compile (a train
+    step's dispatch, the trainer's init): besides the span it notes on
+    its thread, **whether or not a sink is live**, what the thread is
+    doing, so that a compile event firing there can be put down to it
+    (``compile_events()``: "which step recompiled", with tracing off).
+    ``step_num`` in ``args`` is kept as the cause's number."""
+
+    __slots__ = ("_span", "_cause", "_prev")
+
+    def __init__(self, name: str, cat: str = "app",
+                 args: Optional[dict] = None) -> None:
+        self._cause = (name, args.get("step_num") if args else None)
+        self._span = span(name, cat, args)
+
+    def __enter__(self):
+        self._prev = getattr(_tls, "phase", None)
+        _tls.phase = self._cause
+        return self._span.__enter__()
+
+    def __exit__(self, exc_type, exc, tb):
+        _tls.phase = self._prev
+        return self._span.__exit__(exc_type, exc, tb)
+
+
+def _on_compile(event: str, secs: float, **kw) -> None:
+    what = COMPILE_PHASES.get(event)
+    if what is None:
+        return
+    t1 = time.perf_counter()
+    cause = getattr(_tls, "phase", None)
+    if what == "trace":
+        # JAX fires this for every jitted function traced inside
+        # another's trace, thousands of them in one train step, each
+        # before the one that contains it: the log keeps the outermost
+        while _compile_log and _compile_log[-1][0] == "trace" \
+                and _compile_log[-1][3] == cause \
+                and _compile_log[-1][2] - _compile_log[-1][1] >= t1 - secs:
+            _compile_log.pop()
+    _compile_log.append((what, secs, t1, cause))
+    _compile_seconds.inc(secs, phase=what)
+    _compiles.inc(phase=what)
+    if what == "trace" and secs < 1e-3:
+        return      # the nested ones: not worth a span each
+    s = sink()
+    if s is not None:
+        s.complete("compile." + what, "compile", t1 - secs, t1,
+                   {"seconds": secs, "fun": kw.get("fun_name", "")})
+
+
+def compile_events() -> List[tuple]:
+    """``(phase, seconds, t_end, cause)`` of JAX's compile events since
+    jax was first seen (the newest 4096; of traces nested in one
+    another the outermost): phase is trace | lower | backend |
+    cache_read, t_end on ``perf_counter``, cause the ``(name,
+    step_num)`` of the program phase open on the compiling thread
+    (``phase``), or None."""
+    return list(_compile_log)
 
 
 def active() -> Optional[Tracer]:
@@ -286,10 +557,14 @@ def enabled() -> bool:
 
 
 def sink():
-    """The composed event sink (tracer, flight recorder, both, or
+    """The composed event sink (tracer, flight recorder, both, the
+    profiler sink over them while a jax.profiler session is live, or
     None). Hot call sites that emit several events per request cache
     this once per request instead of branching per event — the same
     pattern they used with ``active()``, now flight-aware."""
+    ta = _TA
+    if (ta is None or _session_seen or ta.is_enabled()) and _profiling():
+        return _prof
     return _sink
 
 
@@ -332,8 +607,15 @@ def stop(path: Optional[str] = None) -> Optional[str]:
 
 
 def span(name: str, cat: str = "app", args: Optional[dict] = None):
-    """A context manager timing one span. Disabled: the shared no-op
-    singleton (same object every call — no allocation)."""
+    """A context manager timing one span. Disabled (no sink, no live
+    profiler session): the shared no-op singleton (same object every
+    call — no allocation)."""
+    # the off path stays inline: _profiling() is called only when jax
+    # has not been seen yet, a session was live at the last look, or
+    # one is live now
+    ta = _TA
+    if (ta is None or _session_seen or ta.is_enabled()) and _profiling():
+        return _ProfSpan(_prof, name, cat, args)
     s = _sink
     if s is None:
         return NOOP_SPAN
@@ -342,32 +624,25 @@ def span(name: str, cat: str = "app", args: Optional[dict] = None):
 
 def instant(name: str, cat: str = "app",
             args: Optional[dict] = None) -> None:
-    s = _sink
+    s = sink()
     if s is not None:
         s.instant(name, cat, args)
 
 
-def counter(name: str, values: Dict[str, float],
-            cat: str = "app") -> None:
-    s = _sink
-    if s is not None:
-        s.counter(name, values, cat)
-
-
 def flow_start(name: str, fid: int, cat: str = "flow") -> None:
-    s = _sink
+    s = sink()
     if s is not None:
         s.flow_start(name, fid, cat)
 
 
 def flow_step(name: str, fid: int, cat: str = "flow") -> None:
-    s = _sink
+    s = sink()
     if s is not None:
         s.flow_step(name, fid, cat)
 
 
 def flow_end(name: str, fid: int, cat: str = "flow") -> None:
-    s = _sink
+    s = sink()
     if s is not None:
         s.flow_end(name, fid, cat)
 
@@ -377,8 +652,8 @@ class ProfilerSession:
     """Config-gated jax.profiler trace over a window of train steps
     (formerly ``profiler.TraceSession``; moved here so every tracing
     surface lives in ``obs`` — the Chrome-trace writer above is the
-    host-side span view, this is the XLA/device-op view, and they are
-    enabled by different knobs because they answer different questions).
+    host-side span view, this is the XLA/device-op view, which holds
+    the program's spans too while it runs: the profiler sink above).
 
     Keys (global config, broadcast like every other param):
       profile = 0|1            enable trace capture
@@ -412,8 +687,9 @@ class ProfilerSession:
         batches (1 for a plain step; K for a fused fuse_steps group):
         starts/stops the trace at the configured BATCH indices, so the
         profile window stays in batch units whatever the dispatch
-        grouping. The step_num annotation is the dispatch's first batch
-        index."""
+        grouping. It annotates nothing itself: inside the window the
+        program's own spans are in the capture, and ``trainer.update``
+        carries the step's ``step_num``."""
         n = self._step
         self._step += nbatch
         if not self.enabled or self._done:
@@ -443,9 +719,6 @@ class ProfilerSession:
             jax.profiler.stop_trace()
             self._active = False
             self._done = True
-            return contextlib.nullcontext()
-        if self._active:
-            return jax.profiler.StepTraceAnnotation("train", step_num=n)
         return contextlib.nullcontext()
 
     def close(self) -> None:

@@ -40,6 +40,24 @@ from jax.experimental import pallas as pl
 NEG_INF = -1e30
 
 
+def _named_call(name, kernel, **kw):
+    """``pl.pallas_call`` whose HLO instruction is called ``name``:
+    a device trace then shows ``flash_fwd.N`` / ``flash_dq.N`` /
+    ``flash_dkv.N`` (``flash_bwd.N`` for the one-kernel backward), where
+    an unnamed call inside the custom_vjp reads ``jvp__.N`` /
+    ``transpose_jvp___.N`` for every kernel alike. XLA names the
+    instruction after the innermost scope as the transform left it, so
+    the kernel's own ``name=`` alone comes out as ``jvp_flash_fwd_``:
+    the scope around the call is what gives the plain name. Metadata
+    only: the kernels are the same."""
+    call = pl.pallas_call(kernel, name=name, **kw)
+
+    def run(*operands):
+        with jax.named_scope(name):
+            return call(*operands)
+    return run
+
+
 def _interpret() -> bool:
     from . import pallas_env
     return pallas_env.interpret()
@@ -264,7 +282,8 @@ def _fwd_impl(q, k, v, causal, block_q, block_k, interpret):
     grid = (bh // g, s // block_q)
     kern = functools.partial(_fwd_kernel, causal=causal,
                              block_q=block_q, block_k=block_k, s=s)
-    return pl.pallas_call(
+    return _named_call(
+        "flash_fwd",
         kern,
         grid=grid,
         in_specs=[
@@ -426,7 +445,8 @@ def _bwd1_impl(q, k, v, lse, do, delta, scale, causal, interpret):
     g = _pick_group(bh, "bwd1", s, d, s, s)
     spec_sd = pl.BlockSpec((g, s, d), lambda i: (i, 0, 0))
     spec_stat = pl.BlockSpec((g, 1, s), lambda i: (i, 0, 0))
-    return pl.pallas_call(
+    return _named_call(
+        "flash_bwd",
         functools.partial(_bwd1_kernel, scale=scale, causal=causal,
                           s=s),
         grid=(bh // g,),
@@ -449,7 +469,8 @@ def _bwd_impl(q, k, v, o, lse, do, scale, causal, block_q,
         return _bwd1_impl(q, k, v, lse, do, delta, scale, causal,
                           interpret)
     g1 = _pick_group(bh, "dq", s, d, block_q, block_k)
-    dq = pl.pallas_call(
+    dq = _named_call(
+        "flash_dq",
         functools.partial(_dq_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, s=s),
         grid=(bh // g1, s // block_q),
@@ -466,7 +487,8 @@ def _bwd_impl(q, k, v, o, lse, do, scale, causal, block_q,
         interpret=interpret,
     )(q, k, v, do, lse, delta)
     g2 = _pick_group(bh, "dkv", s, d, block_q, block_k)
-    dk, dv = pl.pallas_call(
+    dk, dv = _named_call(
+        "flash_dkv",
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, s=s),
         grid=(bh // g2, s // block_k),
@@ -647,7 +669,8 @@ def _flash_flat_fwd(qkv, nhead, causal, scale, interpret):
         raise ValueError(
             "flash_attention_flat: unsupported shape s=%d h=%d d=%d "
             "(callers must consult supports_flat)" % (s, h, d))
-    o, lse = pl.pallas_call(
+    o, lse = _named_call(
+        "flash_fwd",
         functools.partial(_flat_fwd_kernel, scale=scale, causal=causal,
                           s=s, h=h, d=d, g=g),
         grid=(b,),
@@ -682,7 +705,8 @@ def _flash_flat_bwd(nhead, causal, scale, interpret, res, grad):
     # a non-8-multiple offset is not)
     lse4 = lse.reshape(b, h // g, g, s)
     delta4 = delta.reshape(b, h // g, g, s)
-    dqkv = pl.pallas_call(
+    dqkv = _named_call(
+        "flash_bwd",
         functools.partial(_flat_bwd_kernel, scale=scale, causal=causal,
                           s=s, h=h, d=d, g=g),
         grid=(b,),
@@ -972,7 +996,8 @@ def _flash_flatb_fwd(qkv, nhead, causal, scale, interpret):
     # hg + ih, v at 2*hg + ih (e = hg * g*d keeps these exact); see
     # _kv_col_idx for the causal DMA-reuse addressing.
     kidx, vidx = _kv_col_idx(hg, causal), _kv_col_idx(2 * hg, causal)
-    o, lse4 = pl.pallas_call(
+    o, lse4 = _named_call(
+        "flash_fwd",
         functools.partial(_flatb_fwd_kernel, scale=scale, causal=causal,
                           s=s, d=d, g=g, block=block),
         grid=(b, hg, nb, nb),
@@ -1016,7 +1041,8 @@ def _flash_flatb_bwd(nhead, causal, scale, interpret, res, grad):
                      * o.astype(jnp.float32).reshape(b, s, h, d),
                      axis=-1).transpose(0, 2, 1).reshape(b, hg, g, s)
     kidx, vidx = _kv_col_idx(hg, causal), _kv_col_idx(2 * hg, causal)
-    dq = pl.pallas_call(
+    dq = _named_call(
+        "flash_dq",
         functools.partial(_flatb_dq_kernel, scale=scale, causal=causal,
                           s=s, d=d, g=g, block=block),
         grid=(b, hg, nb, nb),
@@ -1047,7 +1073,8 @@ def _flash_flatb_bwd(nhead, causal, scale, interpret, res, grad):
                                      jnp.maximum(qb, ki)))
             if causal else
             (lambda ib, ih, ki, qb: (ib, ih, 0, qb)))
-    dk, dv = pl.pallas_call(
+    dk, dv = _named_call(
+        "flash_dkv",
         functools.partial(_flatb_dkv_kernel, scale=scale,
                           causal=causal, s=s, d=d, g=g, block=block),
         grid=(b, hg, nb, nb),

@@ -37,14 +37,18 @@ class StagedBatch:
 
     ``fused`` > 0 marks a STACKED group of that many batches staged as
     one transfer (Trainer.stage_fused); its device fields carry a
-    leading group axis."""
+    leading group axis. ``step`` is the batch's ordinal in its round
+    where a feed staged it (io/prefetch.py): the one identifier the
+    ``feed.stage``, ``feed.get`` and ``trainer.update`` spans of a
+    batch share across the two threads."""
 
-    __slots__ = ("device", "host", "fused")
+    __slots__ = ("device", "host", "fused", "step")
 
     def __init__(self, device, host: DataBatch, fused: int = 0) -> None:
         self.device = device
         self.host = host
         self.fused = fused
+        self.step = None
 
 
 class GroupStager:
@@ -282,6 +286,10 @@ class Trainer:
     def init_model(self) -> None:
         """Parse structure, init params, build jitted steps
         (reference: nnet_impl-inl.hpp:70-81,339-390)."""
+        with _trace.phase("trainer.init", "train"):
+            self._init_model()
+
+    def _init_model(self) -> None:
         self.net_cfg = NetConfig()
         self.net_cfg.configure(self.cfg)
         self._build_network()
@@ -1019,14 +1027,20 @@ class Trainer:
     def update(self, batch) -> None:
         """One minibatch of training (reference: nnet_impl-inl.hpp:141-185).
         Accepts a DataBatch or a StagedBatch from stage()."""
+        if isinstance(batch, StagedBatch) and batch.fused:
+            return self.update_fused(batch)
+        self._step_count += 1
+        with _trace.phase("trainer.update", "train",
+                          {"step_num": self._step_count, "fused": 0,
+                           "step": getattr(batch, "step", None)}):
+            self._update(batch)
+
+    def _update(self, batch) -> None:
         if isinstance(batch, StagedBatch):
-            if batch.fused:
-                return self.update_fused(batch)
             data, extras, labels = batch.device
         else:
             self._maybe_set_norm(batch)
             data, extras, labels = self._put_batch(batch)
-        self._step_count += 1
         if self.update_period == 1:
             if self._step_specs is None:
                 # abstract arg specs for step_cost_analysis (captured
@@ -1101,6 +1115,7 @@ class Trainer:
                  [jnp.stack(col)
                   for col in zip(*(s.device[2] for s in staged))]),
                 staged[0].host, fused=len(staged))
+            group.step = staged[-1].step
         if self._train_multi is None:
             raise RuntimeError(
                 "fuse_steps was not configured before init_model()")
@@ -1111,9 +1126,16 @@ class Trainer:
                 "micro-batches pending from per-step update() calls); "
                 "feed whole groups or finish the window unfused"
                 % (self.update_period, self.sample_counter))
-        data_s, extras_s, labels_s = group.device
         k = group.fused
         self._step_count += k
+        with _trace.phase("trainer.update", "train",
+                          {"step_num": self._step_count, "fused": k,
+                           "step": group.step}):
+            self._update_group(group)
+
+    def _update_group(self, group) -> None:
+        data_s, extras_s, labels_s = group.device
+        k = group.fused
         if self._step_specs is None:
             # per-step abstract specs (group element 0), so
             # step_cost_analysis reports ONE step's flops either path
@@ -1685,6 +1707,10 @@ class Trainer:
     def load_model(self, path: str) -> None:
         """Restore structure + epoch + weights (+ optimizer state, which
         the reference loses on resume — SURVEY.md §5)."""
+        with _trace.phase("trainer.init", "train"):
+            self._load_model(path)
+
+    def _load_model(self, path: str) -> None:
         from . import checkpoint
         self.wait_for_save()
         net_cfg, epoch, params, opt_state, _ = checkpoint.load_model(path)

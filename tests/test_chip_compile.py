@@ -59,6 +59,14 @@ def _compile(fn, one_chip, *shapes):
     return text
 
 
+def _kernel_names(text):
+    """The Mosaic kernels' HLO instruction names, numbers dropped: what
+    a device trace's ``XLA Ops`` events are called."""
+    import re
+    return {re.sub(r"[.\d]+$", "", m.group(1)) for m in re.finditer(
+        r"^\s*(?:ROOT )?%?([\w.\-]+) = .*tpu_custom_call", text, re.M)}
+
+
 def _paged_shapes(B=8, seqs=5):
     blocks = 1 + 4 * B * seqs
     pool = ((blocks, LAYERS, NH, PAGE, D), jnp.bfloat16)
@@ -94,15 +102,21 @@ def test_flash_attention_fwd_bwd_compiles(one_chip):
                                   interpret=False).astype(
                                       jnp.float32).sum()
 
-    _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), one_chip,
-             x, x, x)
+    text = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), one_chip,
+                    x, x, x)
+    assert _kernel_names(text) == {"flash_fwd", "flash_bwd"}
 
 
-@pytest.mark.parametrize("seq,batch", [(512, 16), (2048, 4)])
-def test_flash_attention_flat_fwd_bwd_compiles(one_chip, seq, batch):
+@pytest.mark.parametrize("seq,batch,kernels", [
+    (512, 16, {"flash_fwd", "flash_bwd"}),
+    (2048, 4, {"flash_fwd", "flash_dq", "flash_dkv"})])
+def test_flash_attention_flat_fwd_bwd_compiles(one_chip, seq, batch,
+                                               kernels):
     """seq 512 takes the single-block fused-backward kernels, seq 2048
     the blocked flat kernels — both on the (b, s, 3e) projection
-    layout the training stack feeds them."""
+    layout the training stack feeds them. Each kernel's instruction
+    carries its stable name (what ``flash_*_roofline.train`` match in
+    a chip trace)."""
     from cxxnet_tpu.ops import flash_attention as fa
     assert fa.supports_flat(seq, NH, D) or fa.flat_blocked_plan(
         seq, NH, D)
@@ -112,8 +126,9 @@ def test_flash_attention_flat_fwd_bwd_compiles(one_chip, seq, batch):
                                        interpret=False).astype(
                                            jnp.float32).sum()
 
-    _compile(jax.value_and_grad(loss), one_chip,
-             ((batch, seq, 3 * E), jnp.bfloat16))
+    text = _compile(jax.value_and_grad(loss), one_chip,
+                    ((batch, seq, 3 * E), jnp.bfloat16))
+    assert _kernel_names(text) == kernels
 
 
 def test_decode_attend_compiles(one_chip):

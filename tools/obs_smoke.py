@@ -127,7 +127,6 @@ def _train_leg(td, tr):
     """Overlapped feed + train steps under trace + telemetry; returns
     after scraping and checking both /metrics formats."""
     from cxxnet_tpu.io.prefetch import DevicePrefetchIterator
-    from cxxnet_tpu.obs import trace as obs_trace
     from cxxnet_tpu.obs.registry import get_registry
     from cxxnet_tpu.obs.telemetry import start_telemetry
     import numpy as np
@@ -140,8 +139,7 @@ def _train_leg(td, tr):
     for _ in range(2):
         feed.before_first()
         while feed.next():
-            with obs_trace.span("train.dispatch", "train"):
-                tr.update(feed.value)
+            tr.update(feed.value)      # its own trainer.update span
             steps += 1
     np.asarray(tr._epoch_dev)   # fence: every dispatched step ran
     assert steps > 0, "train leg produced no steps"
