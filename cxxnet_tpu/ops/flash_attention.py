@@ -34,6 +34,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 
@@ -727,18 +728,13 @@ _flash_flat.defvjp(_flash_flat_fwd, _flash_flat_bwd)
 
 
 # ----------------------------------------------------------------------
-# flat-layout BLOCKED kernels (multi-block sequences, r5): the same
+# flat-layout BLOCKED kernels (multi-block sequences): the same
 # zero-relayout property as the single-block flat path — kernels read
 # the projection's raw (b, s, 3e) output and write (b, s, e) — carried
 # past s = 512 by gridding over (batch, head group, q block, k block)
 # with COLUMN-SLICED BlockSpecs and SCRATCH accumulators: every
 # operand in VMEM is one (block, g*d) tile, so the footprint is
-# independent of sequence length. (A first design held each group's
-# whole (s, g*d) K/V panel per program and looped k in-kernel; the
-# compile-probe measured its true allocation at ~9.4 MB PER HEAD at
-# s=2048 — 18.75 MB even at the minimum g=2 — so the panel form
-# cannot fit the 16 MB scoped limit past s=1024. The probe log and
-# per-config actuals are recorded in docs/performance.md r5.)
+# independent of sequence length.
 #
 # Grid order puts the k (or q) block index innermost; the
 # online-softmax / gradient accumulators live in VMEM scratch that
@@ -747,81 +743,139 @@ _flash_flat.defvjp(_flash_flat_fwd, _flash_flat_bwd)
 # flash schedule. Causal block-skipping uses jnp.minimum/maximum in
 # the INDEX MAPS: a masked-out step re-addresses the previous block,
 # so Pallas re-uses the fetched tile instead of issuing a new DMA.
+#
+# Inside a grid step (PR 26) nothing is held as one (g, block, block)
+# score block: the step walks UNITS, fully unrolled — one head's
+# ``sub`` keys against all the block's queries — each a (sub, block)
+# f32 score tile whose matmuls are four 128-lane weight tiles wide,
+# one for each MXU. Scores are held KEYS ON SUBLANES, QUERIES ON LANES:
+#
+# * the softmax statistics m, l, lse, delta are (1, block) lane rows
+#   that broadcast along sublanes for free onto the scores and onto
+#   the (d, block) accumulators, and the max / sum over keys are
+#   elementwise across vregs plus one 8-to-1 sublane fold;
+# * q . k needs no transpose at all: the tile as loaded, (n, g*d), is
+#   the matmul operand, with the OTHER heads' lanes of the
+#   step-invariant side zeroed once per tile (``_masked_heads``) — at
+#   d = 64 a 128-deep MXU pass is half empty anyway, so contracting
+#   over a 128-lane window costs what contracting over d does;
+# * every product that sums over keys or queries into a (d, n)
+#   accumulator streams its d rows through the MXU (64 cycles a
+#   128 x 128 tile where the other orientation takes 128); its
+#   transposed operand (v forward, k in dq, q and do in dkv) is
+#   transposed once per step for all heads;
+# * a diagonal grid step (kb == qi) is its own body under ``pl.when``:
+#   a unit starts at the first query its keys can reach and masks only
+#   the lanes the diagonal crosses; an off-diagonal step carries no
+#   mask at all, and a grid of one block has no off-diagonal body.
+#
 # The backward is the split dq / dkv pair in flat I/O; the three
 # (b, s, e) grads concatenate into dqkv at the end — ~1/4 of the
 # relayout traffic this path deletes, and XLA can fuse the concat
 # into the consuming projection-VJP matmuls.
 # ----------------------------------------------------------------------
+LANES = 128
+FLATB_BLOCKS = (1024, 512, 256, 128)    # preference, largest first
+FLATB_SUBS = (512, 256, 256)            # keys a unit in fwd, dq, dkv
+FLATB_MAX_GROUP = 2      # heads a grid step: the bodies unroll g-fold
+
+
 def flat_blocked_plan(s: int, h: int, d: int,
                       budget: int = 13 * 1024 * 1024):
-    """(g, block) for the blocked flat kernels, or None when they
-    don't apply. The VMEM estimate is EXPLICIT per kernel
-    (_flatb_vmem: tiles double-buffered, f32 intermediates and
-    scratch itemized) and CALIBRATED against on-chip compile-probe
-    actuals (VERDICT r4 #6); the 13 MB budget leaves a 3 MB margin
-    under the 16 MB scoped limit for Mosaic's own spills (the (2,512)
-    gpt2 pick estimates 12.5 MB and compiles). Prefers the largest
-    block (the r3 sweep: 512-wide ~1.7x faster than 128) and then the
-    largest head group that fit.
+    """(g, block, sub_fwd, sub_dq, sub_dkv) for the blocked flat
+    kernels, or None when they don't apply: g heads and one (block,
+    block) pair of q and k tiles a grid step, walked in units of
+    ``sub`` keys against the block's queries. Prefers the largest
+    block, then the largest head group up to FLATB_MAX_GROUP, whose
+    itemized ``_flatb_vmem`` estimate fits the budget (the default
+    leaves 3 MB under Mosaic's 16 MB scoped limit).
 
-    Gated to s <= 3072: measured on-chip (r5 longseq, interleaved
-    with generic anchors), the flat blocked kernels win at 2048
-    (102.3k vs 96.2k tok/s) but the nb^2 grid-program overhead of the
-    scratch-accumulator schedule crosses over at 4096 (72.2k vs
-    74.0k) — longer sequences keep the generic in-kernel-loop path."""
+    Measured on the chip (PR 26; one layer's forward + backward, bf16,
+    causal; us = flash_fwd + flash_dq + flash_dkv, which took 823 +
+    519 + 631 before): at b 8, s 1024, h 16, d 64 block 1024 with
+    units of 512 / 256 / 256 keys takes 292 + 348 + 413 us, each the
+    fastest of {128, 256, 512} for its kernel (fwd 306 / 305 / 292, dq
+    385 / 348 / 382, dkv 440 / 413 / 457); block 512 took 1,510 in
+    all and block 256 2,587 (earlier bodies, which block 1024 ran in
+    1,154); g 4 takes 4 % less than g 2 and twice as long to compile,
+    so g stops at 2. At s 2048 (b 4) block 1024 takes 1,436 us at
+    h 12 and 1,902 at h 16 where block 512 took 1,925 and 2,600 and
+    the kernels before 2,437 and 3,276.
+
+    Gated to s <= 3072: past it the nb^2 grid of the
+    scratch-accumulator schedule is expected to lose to the generic
+    in-kernel-loop path (not measured for these bodies)."""
     if _pick_block(s) == s:
         return None                  # single-block: the fused path
+    if LANES % d and d % LANES:
+        return None                  # a head would straddle a window
     import os
     ov = os.environ.get("CXXNET_FLATB_PLAN")
     if ov:
-        # experiment override "g,block" — checked BEFORE the length
-        # gate (its whole point is probing past the crossover), and
-        # validated: an un-checked g would silently skip heads
-        # (hg = h // g truncates) and a non-dividing block only fails
-        # with a cryptic Mosaic grid error
-        g, block = (int(x) for x in ov.split(","))
-        if h % g or (g * d) % 128 or s % block:
+        # experiment override "g,block[,sub | ,sub_fwd,sub_dq,sub_dkv]"
+        # — checked BEFORE the length gate (its whole point is probing
+        # past the crossover), and validated: an un-checked g would
+        # silently skip heads (hg = h // g truncates) and a
+        # non-dividing block only fails with a cryptic Mosaic grid
+        # error
+        g, block, *subs = (int(x) for x in ov.split(","))
+        subs = tuple(min(x, block) for x in (
+            subs * 3 if len(subs) == 1 else subs or FLATB_SUBS))
+        if h % g or (g * d) % 128 or s % block or len(subs) != 3 \
+                or any(block % x or x % LANES for x in subs):
             raise ValueError(
                 "CXXNET_FLATB_PLAN=%s invalid for s=%d h=%d d=%d: "
-                "need h %% g == 0, (g*d) %% 128 == 0, s %% block == 0"
-                % (ov, s, h, d))
-        return (g, block)
+                "need h %% g == 0, (g*d) %% 128 == 0, s %% block == 0, "
+                "one or three subs with block %% sub == 0 and "
+                "sub %% 128 == 0" % (ov, s, h, d))
+        return (g, block) + subs
     if s > 3072:
-        return None                  # measured crossover (r5)
-    # block-major preference: the r3 sweep measured 512-wide blocks
-    # ~1.7x faster than 128 on the generic kernels (MXU amortization),
-    # so a big block with a smaller group beats the reverse
-    for block in (512, 256, 128):
+        return None
+    for block in FLATB_BLOCKS:
         if s % block:
             continue
-        for g in range(h, 0, -1):
-            if h % g or (g * d) % 128:
-                continue
-            if max(_flatb_vmem(s, h, d, g, block)) <= budget:
-                return (g, block)
+        subs = tuple(min(x, block) for x in FLATB_SUBS)
+        fit = [g for g in range(1, h + 1)
+               if not (h % g or (g * d) % 128)
+               and max(_flatb_vmem(d, g, block, subs)) <= budget]
+        if fit:
+            # up to FLATB_MAX_GROUP heads; more only where fewer
+            # cannot fill a 128-lane window (d < 64)
+            small = [g for g in fit if g <= FLATB_MAX_GROUP]
+            return (max(small) if small else fit[0], block) + subs
     return None
 
 
-def _flatb_vmem(s, h, d, g, block):
-    """Explicit per-kernel VMEM estimates (fwd, dq, dkv) in bytes.
-    Every operand is a (block, g*d) tile (sequence-length independent);
-    the probe-measured Mosaic overhead for the transposed (g, d, n)
-    working copies and mask/iota buffers rides the 1.5x factor on the
-    f32 score blocks."""
-    blk = block * g * d * 2               # one (block, g*d) bf16 tile
-    sq_f32 = g * block * block * 4        # one f32 (g, bq, bk) buffer
-    carry = g * d * block * 4             # one f32 (g, d, block) scratch
-    stat = g * block * 4
-    # fwd: q/k/v in + o out tiles (x2 double-buffer), logits+p f32 +
-    # pc bf16 (+50% working margin), m/l/acc scratch, lse out
-    fwd = 2 * (4 * blk) + int(2.5 * sq_f32 * 1.5) + carry + 3 * stat
-    # dq: q/k/v/do in + dq out tiles, logits/p/dp f32 + ds bf16,
-    # dq scratch, lse/delta tiles
-    dq = 2 * (5 * blk) + int(3.5 * sq_f32 * 1.5) + carry + 4 * stat
-    # dkv: q/k/v/do in + dk/dv out tiles, same intermediates, two
-    # scratch accumulators
-    dkv = 2 * (6 * blk) + int(3.5 * sq_f32 * 1.5) + 2 * carry + 4 * stat
-    return fwd, dq, dkv
+def _head_window(hh, d):
+    """[lo, hi): the 128-aligned lane window of a (n, g*d) tile that
+    holds head ``hh``'s d columns."""
+    return (hh * d) // LANES * LANES, -(-(hh + 1) * d // LANES) * LANES
+
+
+def _flatb_vmem(d, g, block, subs):
+    """Itemized per-kernel VMEM estimates (fwd, dq, dkv) in bytes.
+    Every operand is a (block, g*d) tile, double-buffered by the
+    pipeline (sequence-length independent); the scratch is counted as
+    allocated; a unit's f32 intermediates (scores, p, and in the
+    backward dp, ds: each (sub, block)) are counted whole, since
+    Mosaic spills them.
+
+    Against Mosaic's own allocation for a described v5e (PR 26, the
+    compiler's dump; no chip), g 2, block 1024, units of 512 / 256 /
+    256 keys: 5.3 / 5.4 / 7.4 MB at s 1024 and 8.8 / 7.0 / 8.8 MB at
+    s 2048, whose off-diagonal body spills more, where this says
+    9.5 / 8.5 / 10.0."""
+    w = _head_window(0, d)[1]             # lanes of one head's window
+    tile = block * g * d * 2              # one (block, g*d) bf16 tile
+    masked = g * block * w * 2            # per-head masked copy, bf16
+    acc_t = g * d * block * 4             # (g, d, block) f32
+    stat = g * 8 * block * 4              # (g, 1, block) rows pad to 8
+    unit = [n * sub * block * 4 for n, sub in zip((3, 4, 4), subs)]
+    # pipelined tiles and statistics rows (x 2 buffers), then scratch
+    fwd = 2 * (4 * tile + stat) + masked + tile + 2 * stat + acc_t
+    dq = 2 * (5 * tile + 2 * stat) + 2 * masked + tile + acc_t
+    dkv = 2 * (6 * tile + 2 * stat) + 2 * masked + 3 * tile + 2 * acc_t
+    return fwd + unit[0], dq + unit[1], dkv + unit[2]
 
 
 def _kv_col_idx(col_off, causal):
@@ -835,135 +889,217 @@ def _kv_col_idx(col_off, causal):
     return lambda ib, ih, qi, kb: (ib, kb, col_off + ih)
 
 
-def _t3(mat, g, d):
-    """(n, g*d) minor-sliced tile -> (g, d, n): 2D transpose then a
-    SUBLANE split — the only shape cast Mosaic accepts at d < 128."""
-    n = mat.shape[0]
-    return mat.T.reshape(g, d, n)
+def _rounded(value, dtype):
+    """``value`` as ``dtype`` holds it: multiplying a tile by it in f32
+    and rounding once is then ``tile * value`` in the tile's dtype."""
+    return float(np.asarray(value, dtype))
+
+
+def _masked_heads(ref, dst, g, d, value=1.0):
+    """dst[hh] = the (n, w) lane window of the (1, n, g*d) tile ``ref``
+    around head hh, times ``value`` on the head's own d lanes and zero
+    on its neighbours': contracting it over the window against an
+    unmasked tile contracts over the head's d columns alone."""
+    value = _rounded(value, ref.dtype)
+    for hh in range(g):
+        lo, hi = _head_window(hh, d)
+        lane = lo + lax.broadcasted_iota(jnp.int32, (1, hi - lo), 1)
+        mask = jnp.where((lane >= hh * d) & (lane < (hh + 1) * d),
+                         jnp.float32(value), 0.0)
+        dst[hh] = (ref[0, :, lo:hi].astype(jnp.float32)
+                   * mask).astype(dst.dtype)
+
+
+_NT = (((1,), (1,)), ((), ()))       # a @ b.T
+_NN = (((1,), (0,)), ((), ()))       # a @ b
+
+
+def _dot(a, b, dims):
+    return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _units(diag, block, sub):
+    """(k0, lo) of one grid step's units: the keys [k0, k0 + sub)
+    against the queries [lo, block) — all of them off the diagonal; on
+    it those from k0 on, since no earlier query sees these keys."""
+    return [(k0, k0 if diag else 0) for k0 in range(0, block, sub)]
+
+
+def _causal_unit(st, diag):
+    """A diagonal step's unit ``st`` (sub, width), its keys and its
+    queries starting at the same position, with NEG_INF where the key
+    lies after the query: only in the first ``sub`` lanes."""
+    if not diag:
+        return st
+    sub = st.shape[0]
+    keep = (lax.broadcasted_iota(jnp.int32, (sub, sub), 0)
+            <= lax.broadcasted_iota(jnp.int32, (sub, sub), 1))
+    return _lanes_from(jnp.where(keep, st[:, :sub], NEG_INF), sub,
+                       st[:, sub:])
+
+
+def _lanes_from(old, a, new):
+    """The lanes [:a] of ``old``, then ``new``."""
+    if not a:
+        return new
+    if not new.shape[1]:
+        return old[:, :a]
+    return jnp.concatenate([old[:, :a], new], axis=1)
+
+
+def _when_causal(causal, nb, on_diag, below, work):
+    """Run ``work(diag)`` for this grid step. Not causal: always, with
+    no mask. Causal: the steps strictly below the diagonal (``below``;
+    there are none in a grid of one block) with no mask, the diagonal
+    step (``on_diag``) with it, the steps above not at all."""
+    if not causal:
+        work(False)
+        return
+    if nb > 1:
+        pl.when(below)(lambda: work(False))
+    pl.when(on_diag)(lambda: work(True))
 
 
 def _flatb_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                      m_s, l_s, acc_s, *, scale, causal, s, d, g,
-                      block):
+                      qm_s, vt_s, m_s, l_s, acc_s, *, scale, causal,
+                      d, g, block, sub, nb):
     qi, kb = pl.program_id(2), pl.program_id(3)
-    nk = pl.num_programs(3)
 
     @pl.when(kb == 0)
     def _init():
         m_s[...] = jnp.full_like(m_s, NEG_INF)
         l_s[...] = jnp.zeros_like(l_s)
         acc_s[...] = jnp.zeros_like(acc_s)
+        # the q tile is the same for every kb: scale and mask it once
+        _masked_heads(q_ref, qm_s, g, d, scale)
 
-    @pl.when(jnp.logical_not(causal) | (kb <= qi))
-    def _work():
-        qe = _t3(q_ref[0], g, d) * scale                # (g, d, bq)
-        kt = _t3(k_ref[0], g, d)
-        vt = _t3(v_ref[0], g, d)
-        logits = lax.dot_general(qe, kt, (((1,), (1,)), ((0,), (0,))),
-                                 preferred_element_type=jnp.float32)
-        if causal:
-            logits = jnp.where(
-                _causal_mask(qi, kb, block, block)[None],
-                logits, NEG_INF)
-        m, l = m_s[...], l_s[...]
-        mb = jnp.max(logits, axis=-1)                   # (g, bq)
-        m2 = jnp.maximum(m, mb)
-        p = jnp.exp(logits - m2[..., None])
-        corr = jnp.exp(m - m2)
-        m_s[...] = m2
-        l_s[...] = l * corr + p.sum(axis=-1)
-        # acc[g, d, i] += sum_j v[g, d, j] p[g, i, j]
-        acc_s[...] = acc_s[...] * corr[:, None, :] + lax.dot_general(
-            vt, p.astype(vt.dtype), (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
+    def work(diag):
+        vt_s[...] = v_ref[0].T                          # (g*d, bk)
+        for hh in range(g):
+            lo_c, hi_c = _head_window(hh, d)
+            m, l, acc = m_s[hh], l_s[hh], acc_s[hh]     # (1 | d, bq)
+            for k0, lo in _units(diag, block, sub):
+                # st[j, i] = k_j . q_i: keys on sublanes
+                st = _causal_unit(_dot(
+                    k_ref[0, k0:k0 + sub, lo_c:hi_c],
+                    qm_s[hh, lo:, :], _NT), diag)
+                m1 = m[:, lo:]
 
-    @pl.when(kb == nk - 1)
+                # the unit's softmax, one 128-query group at a time:
+                # a group's scores (sub / 8 vregs) can stay in
+                # registers from the MXU's pop to the cast that feeds
+                # the next matmul, where the whole unit's cannot
+                # (312 -> 292 us a layer, my chip run, PR 26; the
+                # same order in dq and dkv lost 6 and 15 us)
+                m2, psum, p = [], [], []
+                for j in range(0, block - lo, LANES):
+                    sj = st[:, j:j + LANES]
+                    mj = jnp.maximum(m1[:, j:j + LANES],
+                                     jnp.max(sj, axis=0, keepdims=True))
+                    pj = jnp.exp(sj - mj)
+                    m2.append(mj)
+                    psum.append(jnp.sum(pj, axis=0, keepdims=True))
+                    p.append(pj.astype(vt_s.dtype))
+                m2, psum, p = (jnp.concatenate(x, axis=1)
+                               for x in (m2, psum, p))
+                corr = jnp.exp(m1 - m2)
+                l = _lanes_from(l, lo, l[:, lo:] * corr + psum)
+                # acc[c, i] += sum_j v[j, c] p[j, i]
+                acc = _lanes_from(acc, lo, acc[:, lo:] * corr + _dot(
+                    vt_s[hh * d:(hh + 1) * d, k0:k0 + sub], p, _NN))
+                m = _lanes_from(m, lo, m2)
+            m_s[hh], l_s[hh], acc_s[hh] = m, l, acc
+
+    _when_causal(causal, nb, kb == qi, kb < qi, work)
+
+    @pl.when(kb == nb - 1)
     def _flush():
-        lsafe = jnp.maximum(l_s[...], 1e-30)
-        o_ref[0] = (acc_s[...] / lsafe[:, None, :]).reshape(
+        lsafe = jnp.maximum(l_s[...], 1e-30)            # (g, 1, bq)
+        o_ref[0] = (acc_s[...] / lsafe).reshape(
             g * d, block).T.astype(o_ref.dtype)
         lse_ref[0, 0] = m_s[...] + jnp.log(lsafe)
 
 
 def _flatb_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                     dq_ref, dq_s, *, scale, causal, s, d, g, block):
+                     dq_ref, qm_s, dom_s, kt_s, dq_s, *, scale, causal,
+                     d, g, block, sub, nb):
     qi, kb = pl.program_id(2), pl.program_id(3)
-    nk = pl.num_programs(3)
 
     @pl.when(kb == 0)
     def _init():
         dq_s[...] = jnp.zeros_like(dq_s)
+        _masked_heads(q_ref, qm_s, g, d, scale)
+        _masked_heads(do_ref, dom_s, g, d)
 
-    @pl.when(jnp.logical_not(causal) | (kb <= qi))
-    def _work():
-        qe = _t3(q_ref[0], g, d) * scale
-        kt = _t3(k_ref[0], g, d)
-        vt = _t3(v_ref[0], g, d)
-        dot = _t3(do_ref[0], g, d)
-        lse = lse_ref[0, 0]                             # (g, bq)
-        delta = delta_ref[0, 0]
-        logits = lax.dot_general(qe, kt, (((1,), (1,)), ((0,), (0,))),
-                                 preferred_element_type=jnp.float32)
-        if causal:
-            logits = jnp.where(
-                _causal_mask(qi, kb, block, block)[None],
-                logits, NEG_INF)
-        p = jnp.exp(logits - lse[..., None])            # (g, bq, bk)
-        dp = lax.dot_general(dot, vt, (((1,), (1,)), ((0,), (0,))),
-                             preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta[..., None])).astype(kt.dtype)
-        # dq[g, d, i] += sum_j k[g, d, j] ds[g, i, j]
-        dq_s[...] = dq_s[...] + lax.dot_general(
-            kt, ds, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
+    def work(diag):
+        kt_s[...] = k_ref[0].T                          # (g*d, bk)
+        for hh in range(g):
+            lo_c, hi_c = _head_window(hh, d)
+            dq = dq_s[hh]                               # (d, bq)
+            for k0, lo in _units(diag, block, sub):
+                st = _causal_unit(_dot(
+                    k_ref[0, k0:k0 + sub, lo_c:hi_c],
+                    qm_s[hh, lo:, :], _NT), diag)
+                p = jnp.exp(st - lse_ref[0, 0, hh, :, lo:])
+                dp = _dot(v_ref[0, k0:k0 + sub, lo_c:hi_c],
+                          dom_s[hh, lo:, :], _NT)
+                ds = (p * (dp - delta_ref[0, 0, hh, :, lo:])
+                      ).astype(kt_s.dtype)
+                # dq[c, i] += sum_j k[j, c] ds[j, i]
+                dq = _lanes_from(dq, lo, dq[:, lo:] + _dot(
+                    kt_s[hh * d:(hh + 1) * d, k0:k0 + sub], ds, _NN))
+            dq_s[hh] = dq
 
-    @pl.when(kb == nk - 1)
+    _when_causal(causal, nb, kb == qi, kb < qi, work)
+
+    @pl.when(kb == nb - 1)
     def _flush():
         dq_ref[0] = (dq_s[...] * scale).reshape(
             g * d, block).T.astype(dq_ref.dtype)
 
 
 def _flatb_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                      dk_ref, dv_ref, dk_s, dv_s, *, scale, causal,
-                      s, d, g, block):
+                      dk_ref, dv_ref, km_s, vm_s, qs_s, qt_s, dot_s,
+                      dk_s, dv_s, *, scale, causal, d, g, block, sub,
+                      nb):
     ki, qb = pl.program_id(2), pl.program_id(3)
-    nq = pl.num_programs(3)
 
     @pl.when(qb == 0)
     def _init():
         dk_s[...] = jnp.zeros_like(dk_s)
         dv_s[...] = jnp.zeros_like(dv_s)
+        # the k and v tiles are the same for every qb
+        _masked_heads(k_ref, km_s, g, d)
+        _masked_heads(v_ref, vm_s, g, d)
 
-    @pl.when(jnp.logical_not(causal) | (qb >= ki))
-    def _work():
-        kt = _t3(k_ref[0], g, d)                        # (g, d, bk)
-        vt = _t3(v_ref[0], g, d)
-        qe = _t3(q_ref[0], g, d) * scale
-        dot = _t3(do_ref[0], g, d)
-        lse = lse_ref[0, 0]
-        delta = delta_ref[0, 0]
-        logits = lax.dot_general(qe, kt, (((1,), (1,)), ((0,), (0,))),
-                                 preferred_element_type=jnp.float32)
-        if causal:
-            logits = jnp.where(
-                _causal_mask(qb, ki, block, block)[None],
-                logits, NEG_INF)
-        p = jnp.exp(logits - lse[..., None])            # (g, bq, bk)
-        # dv[g, d, j] += sum_i do[g, d, i] p[g, i, j]
-        dv_s[...] = dv_s[...] + lax.dot_general(
-            dot, p.astype(dot.dtype), (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
-        dp = lax.dot_general(dot, vt, (((1,), (1,)), ((0,), (0,))),
-                             preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta[..., None])).astype(qe.dtype)
-        # dk[g, d, j] += sum_i q_eff[g, d, i] ds[g, i, j] (qe carries
-        # the scale, so dk needs no further factor — chain-rule note
-        # in _bwd1_kernel)
-        dk_s[...] = dk_s[...] + lax.dot_general(
-            qe, ds, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
+    def work(diag):
+        # the scaled q: scores carry the factor, and dk accumulated
+        # against it needs no further one (chain-rule note in
+        # _bwd1_kernel)
+        qs_s[...] = (q_ref[0].astype(jnp.float32)
+                     * _rounded(scale, qs_s.dtype)).astype(qs_s.dtype)
+        qt_s[...] = qs_s[...].T                         # (g*d, bq)
+        dot_s[...] = do_ref[0].T
+        for hh in range(g):
+            lo_c, hi_c = _head_window(hh, d)
+            rows = slice(hh * d, (hh + 1) * d)
+            for k0, lo in _units(diag, block, sub):
+                ks = slice(k0, k0 + sub)
+                st = _causal_unit(_dot(
+                    km_s[hh, ks, :], qs_s[lo:, lo_c:hi_c], _NT), diag)
+                p = jnp.exp(st - lse_ref[0, 0, hh, :, lo:])
+                dp = _dot(vm_s[hh, ks, :], do_ref[0, lo:, lo_c:hi_c],
+                          _NT)
+                ds = p * (dp - delta_ref[0, 0, hh, :, lo:])
+                # dv[c, j] += sum_i do[i, c] p[j, i]
+                dv_s[hh, :, ks] += _dot(dot_s[rows, lo:],
+                                        p.astype(dot_s.dtype), _NT)
+                dk_s[hh, :, ks] += _dot(qt_s[rows, lo:],
+                                        ds.astype(qt_s.dtype), _NT)
 
-    @pl.when(qb == nq - 1)
+    _when_causal(causal, nb, qb == ki, qb > ki, work)
+
+    @pl.when(qb == nb - 1)
     def _flush():
         dk_ref[0] = dk_s[...].reshape(g * d, block).T.astype(
             dk_ref.dtype)
@@ -977,29 +1113,75 @@ def _flash_flatb(qkv, nhead, causal, scale, interpret):
     return out
 
 
-def _flash_flatb_fwd(qkv, nhead, causal, scale, interpret):
-    from jax.experimental.pallas import tpu as pltpu
-    b, s, e3 = qkv.shape
+def _flatb_plan(kernels, qkv, nhead):
+    """-> (plan, args): the plan of ``qkv``'s shape and what its
+    ``flash.plan`` marker says of it, so a capture or a ``trace_out=``
+    file shows which schedule the shape took (``kernels`` = "fwd" or
+    "bwd")."""
+    _, s, e3 = qkv.shape
     h, d = nhead, e3 // (3 * nhead)
-    if scale is None:
-        scale = d ** -0.5
     plan = flat_blocked_plan(s, h, d)
     if plan is None:
         raise ValueError(
             "flash_attention_flat: unsupported blocked shape s=%d h=%d "
             "d=%d (callers must consult flat_blocked_plan)" % (s, h, d))
-    g, block = plan
+    g, block, *subs = plan
+    fwd, dq, dkv = _flatb_vmem(d, g, block, subs)
+    args = {"kernels": kernels, "s": s, "h": h, "d": d, "g": g,
+            "block_q": block, "block_k": block}
+    if kernels == "fwd":
+        args.update(sub=subs[0], vmem_bytes=fwd)
+    else:
+        args.update(sub=subs[1], sub_dkv=subs[2],
+                    vmem_bytes=max(dq, dkv))
+    return plan, args
+
+
+def _flash_flatb_fwd(qkv, nhead, causal, scale, interpret):
+    from ..obs import trace
+    plan, mark = _flatb_plan("fwd", qkv, nhead)
+    with trace.span("flash.plan", "kernel", mark):
+        o, lse5 = _flatb_fwd_call(qkv, nhead, causal, scale, interpret,
+                                  plan)
+    return o, (qkv, o, lse5)
+
+
+def _flash_flatb_bwd(nhead, causal, scale, interpret, res, grad):
+    from ..obs import trace
+    qkv, o, lse5 = res
+    plan, mark = _flatb_plan("bwd", qkv, nhead)
+    with trace.span("flash.plan", "kernel", mark):
+        return (_flatb_bwd_call(qkv, o, lse5, grad, nhead, causal, scale,
+                                interpret, plan),)
+
+
+# The two calls below are jitted on their own so that a model's n
+# layers trace and lower these kernels once and not n times: a stack
+# unrolled in Python (scan_unroll >= nlayer) otherwise pays the
+# unrolled bodies' tracing and lowering per layer and per build (24
+# layers of the benchmark's cell: +12 s a build of the train step, my
+# chip run, PR 26). The plan is an argument so that it is part of the
+# cache's key.
+@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4, 5))
+def _flatb_fwd_call(qkv, nhead, causal, scale, interpret, plan):
+    from jax.experimental.pallas import tpu as pltpu
+    b, s, e3 = qkv.shape
+    h, d = nhead, e3 // (3 * nhead)
+    if scale is None:
+        scale = d ** -0.5
+    g, block, sub, _, _ = plan
     hg, e = h // g, h * d
     nb = s // block
+    w = _head_window(0, d)[1]
     # qkv passed three times with column-sliced BlockSpecs: the column
     # block unit is g*d, so q group ih sits at column block ih, k at
     # hg + ih, v at 2*hg + ih (e = hg * g*d keeps these exact); see
     # _kv_col_idx for the causal DMA-reuse addressing.
     kidx, vidx = _kv_col_idx(hg, causal), _kv_col_idx(2 * hg, causal)
-    o, lse4 = _named_call(
+    return _named_call(
         "flash_fwd",
         functools.partial(_flatb_fwd_kernel, scale=scale, causal=causal,
-                          s=s, d=d, g=g, block=block),
+                          d=d, g=g, block=block, sub=sub, nb=nb),
         grid=(b, hg, nb, nb),
         in_specs=[
             pl.BlockSpec((1, block, g * d),
@@ -1010,103 +1192,98 @@ def _flash_flatb_fwd(qkv, nhead, causal, scale, interpret):
         out_specs=[
             pl.BlockSpec((1, block, g * d),
                          lambda ib, ih, qi, kb: (ib, qi, ih)),
-            pl.BlockSpec((1, 1, g, block),
-                         lambda ib, ih, qi, kb: (ib, ih, 0, qi)),
+            # statistics as (1, block) lane rows, one per head
+            pl.BlockSpec((1, 1, g, 1, block),
+                         lambda ib, ih, qi, kb: (ib, ih, 0, 0, qi)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, s, e), qkv.dtype),
-            jax.ShapeDtypeStruct((b, hg, g, s), jnp.float32),
+            jax.ShapeDtypeStruct((b, hg, g, 1, s), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((g, block), jnp.float32),
-            pltpu.VMEM((g, block), jnp.float32),
-            pltpu.VMEM((g, d, block), jnp.float32),
+            pltpu.VMEM((g, block, w), qkv.dtype),       # masked q
+            pltpu.VMEM((g * d, block), qkv.dtype),      # v.T
+            pltpu.VMEM((g, 1, block), jnp.float32),     # m
+            pltpu.VMEM((g, 1, block), jnp.float32),     # l
+            pltpu.VMEM((g, d, block), jnp.float32),     # acc
         ],
         interpret=interpret,
     )(qkv, qkv, qkv)
-    return o, (qkv, o, lse4)
 
 
-def _flash_flatb_bwd(nhead, causal, scale, interpret, res, grad):
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8))
+def _flatb_bwd_call(qkv, o, lse5, grad, nhead, causal, scale, interpret,
+                    plan):
     from jax.experimental.pallas import tpu as pltpu
-    qkv, o, lse4 = res
     b, s, e3 = qkv.shape
     h, d = nhead, e3 // (3 * nhead)
     if scale is None:
         scale = d ** -0.5
-    g, block = flat_blocked_plan(s, h, d)
+    g, block, _, sub_dq, sub_dkv = plan
     hg, e = h // g, h * d
     nb = s // block
-    delta4 = jnp.sum(grad.astype(jnp.float32).reshape(b, s, h, d)
+    w = _head_window(0, d)[1]
+    delta5 = jnp.sum(grad.astype(jnp.float32).reshape(b, s, h, d)
                      * o.astype(jnp.float32).reshape(b, s, h, d),
-                     axis=-1).transpose(0, 2, 1).reshape(b, hg, g, s)
+                     axis=-1).transpose(0, 2, 1).reshape(b, hg, g, 1, s)
     kidx, vidx = _kv_col_idx(hg, causal), _kv_col_idx(2 * hg, causal)
-    dq = _named_call(
-        "flash_dq",
-        functools.partial(_flatb_dq_kernel, scale=scale, causal=causal,
-                          s=s, d=d, g=g, block=block),
-        grid=(b, hg, nb, nb),
-        in_specs=[
-            pl.BlockSpec((1, block, g * d),
-                         lambda ib, ih, qi, kb: (ib, qi, ih)),
-            pl.BlockSpec((1, block, g * d), kidx),
-            pl.BlockSpec((1, block, g * d), vidx),
-            pl.BlockSpec((1, block, g * d),
-                         lambda ib, ih, qi, kb: (ib, qi, ih)),
-            pl.BlockSpec((1, 1, g, block),
-                         lambda ib, ih, qi, kb: (ib, ih, 0, qi)),
-            pl.BlockSpec((1, 1, g, block),
-                         lambda ib, ih, qi, kb: (ib, ih, 0, qi)),
-        ],
-        out_specs=pl.BlockSpec((1, block, g * d),
-                               lambda ib, ih, qi, kb: (ib, qi, ih)),
-        out_shape=jax.ShapeDtypeStruct((b, s, e), qkv.dtype),
-        scratch_shapes=[pltpu.VMEM((g, d, block), jnp.float32)],
-        interpret=interpret,
-    )(qkv, qkv, qkv, grad, lse4, delta4)
+    tile = lambda idx: pl.BlockSpec((1, block, g * d), idx)
+    stat = lambda idx: pl.BlockSpec((1, 1, g, 1, block), idx)
+    params = dict(scale=scale, causal=causal, d=d, g=g, block=block,
+                  nb=nb)
+    at_q = lambda ib, ih, qi, kb: (ib, qi, ih)
+    stat_q = lambda ib, ih, qi, kb: (ib, ih, 0, 0, qi)
     # dkv grid: q block innermost; a causal-skipped q step (qb < ki)
     # re-addresses block max(qb, ki) — no new DMA
-    qidx = ((lambda ib, ih, ki, qb: (ib, jnp.maximum(qb, ki), ih))
-            if causal else
-            (lambda ib, ih, ki, qb: (ib, qb, ih)))
-    sidx = ((lambda ib, ih, ki, qb: (ib, ih, 0,
-                                     jnp.maximum(qb, ki)))
-            if causal else
-            (lambda ib, ih, ki, qb: (ib, ih, 0, qb)))
-    dk, dv = _named_call(
-        "flash_dkv",
-        functools.partial(_flatb_dkv_kernel, scale=scale,
-                          causal=causal, s=s, d=d, g=g, block=block),
+    if causal:
+        qidx = lambda ib, ih, ki, qb: (ib, jnp.maximum(qb, ki), ih)
+        sidx = lambda ib, ih, ki, qb: (ib, ih, 0, 0,
+                                       jnp.maximum(qb, ki))
+    else:
+        qidx = lambda ib, ih, ki, qb: (ib, qb, ih)
+        sidx = lambda ib, ih, ki, qb: (ib, ih, 0, 0, qb)
+    dq = _named_call(
+        "flash_dq",
+        functools.partial(_flatb_dq_kernel, sub=sub_dq, **params),
         grid=(b, hg, nb, nb),
-        in_specs=[
-            pl.BlockSpec((1, block, g * d), qidx),
-            pl.BlockSpec((1, block, g * d),
-                         lambda ib, ih, ki, qb: (ib, ki, hg + ih)),
-            pl.BlockSpec((1, block, g * d),
-                         lambda ib, ih, ki, qb: (ib, ki, 2 * hg + ih)),
-            pl.BlockSpec((1, block, g * d), qidx),
-            pl.BlockSpec((1, 1, g, block), sidx),
-            pl.BlockSpec((1, 1, g, block), sidx),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block, g * d),
-                         lambda ib, ih, ki, qb: (ib, ki, ih)),
-            pl.BlockSpec((1, block, g * d),
-                         lambda ib, ih, ki, qb: (ib, ki, ih)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, s, e), qkv.dtype),
-            jax.ShapeDtypeStruct((b, s, e), qkv.dtype),
-        ],
+        in_specs=[tile(at_q), tile(kidx), tile(vidx), tile(at_q),
+                  stat(stat_q), stat(stat_q)],
+        out_specs=tile(at_q),
+        out_shape=jax.ShapeDtypeStruct((b, s, e), qkv.dtype),
         scratch_shapes=[
-            pltpu.VMEM((g, d, block), jnp.float32),
-            pltpu.VMEM((g, d, block), jnp.float32),
+            pltpu.VMEM((g, block, w), qkv.dtype),       # masked q
+            pltpu.VMEM((g, block, w), qkv.dtype),       # masked do
+            pltpu.VMEM((g * d, block), qkv.dtype),      # k.T
+            pltpu.VMEM((g, d, block), jnp.float32),     # dq
         ],
         interpret=interpret,
-    )(qkv, qkv, qkv, grad, lse4, delta4)
+    )(qkv, qkv, qkv, grad, lse5, delta5)
+    dk, dv = _named_call(
+        "flash_dkv",
+        functools.partial(_flatb_dkv_kernel, sub=sub_dkv, **params),
+        grid=(b, hg, nb, nb),
+        in_specs=[
+            tile(qidx),
+            tile(lambda ib, ih, ki, qb: (ib, ki, hg + ih)),
+            tile(lambda ib, ih, ki, qb: (ib, ki, 2 * hg + ih)),
+            tile(qidx), stat(sidx), stat(sidx),
+        ],
+        out_specs=[tile(lambda ib, ih, ki, qb: (ib, ki, ih))] * 2,
+        out_shape=[jax.ShapeDtypeStruct((b, s, e), qkv.dtype)] * 2,
+        scratch_shapes=[
+            pltpu.VMEM((g, block, w), qkv.dtype),       # masked k
+            pltpu.VMEM((g, block, w), qkv.dtype),       # masked v
+            pltpu.VMEM((block, g * d), qkv.dtype),      # scaled q
+            pltpu.VMEM((g * d, block), qkv.dtype),      # its .T
+            pltpu.VMEM((g * d, block), qkv.dtype),      # do.T
+            pltpu.VMEM((g, d, block), jnp.float32),     # dk
+            pltpu.VMEM((g, d, block), jnp.float32),     # dv
+        ],
+        interpret=interpret,
+    )(qkv, qkv, qkv, grad, lse5, delta5)
     # column concat back to the projection layout; XLA fuses this into
     # the consuming dW/dx matmuls when it can
-    return (jnp.concatenate([dq, dk, dv], axis=-1),)
+    return jnp.concatenate([dq, dk, dv], axis=-1)
 
 
 _flash_flatb.defvjp(_flash_flatb_fwd, _flash_flatb_bwd)

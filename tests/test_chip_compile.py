@@ -107,27 +107,30 @@ def test_flash_attention_fwd_bwd_compiles(one_chip):
     assert _kernel_names(text) == {"flash_fwd", "flash_bwd"}
 
 
-@pytest.mark.parametrize("seq,batch,kernels", [
-    (512, 16, {"flash_fwd", "flash_bwd"}),
-    (2048, 4, {"flash_fwd", "flash_dq", "flash_dkv"})])
-def test_flash_attention_flat_fwd_bwd_compiles(one_chip, seq, batch,
+@pytest.mark.parametrize("seq,batch,nh,kernels", [
+    (512, 16, NH, {"flash_fwd", "flash_bwd"}),
+    (2048, 4, NH, {"flash_fwd", "flash_dq", "flash_dkv"}),
+    # the benchmark's cell, train.gpt2_medium.seq1024 (h 16)
+    (1024, 8, 16, {"flash_fwd", "flash_dq", "flash_dkv"})])
+def test_flash_attention_flat_fwd_bwd_compiles(one_chip, seq, batch, nh,
                                                kernels):
-    """seq 512 takes the single-block fused-backward kernels, seq 2048
-    the blocked flat kernels — both on the (b, s, 3e) projection
-    layout the training stack feeds them. Each kernel's instruction
-    carries its stable name (what ``flash_*_roofline.train`` match in
-    a chip trace)."""
+    """seq 512 takes the single-block fused-backward kernels, seq 1024
+    and 2048 the blocked flat kernels (one diagonal block, and two
+    blocks a side) — all on the (b, s, 3e) projection layout the
+    training stack feeds them. Each kernel's instruction carries its
+    stable name (what ``flash_*_roofline.train`` match in a chip
+    trace)."""
     from cxxnet_tpu.ops import flash_attention as fa
-    assert fa.supports_flat(seq, NH, D) or fa.flat_blocked_plan(
-        seq, NH, D)
+    assert fa.supports_flat(seq, nh, D) or fa.flat_blocked_plan(
+        seq, nh, D)
 
     def loss(qkv):
-        return fa.flash_attention_flat(qkv, NH, causal=True,
+        return fa.flash_attention_flat(qkv, nh, causal=True,
                                        interpret=False).astype(
                                            jnp.float32).sum()
 
     text = _compile(jax.value_and_grad(loss), one_chip,
-                    ((batch, seq, 3 * E), jnp.bfloat16))
+                    ((batch, seq, 3 * nh * D), jnp.bfloat16))
     assert _kernel_names(text) == kernels
 
 

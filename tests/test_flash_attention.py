@@ -218,9 +218,10 @@ def test_flat_blocked_plan_gates():
     for s in (1024, 2048):
         plan = fa.flat_blocked_plan(s, 12, 64)
         assert plan is not None, s
-        g, block = plan
+        g, block, *subs = plan
         assert 12 % g == 0 and (g * 64) % 128 == 0 and s % block == 0
-        assert max(fa._flatb_vmem(s, 12, 64, g, block)) \
+        assert len(subs) == 3 and not any(block % x for x in subs)
+        assert max(fa._flatb_vmem(64, g, block, subs)) \
             <= 13 * 1024 * 1024
     assert fa.flat_blocked_plan(4096, 12, 64) is None
     assert fa.flat_blocked_plan(8192, 12, 64) is None
@@ -228,6 +229,24 @@ def test_flat_blocked_plan_gates():
     assert fa.flat_blocked_plan(640, 2, 64) is not None
     # head/dim layouts that can't 128-align a group: no plan
     assert fa.flat_blocked_plan(1024, 3, 40) is None
+    # nor one whose heads would straddle a 128-lane window (the
+    # generic kernels take it)
+    assert fa.flat_blocked_plan(1024, 16, 40) is None
+    assert fa.flat_blocked_plan(1024, 8, 32) is not None
+    assert fa.flat_blocked_plan(1024, 2, 128) is not None
+
+
+def test_flat_blocked_plan_at_the_benchmark_shape():
+    """train.gpt2_medium.seq1024 (s 1024, h 16, d 64): the whole
+    sequence as one diagonal block, two heads a grid step, units of
+    512 / 256 / 256 keys in fwd / dq / dkv (each the fastest of its
+    kernel on the chip, PERF.md PR 26), at most 10 MB of the 16 MB
+    scoped VMEM by the itemized estimate."""
+    assert fa.flat_blocked_plan(1024, 16, 64) == (2, 1024, 512, 256, 256)
+    est = fa._flatb_vmem(64, 2, 1024, (512, 256, 256))
+    assert max(est) <= 10 * 1024 * 1024
+    # Mosaic's own allocation: 5.3-7.4 MB, 7.0-8.8 MB at s 2048
+    assert min(est) >= 8 * 1024 * 1024
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -263,7 +282,7 @@ def test_flat_blocked_small_blocks(monkeypatch):
     program (the causal skip, the online-softmax merge, and the dkv
     q_lo start all execute)."""
     monkeypatch.setattr(fa, "flat_blocked_plan",
-                        lambda s, h, d, budget=0: (2, 128))
+                        lambda s, h, d, budget=0: (2, 128, 128, 128, 128))
     q, k, v = _qkv(b=2, h=2, s=256, d=64, seed=6)
     qkv = _pack_flat(q, k, v)
     for causal in (False, True):
@@ -279,6 +298,94 @@ def test_flat_blocked_small_blocks(monkeypatch):
         np.testing.assert_allclose(np.asarray(g_flat),
                                    np.asarray(g_ref),
                                    rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("h,plan,causal", [
+    # two blocks: an unmasked off-diagonal pair, then a diagonal pair
+    # whose second unit starts past the first 128 queries
+    (2, (2, 256, 128, 128, 128), True),
+    # one block, four units a kernel: every unit but the first skips
+    # the query groups before its keys
+    (2, (2, 512, 128, 128, 128), True),
+    # units of different sizes in fwd, dq and dkv (as the chosen plan
+    # has them), the masked lanes wider than one query group
+    (2, (2, 512, 512, 256, 128), True),
+    # four heads a grid step over two 128-lane windows, four blocks
+    (4, (4, 128, 128, 128, 128), True),
+    (8, (4, 256, 256, 128, 128), True),
+    # no mask, no skip: every pair takes the off-diagonal body
+    (2, (2, 256, 256, 128, 128), False),
+])
+def test_flat_blocked_schedules(monkeypatch, h, plan, causal):
+    """Forward and gradients of the blocked flat kernels against the
+    XLA attend at s 512 under forced plans (interpret mode)."""
+    monkeypatch.setattr(fa, "flat_blocked_plan",
+                        lambda s, h, d, budget=0: plan)
+    q, k, v = _qkv(b=1, h=h, s=512, d=64, seed=7)
+    qkv = _pack_flat(q, k, v)
+    out = fa._flash_flatb(qkv, h, causal, None, True)
+    ref = ra.attention(q, k, v, causal=causal)
+    np.testing.assert_allclose(
+        np.asarray(out.reshape(1, 512, h, 64).transpose(0, 2, 1, 3)),
+        np.asarray(ref), rtol=2e-5, atol=2e-5)
+    g_flat = jax.grad(lambda x: jnp.sum(
+        fa._flash_flatb(x, h, causal, None, True) ** 2))(qkv)
+    g_ref = _pack_flat(*jax.grad(lambda a: jnp.sum(
+        ra.attention(*a, causal=causal) ** 2))((q, k, v)))
+    np.testing.assert_allclose(np.asarray(g_flat), np.asarray(g_ref),
+                               rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("h,d,g", [(4, 32, 4), (2, 128, 1), (1, 256, 1)])
+def test_flat_blocked_head_windows(monkeypatch, h, d, g):
+    """Heads narrower (d 32: four to a 128-lane window) and wider
+    (d 128, d 256: a window each) than the benchmark's d 64."""
+    monkeypatch.setattr(fa, "flat_blocked_plan",
+                        lambda s, h, d, budget=0: (g, 128, 128, 128, 128))
+    q, k, v = _qkv(b=1, h=h, s=256, d=d, seed=8)
+    qkv = _pack_flat(q, k, v)
+    out = fa._flash_flatb(qkv, h, True, None, True)
+    ref = ra.attention(q, k, v, causal=True)
+    np.testing.assert_allclose(
+        np.asarray(out.reshape(1, 256, h, d).transpose(0, 2, 1, 3)),
+        np.asarray(ref), rtol=2e-5, atol=2e-5)
+    g_flat = jax.grad(lambda x: jnp.sum(
+        fa._flash_flatb(x, h, True, None, True) ** 2))(qkv)
+    g_ref = _pack_flat(*jax.grad(lambda a: jnp.sum(
+        ra.attention(*a, causal=True) ** 2))((q, k, v)))
+    np.testing.assert_allclose(np.asarray(g_flat),
+                               np.asarray(g_ref),
+                               rtol=2e-4, atol=2e-4)
+
+
+def test_flat_blocked_plan_marker(monkeypatch):
+    """One ``flash.plan`` marker per traced forward and per traced
+    backward, carrying the plan; with nothing listening the marker is
+    the shared no-op span."""
+    from cxxnet_tpu.obs import trace as obs_trace
+    monkeypatch.setattr(fa, "flat_blocked_plan",
+                        lambda s, h, d, budget=0: (2, 128, 128, 128, 128))
+    with obs_trace.span("flash.plan", "kernel") as off:
+        assert off is obs_trace.NOOP_SPAN
+    q, k, v = _qkv(b=1, h=2, s=256, d=64, seed=9)
+    qkv = _pack_flat(q, k, v)
+    tr = obs_trace.start()
+    try:
+        jax.grad(lambda x: jnp.sum(
+            fa._flash_flatb(x, 2, True, None, True)))(qkv)
+        marks = [e for e in tr.trace_events()
+                 if e.get("name") == "flash.plan"]
+    finally:
+        obs_trace.stop()
+    assert [m["args"]["kernels"] for m in marks] == ["fwd", "bwd"]
+    for m in marks:
+        assert m["cat"] == "kernel" and m["ph"] == "X"
+        for key, val in (("s", 256), ("h", 2), ("d", 64), ("g", 2),
+                         ("block_q", 128), ("block_k", 128),
+                         ("sub", 128)):
+            assert m["args"][key] == val, key
+        assert m["args"]["vmem_bytes"] > 0
+    assert marks[1]["args"]["sub_dkv"] == 128
 
 
 def test_pick_group_itemized_budget():
@@ -325,7 +432,7 @@ def test_stack_flat_blocked_matches_generic_trajectory(monkeypatch):
 
     monkeypatch.setattr(fa, "flat_blocked_plan",
                         lambda s, h, d, budget=0:
-                        (2, 128) if s == 256 else None)
+                        (2, 128, 128, 128, 128) if s == 256 else None)
     monkeypatch.setattr(fa, "supports_flat", lambda *a, **k: 0)
 
     def build(flat):
