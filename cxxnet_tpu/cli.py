@@ -313,6 +313,12 @@ class LearnTask:
                 self.start_counter += 1
         self.create_iterators()
         self._warn_unconsumed()
+        if self.task in ("generate", "export_model", "serve"):
+            from . import generate
+            why = generate.decode_blocker(self.trainer.net)
+            if why:
+                raise RuntimeError("task = %s is not implemented for "
+                                   "this net: %s" % (self.task, why))
 
     # keys the CLI layer itself consumes (set_param above + run())
     CLI_KEYS = frozenset([

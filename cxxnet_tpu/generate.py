@@ -86,6 +86,26 @@ from . import layers as L
 from .ops.ring_attention import NEG_INF as NEG
 
 
+def decode_blocker(net) -> str:
+    """Why this net cannot be decoded, exported or served ('' where it
+    can): the mechanism the decode path lacks, by name. ``cli`` refuses
+    ``task = generate | export_model | serve`` with it."""
+    for mod in net.modules:
+        if isinstance(mod, L.BlockDiffusionNoiseLayer) or getattr(
+                mod, "objective", "") == "block_diffusion":
+            return ("the net trains by block diffusion (%s): generation "
+                    "fills a block of tokens a step by iterated "
+                    "denoising, not one token a lane, and neither the "
+                    "KV-cache decode (generate.py) nor the serving "
+                    "scheduler (serve/continuous.py) has such a step"
+                    % mod.type_name)
+        if isinstance(mod, L.TransformerStackLayer):
+            why = mod.decode_blocker()
+            if why:
+                return why
+    return ""
+
+
 def plan(net) -> Optional[dict]:
     """Return a decode plan if the net matches the canonical LM pattern
     (a linear chain: embed, causal transformer_stack(s) — dense or MoE —
@@ -102,6 +122,9 @@ def plan_or_reason(net):
     quadratic (VERDICT r2 weak #3)."""
     mods = net.modules
     infos = net.cfg.layers
+    why = decode_blocker(net)
+    if why:
+        return None, why
     # linear chain: each layer consumes exactly the previous layer's node
     prev = 0
     for info in infos:

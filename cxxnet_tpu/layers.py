@@ -178,6 +178,11 @@ class ApplyContext:
     # params after the optimizer step
     layer_index: int = -1
     state_updates: Dict = field(default_factory=dict)
+    # device-side counters a layer computes in the step itself
+    # ({(layer_index, name): array}, e.g. the routed layer's loads): the
+    # trainer returns them from the train step and reads a finished
+    # step's without waiting on the device
+    stats: Dict = field(default_factory=dict)
     # sequence parallelism: when set, attention layers run ring attention
     # sharded over this mesh axis (cxxnet_tpu/ops/ring_attention.py)
     mesh: Optional[object] = None
@@ -363,7 +368,8 @@ class EmbeddingLayer(Layer):
     pipeline's uniform dtype) and are cast to int32. ``learn_pos = 1``
     adds a learned positional embedding (attention is otherwise
     permutation-equivariant). Config: ``vocab_size``, ``nhidden``,
-    ``learn_pos``. Tags: ``wmat`` (vocab, nhidden), ``pos``
+    ``learn_pos``, ``init_sigma`` (the table's normal init; default
+    nhidden^-0.5). Tags: ``wmat`` (vocab, nhidden), ``pos``
     (seq, nhidden).
     """
     has_params = True
@@ -373,12 +379,15 @@ class EmbeddingLayer(Layer):
         super().__init__()
         self.vocab_size = 0
         self.learn_pos = 0
+        self.sigma = 0.0
 
     def set_param(self, name, val):
         if name == "vocab_size":
             self.vocab_size = int(val)
         elif name == "learn_pos":
             self.learn_pos = int(val)
+        elif name == "init_sigma":
+            self.sigma = float(val)
         else:
             super().set_param(name, val)
 
@@ -395,7 +404,8 @@ class EmbeddingLayer(Layer):
         e = self.param.num_hidden
         r1, r2 = jax.random.split(rng)
         p = {"wmat": jax.random.normal(r1, (self.vocab_size, e),
-                                       jnp.float32) * (e ** -0.5)}
+                                       jnp.float32)
+             * (self.sigma or e ** -0.5)}
         if self.learn_pos:
             p["pos"] = jax.random.normal(r2, (self.seq_len, e),
                                          jnp.float32) * 0.02
@@ -413,6 +423,77 @@ class EmbeddingLayer(Layer):
             out = out + params["pos"].astype(ctx.compute_dtype)[None]
         return [out.astype(jnp.float32).reshape(
             n, 1, s, self.param.num_hidden)]
+
+
+@register("bd_noise")
+class BlockDiffusionNoiseLayer(Layer):
+    """The noising step of block-diffusion training (BD3-LM), ahead of
+    ``embed``: (b, 1, s, 1) clean ids -> two nodes, the model's input
+    ``[x_t ; x_0]`` as (b, 1, 2s, 1) ids and (b, 1, s, 2) holding each
+    position's target (the clean id) and loss weight for ``lm_head``
+    under ``objective = block_diffusion``.
+
+    For each row and block b of ``block_len`` tokens a level
+    ``t_b ~ U(0, 1)`` floored at ``t_floor``; token i of the block
+    becomes ``mask_token`` where ``u_i < t_b``, ``u ~ U(0, 1)`` a
+    position; the weight is ``[masked_i] / t_b``. Both draws come from
+    the step's key (``ctx.rng``, which the trainer carries on the device
+    and splits once a step, folded with this layer's index):
+    ``t = uniform(fold_in(key, 0), (b, s / block_len))``,
+    ``u = uniform(fold_in(key, 1), (b, s))``. Config: ``mask_token``,
+    ``block_len`` (default 4), ``t_floor`` (default 1e-3). No params.
+    """
+
+    def __init__(self):
+        super().__init__()
+        self.mask_token = -1
+        self.block_len = 4
+        self.t_floor = 1e-3
+
+    def set_param(self, name, val):
+        if name == "mask_token":
+            self.mask_token = int(val)
+        elif name == "block_len":
+            self.block_len = int(val)
+        elif name == "t_floor":
+            self.t_floor = float(val)
+        else:
+            super().set_param(name, val)
+
+    def infer_shape(self, in_shapes):
+        self._check_arity(in_shapes, 1, 2)
+        n, c, s, w = in_shapes[0]
+        if c != 1 or w != 1:
+            raise ValueError("bd_noise: input must be (batch,1,seq,1) ids")
+        if self.mask_token < 0:
+            raise ValueError("bd_noise: must set mask_token")
+        if s % self.block_len:
+            raise ValueError("bd_noise: seq %d is not whole blocks of %d"
+                             % (s, self.block_len))
+        self.in_shapes = list(in_shapes)
+        self.out_shapes = [(n, 1, 2 * s, 1), (n, 1, s, 2)]
+        return self.out_shapes
+
+    def apply(self, params, inputs, ctx):
+        if ctx.rng is None:
+            raise ValueError(
+                "bd_noise: no key to draw the noise from: the "
+                "block-diffusion objective is a training objective; "
+                "evaluating or predicting through it is not implemented")
+        n, _, s, _ = inputs[0].shape
+        ids = inputs[0].reshape(n, s)
+        t = jnp.maximum(jax.random.uniform(
+            jax.random.fold_in(ctx.rng, 0), (n, s // self.block_len)),
+            self.t_floor)
+        t = jnp.repeat(t, self.block_len, axis=1)
+        masked = jax.random.uniform(jax.random.fold_in(ctx.rng, 1),
+                                    (n, s)) < t
+        noisy = jnp.where(masked, jnp.float32(self.mask_token),
+                          ids.astype(jnp.float32))
+        both = jnp.concatenate([noisy, ids.astype(jnp.float32)], axis=1)
+        side = jnp.stack([ids.astype(jnp.float32),
+                          masked.astype(jnp.float32) / t], axis=-1)
+        return [both.reshape(n, 1, 2 * s, 1), side.reshape(n, 1, s, 2)]
 
 
 @register("im2seq")
@@ -1844,9 +1925,28 @@ class TransformerStackLayer(Layer):
     jax.checkpoint — so only one (b, s, e) boundary activation per layer
     is kept instead of every intra-block tensor; the standard
     FLOPs-for-HBM trade for deep stacks).
+
+    Options of the same block (each off by default; any of them takes
+    the grouped block, ``_block_fn``'s second body):
+    ``nkvhead`` (kv heads, each shared by nhead / nkvhead q heads),
+    ``head_dim`` (head size, where it is not embed / nhead), ``qk_norm``
+    (an RMSNorm with a learned gain over each q and k head before the
+    rotation: tags ``qnorm``, ``knorm``), ``rope_theta`` (rotary
+    positions over the whole head, rotate-half pairing), ``mlp_act =
+    relu|swiglu`` (``swiglu``: ``w2 (silu(W1g x) * W1u x)``, ``w1``
+    holding W1g's rows then W1u's), ``final_norm`` (an RMSNorm after the
+    last block: tag ``normf``), ``attn_mask = full|causal|
+    block_diffusion`` with ``block_len`` (``block_diffusion``: the input
+    is ``[x_t ; x_0]``, both halves at positions 0..s/2-1, masked as
+    ``ops.flash_attention.gq_pairs`` says), and ``moe_dispatch =
+    onehot|sorted``: ``sorted`` is the dropless dispatch of
+    ``ops/moe_sorted.py`` over this share's experts, ``expert_first`` and
+    ``expert_held`` of ``nexpert`` (the router stays ``nexpert`` wide),
+    ``moe_norm_topk`` (renormalise the chosen weights).
     """
     has_params = True
-    param_tags = ("wqkv", "wo", "w1", "w2", "norm1", "norm2", "gate")
+    param_tags = ("wqkv", "wo", "w1", "w2", "norm1", "norm2", "gate",
+                  "qnorm", "knorm", "normf")
 
     def __init__(self):
         super().__init__()
@@ -1860,13 +1960,41 @@ class TransformerStackLayer(Layer):
         self.nexpert = 0
         self.topk = 2
         self.capacity_factor = 1.25
-        self.moe_loss = 0.01
+        self.moe_loss = None        # unset: 0.01 one-hot, 0 sorted
         self.attn_impl = "auto"
         self.attn_flat = "auto"
         self.scan_unroll = 1
+        self.nkvhead = 0
+        self.head_dim = 0
+        self.qk_norm = 0
+        self.rope_theta = 0.0
+        self.mlp_act = "relu"
+        self.final_norm = 0
+        self.attn_mask = ""         # unset: by ``causal``
+        self.block_len = 4
+        self.moe_dispatch = "onehot"
+        self.expert_first = 0
+        self.expert_held = 0
+        self.moe_norm_topk = 0
+
+    _INT_KEYS = ("nkvhead", "head_dim", "qk_norm", "final_norm",
+                 "block_len", "expert_first", "expert_held",
+                 "moe_norm_topk")
+    _CHOICES = {"mlp_act": ("relu", "swiglu"),
+                "attn_mask": ("full", "causal", "block_diffusion"),
+                "moe_dispatch": ("onehot", "sorted")}
 
     def set_param(self, name, val):
-        if name == "nlayer":
+        if name in self._INT_KEYS:
+            setattr(self, name, int(val))
+        elif name == "rope_theta":
+            self.rope_theta = float(val)
+        elif name in self._CHOICES:
+            if val not in self._CHOICES[name]:
+                raise ValueError("%s must be %s" % (
+                    name, "|".join(self._CHOICES[name])))
+            setattr(self, name, val)
+        elif name == "nlayer":
             self.nlayer = int(val)
         elif name == "attn_flat":
             # auto: flat kernels whenever the shape supports them;
@@ -1911,17 +2039,103 @@ class TransformerStackLayer(Layer):
         if c != 1:
             raise ValueError(
                 "transformer_stack: input must be (batch,1,seq,embed)")
-        if e % self.nhead != 0:
+        if not self.head_dim and e % self.nhead != 0:
             raise ValueError("transformer_stack: embed %d vs nhead %d"
                              % (e, self.nhead))
         if self.nhidden_mlp == 0:
             self.nhidden_mlp = 4 * e
+        if self.moe_loss is None:
+            self.moe_loss = 0.0 if self.sorted else 0.01
+        if self.grouped:
+            self._check_grouped(s, e)
         return [(n, 1, s, e)]
+
+    @property
+    def mask(self) -> str:
+        return self.attn_mask or ("causal" if self.causal else "full")
+
+    @property
+    def sorted(self) -> bool:
+        return bool(self.moe) and self.moe_dispatch == "sorted"
+
+    @property
+    def grouped(self) -> bool:
+        """Does any option ask for the grouped block?"""
+        return bool(
+            self.nkvhead or self.head_dim or self.qk_norm
+            or self.rope_theta or self.final_norm or self.sorted
+            or self.mlp_act != "relu" or self.mask == "block_diffusion")
+
+    def _check_grouped(self, s, e):
+        """The grouped block's sizes, and what it does not do, said
+        here where the conf is read."""
+        err = lambda msg: ValueError("transformer_stack: " + msg)
+        self.nkv = self.nkvhead or self.nhead
+        self.hd = self.head_dim or e // self.nhead
+        if self.nhead % self.nkv:
+            raise err("nhead %d is not whole groups of nkvhead %d"
+                      % (self.nhead, self.nkv))
+        if self.hd % 2 and self.rope_theta:
+            raise err("rope_theta needs an even head size, not %d"
+                      % self.hd)
+        if self.mask == "block_diffusion" and (
+                s % 2 or (s // 2) % self.block_len):
+            raise err("attn_mask = block_diffusion reads [x_t ; x_0]: "
+                      "%d positions are not two halves of whole blocks "
+                      "of %d" % (s, self.block_len))
+        if self.moe and not self.sorted:
+            raise err("the options of the grouped block (nkvhead, "
+                      "head_dim, qk_norm, rope_theta, mlp_act, "
+                      "final_norm, attn_mask = block_diffusion) route "
+                      "by moe_dispatch = sorted only; the one-hot "
+                      "dispatch runs in the plain block")
+        if self.sorted:
+            self.held = self.expert_held or self.nexpert
+            if self.mlp_act != "swiglu":
+                raise err("moe_dispatch = sorted has swiglu experts "
+                          "only (set mlp_act = swiglu)")
+            if self.moe_loss > 0.0:
+                raise err("moe_dispatch = sorted computes no auxiliary "
+                          "load-balance loss (moe_loss must be 0)")
+            if not 0 <= self.expert_first \
+                    <= self.nexpert - self.held < self.nexpert:
+                raise err("experts %d..%d are not a share of nexpert %d"
+                          % (self.expert_first,
+                             self.expert_first + self.held, self.nexpert))
+            if self.topk > self.nexpert:
+                raise err("moe_topk %d > nexpert %d"
+                          % (self.topk, self.nexpert))
+
+    def decode_blocker(self) -> str:
+        """Why ``task = generate``, ``export_model`` and ``serve`` cannot
+        run this stack ('' where they can): they decode through
+        generate.py's own copy of the block, which has none of the
+        grouped block's mechanisms."""
+        if not self.grouped:
+            return ""
+        what = [name for name, on in (
+            ("rotary positions (rope_theta)", self.rope_theta),
+            ("grouped-query heads (nkvhead / head_dim)",
+             self.nkvhead or self.head_dim),
+            ("q/k norms (qk_norm)", self.qk_norm),
+            ("a gated MLP (mlp_act = swiglu)", self.mlp_act != "relu"),
+            ("the sorted expert dispatch (moe_dispatch = sorted)",
+             self.sorted),
+            ("a final norm (final_norm)", self.final_norm),
+            ("the block-diffusion objective (attn_mask = "
+             "block_diffusion): a decode step fills a block of tokens, "
+             "not one token a lane", self.mask == "block_diffusion"))
+            if on]
+        return ("the KV-cache decode (generate.py, serving.py) has its "
+                "own copy of the transformer block and lacks: "
+                + "; ".join(what))
 
     def init_params(self, rng) -> Params:
         e, m, L = self.in_shapes[0][3], self.nhidden_mlp, self.nlayer
         p = self.param
         ks = jax.random.split(rng, 5)
+        if self.grouped:
+            return self._init_grouped(ks, e, m, L)
         out = {
             "wqkv": p.rand_init_weight(ks[0], (L, 3 * e, e), e, 3 * e),
             "wo": p.rand_init_weight(ks[1], (L, e, e), e, e),
@@ -1946,9 +2160,60 @@ class TransformerStackLayer(Layer):
             out["w2"] = p.rand_init_weight(ks[3], (L, e, m), m, e)
         return out
 
+    def _init_grouped(self, ks, e, m, L):
+        """The grouped block's tree: ``wqkv`` holds the q heads' rows,
+        then the k heads', then the v heads'; a gated ``w1`` W1g's rows,
+        then W1u's (columns, in the sorted dispatch's experts)."""
+        p = self.param
+        nq, nkv = self.nhead * self.hd, self.nkv * self.hd
+        wide = m * (2 if self.mlp_act == "swiglu" else 1)
+        out = {
+            "wqkv": p.rand_init_weight(ks[0], (L, nq + 2 * nkv, e), e,
+                                       nq + 2 * nkv),
+            "wo": p.rand_init_weight(ks[1], (L, e, nq), nq, e),
+            "norm1": jnp.ones((L, e), jnp.float32),
+            "norm2": jnp.ones((L, e), jnp.float32)}
+        if self.qk_norm:
+            out["qnorm"] = jnp.ones((L, self.hd), jnp.float32)
+            out["knorm"] = jnp.ones((L, self.hd), jnp.float32)
+        if self.final_norm:
+            out["normf"] = jnp.ones((e,), jnp.float32)
+        if self.sorted:
+            H = self.held
+            # an expert's matrices as (in, out), the grouped products'
+            # own layout (ops/moe_sorted.py)
+            out["w1"] = p.rand_init_weight(ks[2], (L, H, e, wide), e, wide)
+            out["w2"] = p.rand_init_weight(ks[3], (L, H, m, e), m, e)
+            out["gate"] = p.rand_init_weight(
+                ks[4], (L, self.nexpert, e), e, self.nexpert)
+        else:
+            out["w1"] = p.rand_init_weight(ks[2], (L, wide, e), e, wide)
+            out["w2"] = p.rand_init_weight(ks[3], (L, e, m), m, e)
+        return out
+
+    def _attend_pairs(self, s):
+        """Query-key pairs one row's mask allows, as a share of s * s."""
+        if self.mask == "block_diffusion":
+            from .ops import flash_attention as fa
+            return fa.gq_pairs_allowed(self.mask, s // 2,
+                                       self.block_len) / float(s * s)
+        return 0.5 if self.mask == "causal" else 1.0
+
     def analytic_flops(self, skip_dx=False):
         n, _, s, e = self.in_shapes[0]
         m = self.nhidden_mlp or 4 * e
+        if self.grouped:
+            nq, nkv = self.nhead * self.hd, self.nkv * self.hd
+            proj = 2.0 * n * s * e * (nq + 2 * nkv) + 2.0 * n * s * nq * e
+            attend = 4.0 * self._attend_pairs(s) * n * s * s * nq
+            wide = m * (3 if self.mlp_act == "swiglu" else 2)
+            if self.sorted:     # the mean load of this share
+                mlp = 2.0 * n * s * self.nexpert * e + 2.0 * n * s * (
+                    self.topk * self.held / self.nexpert) * wide * e
+            else:
+                mlp = 2.0 * n * s * wide * e
+            fwd = self.nlayer * (proj + attend + mlp)
+            return fwd, 2.0 * fwd
         c = 0.5 if self.causal else 1.0              # useful causal half
         proj = 2.0 * n * s * e * (3 * e) + 2.0 * n * s * e * e
         attend = 4.0 * c * n * s * s * e             # QK^T + PV, all heads
@@ -2013,6 +2278,10 @@ class TransformerStackLayer(Layer):
                              cap_f, dt)
             return y.reshape(b, s, e), aux
 
+        if self.grouped:
+            return self._grouped_block(dt, interpret, mesh, seq_sharded,
+                                       use_flash, rmsnorm)
+
         def block(lp, h):
             b, s, e = h.shape
             d = e // nh
@@ -2067,6 +2336,122 @@ class TransformerStackLayer(Layer):
             return h + y, aux
         return block
 
+    def _grouped_block(self, dt, interpret, mesh, seq_sharded, use_flash,
+                       rmsnorm):
+        """The block with the options of the class docstring: grouped
+        heads of their own size, q/k norms, rotary positions, a gated
+        MLP or the sorted expert dispatch, a scheduled mask. -> block(lp,
+        h) -> (h, aux), aux the routed layer's counters
+        (``ops.moe_sorted.STATS``) or 0."""
+        from .ops import flash_attention as fa
+        from .ops import moe_sorted as ms
+        from .ops import pallas_env
+        nh, nkv, d = self.nhead, self.nkv, self.hd
+        mask, blen = self.mask, self.block_len
+        rows = pallas_env.rows_spec(mesh)
+        if seq_sharded:
+            raise ValueError(
+                "transformer_stack: the grouped block (rotary positions, "
+                "grouped heads, attn_mask = block_diffusion) does not run "
+                "under sequence sharding: ring and ulysses attention "
+                "know neither the mask nor shared kv heads")
+        if self.sorted and mesh is not None and any(
+                n > 1 for ax, n in mesh.shape.items() if ax != "data"):
+            raise ValueError(
+                "transformer_stack: moe_dispatch = sorted runs on one "
+                "device or on data-parallel replicas of one share, not "
+                "on a mesh %s: experts sharded over a mesh axis need the "
+                "exchange of routed rows, which is not implemented"
+                % dict(mesh.shape))
+        flash = use_flash and d % 128 == 0 and mask != "full"
+
+        tables = {}
+
+        def rope(x, s):
+            """Rotate-half rotary positions on (b, s, heads, d); under
+            block diffusion both halves sit at positions 0..s/2-1."""
+            if s not in tables:     # one pair of constants a trace
+                half = s // 2 if mask == "block_diffusion" else s
+                inv = self.rope_theta ** (-np.arange(0, d, 2) / float(d))
+                ang = (np.arange(s) % half)[:, None] * inv[None]
+                tables[s] = tuple(
+                    np.concatenate([f(ang)] * 2, -1).astype(np.float32)[
+                        None, :, None] for f in (np.cos, np.sin))
+            cos, sin = tables[s]
+            x = x.astype(jnp.float32)
+            turned = jnp.concatenate([-x[..., d // 2:], x[..., :d // 2]],
+                                     -1)
+            return x * cos + turned * sin
+
+        def heads(x, g, s):
+            """(b, s, heads, d) -> normed and rotated, (b, s, heads*d)."""
+            b = x.shape[0]
+            if self.qk_norm:
+                x = rmsnorm(x, g)
+            if self.rope_theta:
+                x = rope(x, s)
+            return x.astype(dt).reshape(b, s, -1)
+
+        def attend(q, k, v):
+            if flash:
+                return pallas_env.per_shard(
+                    mesh, lambda q, k, v: fa.flash_attention_gq(
+                        q, k, v, nkv, mask, blen, interpret=interpret),
+                    (rows, rows, rows), rows)(q, k, v)
+            return fa.attention_gq_dense(q, k, v, nkv, mask, blen)
+
+        m = self.nhidden_mlp
+
+        def mlp(lp, x):
+            b, s, e = x.shape
+            if self.sorted:
+                def routed(x, gate, w1, w2):
+                    # each data-parallel replica routes its own rows
+                    # (the grouped products are Pallas kernels)
+                    n = x.shape[0] * s
+                    y, stats = ms.moe_sorted(
+                        x.reshape(n, e), {"gate": gate, "w1": w1,
+                                          "w2": w2},
+                        topk=self.topk, total=self.nexpert,
+                        first=self.expert_first, held=self.held,
+                        norm_topk=bool(self.moe_norm_topk), dt=dt,
+                        interpret=interpret)
+                    return y.reshape(x.shape), stats[None]
+                from jax.sharding import PartitionSpec as P
+                y, stats = pallas_env.per_shard(
+                    mesh, routed, (rows, P(), P(), P()), (rows, rows))(
+                        x, lp["gate"], lp["w1"], lp["w2"])
+                # the replicas' counters: sums, and the largest load
+                stats = jnp.where(
+                    jnp.arange(len(ms.STATS)) == ms.STATS.index(
+                        "load_max"), stats.max(0), stats.sum(0))
+                return y, stats
+            a = jnp.einsum("bse,me->bsm", x, lp["w1"].astype(dt))
+            if self.mlp_act == "swiglu":
+                a = (jax.nn.silu(a[..., :m].astype(jnp.float32))
+                     * a[..., m:].astype(jnp.float32)).astype(dt)
+            else:
+                a = jax.nn.relu(a)
+            return jnp.einsum("bsm,em->bse", a, lp["w2"].astype(dt)), 0.0
+
+        def block(lp, h):
+            b, s, e = h.shape
+            x = rmsnorm(h, None)          # gain folded into wqkv
+            qkv = jnp.einsum("bse,fe->bsf", x, lp["wqkv"].astype(dt))
+            nq, nk = nh * d, nkv * d
+            q = heads(qkv[..., :nq].reshape(b, s, nh, d),
+                      lp.get("qnorm"), s)
+            k = heads(qkv[..., nq:nq + nk].reshape(b, s, nkv, d),
+                      lp.get("knorm"), s)
+            att = attend(q, k, qkv[..., nq + nk:])
+            h = h + jnp.einsum("bsf,ef->bse", att, lp["wo"].astype(dt))
+            # the routed layer gates on the gained activations: its
+            # gain is applied, not folded (_fold_norms)
+            y, aux = mlp(lp, rmsnorm(h, lp["norm2"] if self.moe
+                                     else None))
+            return h + y, aux
+        return block
+
     def _fold_norms(self, params, dt):
         """Fold the rmsnorm gains into the weight matrices they feed:
         (g * x) . W^T == x . (W * g)^T, so norm1 rides wqkv and norm2
@@ -2090,9 +2475,12 @@ class TransformerStackLayer(Layer):
         # (unfolded — router-gain constraint) and gate as well; the
         # in-block astype(dt) calls become no-ops, and the routing
         # math already runs in dt
-        for k in ("wo", "w2", "w1", "gate"):
+        # (the sorted dispatch routes in float32: its gate stays as it is)
+        for k in ("wo", "w2", "w1") + (() if self.sorted else ("gate",)):
             if k in out and out[k].dtype != dt and out[k].ndim > 2:
                 out[k] = out[k].astype(dt)
+        # the one leaf that is not stacked over depth
+        out.pop("normf", None)
         return out
 
     def apply(self, params, inputs, ctx):
@@ -2113,7 +2501,15 @@ class TransformerStackLayer(Layer):
         seq_sharded = (pipe == 1 and mesh is not None
                        and seq_axis is not None
                        and mesh.shape.get(seq_axis, 1) > 1)
-        if use_flash and (not seq_sharded or self.attn_impl == "pallas"):
+        if self.grouped and pipe > 1:
+            raise ValueError(
+                "transformer_stack: the grouped block (rotary positions, "
+                "grouped heads, attn_mask = block_diffusion, the sorted "
+                "dispatch) does not run under pipeline_parallel: the "
+                "stages' shard_map hands a block neither its mask's "
+                "schedule nor its counters")
+        if use_flash and not self.grouped \
+                and (not seq_sharded or self.attn_impl == "pallas"):
             fhw, bhw = fa.analytic_flops(b, self.nhead, s,
                                          e // self.nhead,
                                          bool(self.causal))
@@ -2160,24 +2556,32 @@ class TransformerStackLayer(Layer):
             # sliced-stack access without removing the loop).
             # Costs compile time ~linear in depth; opt-in by knob.
             folded = self._fold_norms(params, dt)
-            aux_total = jnp.zeros((), jnp.float32)
+            auxs = []
             for i in range(self.nlayer):
                 lp = jax.tree.map(lambda v, i=i: v[i], folded)
                 h, a = block(lp, h)
-                aux_total = aux_total + a
+                auxs.append(jnp.asarray(a, jnp.float32))
+            auxs = jnp.stack(auxs)
         else:
-            def body(carry, lp):
-                hh, aux = carry
+            def body(hh, lp):
                 h2, a = block(lp, hh)
-                return (h2, aux + a), None
-            (h, aux_total), _ = jax.lax.scan(
-                body, (h, jnp.zeros((), jnp.float32)),
-                self._fold_norms(params, dt),
+                return h2, jnp.asarray(a, jnp.float32)
+            h, auxs = jax.lax.scan(
+                body, h, self._fold_norms(params, dt),
                 unroll=max(1, min(self.scan_unroll, self.nlayer)))
-        if pipe == 1 and self.moe and ctx.train and self.moe_loss > 0.0:
-            # shared tail for the unroll and scan paths (the pipeline
-            # branch rejects moe above)
-            ctx.losses.append(self.moe_loss * aux_total / self.nlayer)
+        # a layer's aux, one a layer: the one-hot dispatch's load-balance
+        # loss, or the sorted dispatch's counters (the pipeline branch
+        # rejects moe above)
+        if pipe == 1 and self.moe and self.sorted:
+            from .ops.moe_sorted import STATS
+            for j, name in enumerate(STATS):
+                ctx.stats[(ctx.layer_index, "moe_" + name)] = auxs[:, j]
+        elif pipe == 1 and self.moe and ctx.train and self.moe_loss > 0.0:
+            ctx.losses.append(self.moe_loss * jnp.sum(auxs) / self.nlayer)
+        if self.final_norm:
+            h = (h.astype(jnp.float32) * jax.lax.rsqrt(jnp.mean(
+                jnp.square(h.astype(jnp.float32)), -1, keepdims=True)
+                + 1e-6) * params["normf"])
         return [h.astype(jnp.float32).reshape(b, 1, s, e)]
 
 
@@ -2271,9 +2675,19 @@ class LMHeadLayer(_LossLayer):
         super().__init__()
         self.ce_chunk = 0
         self.logit_dtype = "compute"
+        self.objective = "next_token"
 
     def set_param(self, name, val):
-        if name == "ce_chunk":
+        if name == "objective":
+            # block_diffusion: two inputs, the stack's output over
+            # [x_t ; x_0] and bd_noise's (target, weight) node; the loss
+            # is over the noisy half, each position's CE against its
+            # clean token times its weight; the label field is not read
+            if val not in ("next_token", "block_diffusion"):
+                raise ValueError(
+                    "lm_head: objective must be next_token|block_diffusion")
+            self.objective = val
+        elif name == "ce_chunk":
             self.ce_chunk = int(val)
         elif name == "logit_dtype":
             if val not in ("compute", "float32"):
@@ -2282,6 +2696,20 @@ class LMHeadLayer(_LossLayer):
             self.logit_dtype = val
         else:
             super().set_param(name, val)
+
+    def infer_shape(self, in_shapes):
+        if self.objective != "block_diffusion":
+            return super().infer_shape(in_shapes)
+        self._check_arity(in_shapes, 2, 1)
+        n, c, s2, e = in_shapes[0]
+        if in_shapes[1] != (n, 1, s2 // 2, 2):
+            raise ValueError(
+                "lm_head: objective = block_diffusion reads the stack's "
+                "(batch,1,2*seq,embed) and bd_noise's (batch,1,seq,2); "
+                "got %s and %s" % (in_shapes[0], in_shapes[1]))
+        out = self._infer([(n, c, s2 // 2, e)])
+        self.in_shapes, self.out_shapes = list(in_shapes), out
+        return out
 
     def _infer(self, in_shapes):
         n, c, s, e = in_shapes[0]
@@ -2306,6 +2734,8 @@ class LMHeadLayer(_LossLayer):
 
     def analytic_flops(self, skip_dx=False):
         n, _, s, e = self.in_shapes[0]
+        if self.objective == "block_diffusion":
+            s //= 2                     # the noisy half alone
         f = 2.0 * n * s * e * self.param.num_hidden
         return f, f if skip_dx else 2.0 * f
 
@@ -2325,7 +2755,11 @@ class LMHeadLayer(_LossLayer):
         v = self.param.num_hidden
         dt = ctx.compute_dtype if self.logit_dtype == "compute" \
             else jnp.float32
-        x = inputs[0].reshape(n * s, e).astype(dt)
+        hidden, side = inputs[0], None
+        if self.objective == "block_diffusion":
+            s //= 2
+            hidden, side = hidden[:, :, :s], inputs[1].reshape(n * s, 2)
+        x = hidden.reshape(n * s, e).astype(dt)
         w = params["wmat"].astype(dt)
         bias = params.get("bias")
 
@@ -2338,9 +2772,10 @@ class LMHeadLayer(_LossLayer):
         # eval/predict surface (dead code in fused-loss train traces)
         probs = jax.nn.softmax(
             _stable_logits(logits_of(x).astype(jnp.float32)), axis=-1)
-        if ctx.labels is not None:
-            y = self._label(ctx).astype(jnp.int32)
-            if s > 1 and y.shape[1] != s:
+        if ctx.labels is not None or side is not None:
+            y = (side[:, 0] if side is not None
+                 else self._label(ctx)).astype(jnp.int32)
+            if side is None and s > 1 and y.shape[1] != s:
                 raise ValueError(
                     "lm_head on a %d-position sequence needs an equally "
                     "wide label field (declare label_vec[0,%d) = %s and "
@@ -2350,7 +2785,8 @@ class LMHeadLayer(_LossLayer):
             c = self._chunks(rows, v)
             chunk = -(-rows // c)        # pad + mask the ragged tail
             yf = y.reshape(rows)
-            wf = jnp.ones((rows,), jnp.float32)
+            wf = side[:, 1] if side is not None \
+                else jnp.ones((rows,), jnp.float32)
             if c * chunk != rows:
                 extra = c * chunk - rows
                 x = jnp.pad(x, ((0, extra), (0, 0)))

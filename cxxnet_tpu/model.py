@@ -171,7 +171,8 @@ class Network:
               train: bool = False,
               rng: Optional[jnp.ndarray] = None,
               epoch=0,
-              state_out: Optional[Dict] = None
+              state_out: Optional[Dict] = None,
+              stats_out: Optional[Dict] = None
               ) -> Tuple[Dict[int, jnp.ndarray], jnp.ndarray]:
         """Run the DAG; returns ({node_index: value}, scalar_loss).
 
@@ -179,7 +180,9 @@ class Network:
         (reference GetLabelInfo, nnet_impl-inl.hpp:271-285).
         ``state_out``, when given, receives {(layer_index, tag): value}
         non-trainable state writes (BN running stats) for the trainer to
-        fold back into params.
+        fold back into params. ``stats_out`` receives the layers'
+        device-side counters, {(layer_index, name): array}
+        (``ApplyContext.stats``).
         """
         ctx = L.ApplyContext(
             train=train, rng=rng, labels=labels,
@@ -234,6 +237,8 @@ class Network:
             loss = jnp.zeros((), jnp.float32)
         if state_out is not None:
             state_out.update(ctx.state_updates)
+        if stats_out is not None:
+            stats_out.update(ctx.stats)
         # trace-time side record (plain Python floats; tracing runs once
         # per compiled program, so this survives for step_cost_analysis)
         self.pallas_flops_record[bool(train)] = list(ctx.pallas_flops)

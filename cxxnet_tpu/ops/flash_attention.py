@@ -1290,6 +1290,433 @@ _flash_flatb.defvjp(_flash_flatb_fwd, _flash_flatb_bwd)
 
 
 # ----------------------------------------------------------------------
+# grouped-query kernels over a static schedule of tiles: kv heads shared
+# by a group of q heads, and masks that empty whole tiles (causal, block
+# diffusion). q, o and do stay in the projection's (b, S, heads * d)
+# layout and k, v in (b, S, kv_heads * d): a grid step takes one tile of
+# queries of one kv head's whole group against one tile of its keys, the
+# heads of the group in a static loop, so k and v are read once a group
+# and dk, dv sum over the group in the accumulator. Scores are held keys
+# on sublanes (st[j, i] = k_j . q_i), as the blocked flat kernels hold
+# them: every statistic is a lane row, and dkv needs no transpose.
+#
+# The mask is static, so the tile pairs it leaves are listed once on the
+# host (``gq_schedule``) and the grid walks that list: a pair the mask
+# empties costs nothing, not even a grid step. Each pair carries the
+# bounds (lo, hi) its scores are kept between, on the difference of the
+# key's and the query's block index within their tiles.
+# ----------------------------------------------------------------------
+GQ_TILE = 512           # queries and keys a tile
+_GQ_OPEN = 1 << 30      # a bound that keeps every pair of a tile
+
+
+def gq_tile(seq_len: int, tile: int = 0) -> int:
+    """Tile of a segment of ``seq_len`` positions: ``GQ_TILE``, or the
+    segment rounded up to whole lanes where it is shorter."""
+    return tile or min(GQ_TILE, -(-seq_len // LANES) * LANES)
+
+
+def gq_pairs(mask: str, n: int):
+    """[(q tile, k tile, lo, hi)] the mask leaves, q-major, for segments
+    of ``n`` tiles. ``causal``: one segment, a key at or before the
+    query. ``block_diffusion``: ``[x_t ; x_0]``, tiles [0, n) noisy and
+    [n, 2n) clean, both halves at the same positions: a noisy query sees
+    the noisy keys of its own block and the clean keys of earlier
+    blocks, a clean query the clean keys of its own and earlier blocks.
+    A query's first pair always holds a key it sees (the online softmax
+    starts from real scores)."""
+    full = (-_GQ_OPEN, _GQ_OPEN)
+    out = []
+    if mask == "causal":
+        for j in range(n):
+            out += [(j, t) + full for t in range(j)]
+            out.append((j, j, -_GQ_OPEN, 0))
+    elif mask == "block_diffusion":
+        for j in range(n):
+            out.append((j, j, 0, 0))
+            out += [(j, n + t) + full for t in range(j)]
+            out.append((j, n + j, -_GQ_OPEN, -1))
+        for j in range(n):
+            out += [(n + j, n + t) + full for t in range(j)]
+            out.append((n + j, n + j, -_GQ_OPEN, 0))
+    else:
+        raise ValueError("mask must be causal|block_diffusion, not %r"
+                         % mask)
+    return out
+
+
+def gq_schedule(mask: str, n: int, by: str):
+    """The pairs as the int32 rows a kernel prefetches: q tile, k tile,
+    lo, hi, first and last of its run; ``by`` = "q" (forward, dq: runs
+    of one q tile) or "k" (dkv: runs of one k tile)."""
+    pairs = gq_pairs(mask, n)
+    col = 0 if by == "q" else 1
+    if by == "k":
+        pairs = sorted(pairs, key=lambda p: (p[1], p[0]))
+    run = [p[col] for p in pairs]
+    first = [int(i == 0 or run[i - 1] != r) for i, r in enumerate(run)]
+    last = first[1:] + [1]
+    rows = [list(c) for c in zip(*pairs)] + [first, last]
+    return tuple(np.asarray(r, np.int32) for r in rows)
+
+
+def gq_pairs_allowed(mask: str, seq_len: int, block_len: int) -> int:
+    """Query-key pairs the mask allows over segments of ``seq_len``."""
+    if mask == "causal":
+        return seq_len * (seq_len + 1) // 2
+    nb = seq_len // block_len
+    # noisy-noisy B a query; noisy-clean B * b; clean-clean B * (b + 1)
+    return block_len * block_len * (nb + nb * (nb - 1) // 2
+                                    + nb * (nb + 1) // 2)
+
+
+def _gq_keep(lo, hi, T, shift):
+    """(T keys, T queries) bool: the pairs of a tile whose block-index
+    difference lies in [lo, hi]."""
+    kb = lax.shift_right_logical(
+        lax.broadcasted_iota(jnp.int32, (T, T), 0), shift)
+    qb = lax.shift_right_logical(
+        lax.broadcasted_iota(jnp.int32, (T, T), 1), shift)
+    diff = kb - qb
+    return (diff >= lo) & (diff <= hi)
+
+
+def _gq_bodies(lo_ref, hi_ref, si, T, shift, work):
+    """``work(keep)`` with the tile's mask where it has one, with None
+    where it keeps every pair: two bodies, so an open tile pays no
+    select."""
+    lo, hi = lo_ref[si], hi_ref[si]
+    is_open = (lo <= -_GQ_OPEN) & (hi >= _GQ_OPEN)
+    pl.when(is_open)(lambda: work(None))
+    pl.when(jnp.logical_not(is_open))(
+        lambda: work(_gq_keep(lo, hi, T, shift)))
+
+
+def _gq_scores(k, q_ref, g, d, keep):
+    st = _dot(k, q_ref[0, :, g * d:(g + 1) * d], _NT)    # (keys, queries)
+    return st if keep is None else jnp.where(keep, st, NEG_INF)
+
+
+def _gq_fwd_kernel(qt_ref, kt_ref, lo_ref, hi_ref, first_ref, last_ref,
+                   q_ref, k_ref, v_ref, o_ref, lse_ref,
+                   vt_s, m_s, l_s, acc_s, *, G, d, T, shift):
+    si = pl.program_id(2)
+
+    @pl.when(first_ref[si] == 1)
+    def _init():
+        m_s[...] = jnp.full_like(m_s, NEG_INF)
+        l_s[...] = jnp.zeros_like(l_s)
+        acc_s[...] = jnp.zeros_like(acc_s)
+
+    vt_s[...] = v_ref[0].T                               # (d, keys)
+
+    def work(keep):
+        k = k_ref[0]
+        for g in range(G):
+            st = _gq_scores(k, q_ref, g, d, keep)
+            m1 = m_s[g]                                  # (1, queries)
+            m2 = jnp.maximum(m1, jnp.max(st, axis=0, keepdims=True))
+            p = jnp.exp(st - m2)
+            corr = jnp.exp(m1 - m2)
+            l_s[g] = l_s[g] * corr + jnp.sum(p, axis=0, keepdims=True)
+            # acc[c, i] += sum_j v[j, c] p[j, i]
+            acc_s[g] = acc_s[g] * corr + _dot(
+                vt_s[...], p.astype(vt_s.dtype), _NN)
+            m_s[g] = m2
+
+    _gq_bodies(lo_ref, hi_ref, si, T, shift, work)
+
+    @pl.when(last_ref[si] == 1)
+    def _flush():
+        lsafe = jnp.maximum(l_s[...], 1e-30)             # (G, 1, T)
+        o_ref[0] = (acc_s[...] / lsafe).reshape(G * d, T).T.astype(
+            o_ref.dtype)
+        lse_ref[0, 0] = m_s[...] + jnp.log(lsafe)
+
+
+def _gq_dq_kernel(qt_ref, kt_ref, lo_ref, hi_ref, first_ref, last_ref,
+                  q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+                  kt_s, acc_s, *, G, d, T, shift, scale):
+    si = pl.program_id(2)
+
+    @pl.when(first_ref[si] == 1)
+    def _init():
+        acc_s[...] = jnp.zeros_like(acc_s)
+
+    kt_s[...] = k_ref[0].T                               # (d, keys)
+
+    def work(keep):
+        k, v = k_ref[0], v_ref[0]
+        for g in range(G):
+            p = jnp.exp(_gq_scores(k, q_ref, g, d, keep)
+                        - lse_ref[0, 0, g])
+            dp = _dot(v, do_ref[0, :, g * d:(g + 1) * d], _NT)
+            ds = (p * (dp - delta_ref[0, 0, g])).astype(kt_s.dtype)
+            # dq[c, i] += sum_j k[j, c] ds[j, i]
+            acc_s[g] = acc_s[g] + _dot(kt_s[...], ds, _NN)
+
+    _gq_bodies(lo_ref, hi_ref, si, T, shift, work)
+
+    @pl.when(last_ref[si] == 1)
+    def _flush():
+        # q came in scaled: the chain rule's factor goes on here
+        dq_ref[0] = (acc_s[...] * scale).reshape(G * d, T).T.astype(
+            dq_ref.dtype)
+
+
+def _gq_dkv_kernel(qt_ref, kt_ref, lo_ref, hi_ref, first_ref, last_ref,
+                   q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                   dk_ref, dv_ref, dk_s, dv_s, *, G, d, T, shift):
+    si = pl.program_id(2)
+
+    @pl.when(first_ref[si] == 1)
+    def _init():
+        dk_s[...] = jnp.zeros_like(dk_s)
+        dv_s[...] = jnp.zeros_like(dv_s)
+
+    def work(keep):
+        k, v = k_ref[0], v_ref[0]
+        dk, dv = dk_s[...], dv_s[...]
+        for g in range(G):                   # the group sums in dk, dv
+            qg = q_ref[0, :, g * d:(g + 1) * d]
+            dog = do_ref[0, :, g * d:(g + 1) * d]
+            p = jnp.exp(_gq_scores(k, q_ref, g, d, keep)
+                        - lse_ref[0, 0, g])
+            dv = dv + _dot(p.astype(dog.dtype), dog, _NN)
+            dp = _dot(v, dog, _NT)
+            ds = (p * (dp - delta_ref[0, 0, g])).astype(qg.dtype)
+            # against the scaled q: dk carries the factor already
+            dk = dk + _dot(ds, qg, _NN)
+        dk_s[...], dv_s[...] = dk, dv
+
+    _gq_bodies(lo_ref, hi_ref, si, T, shift, work)
+
+    @pl.when(last_ref[si] == 1)
+    def _flush():
+        dk_ref[0] = dk_s[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_s[...].astype(dv_ref.dtype)
+
+
+def _gq_specs(G, d, T):
+    """BlockSpecs by role; an index map takes the grid's (row, kv head,
+    step) and then the schedule's six rows."""
+    wide = pl.BlockSpec((1, T, G * d),
+                        lambda b, h, s, qt, *_: (b, qt[s], h))
+    kv = pl.BlockSpec((1, T, d), lambda b, h, s, qt, kt, *_: (b, kt[s], h))
+    stat = pl.BlockSpec((1, 1, G, 1, T),
+                        lambda b, h, s, qt, *_: (b, h, 0, 0, qt[s]))
+    return wide, kv, stat
+
+
+def _gq_call(name, kernel, sched, grid, in_specs, out_specs, out_shape,
+             scratch, interpret):
+    from jax.experimental.pallas import tpu as pltpu
+    return _named_call(
+        name, kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(sched), grid=grid, in_specs=in_specs,
+            out_specs=out_specs, scratch_shapes=scratch),
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=64 << 20),
+        interpret=interpret)
+
+
+# jitted on their own, the plan static, so that a model's layers trace
+# and lower these kernels once (see _flatb_fwd_call)
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7))
+def _gq_fwd_call(q, k, v, nkv, mask, n, shift, interpret):
+    from jax.experimental.pallas import tpu as pltpu
+    b, S, hd = k.shape
+    d = hd // nkv
+    G = q.shape[2] // hd
+    T = S // (2 * n if mask == "block_diffusion" else n)
+    sched = gq_schedule(mask, n, "q")
+    wide, kv, stat = _gq_specs(G, d, T)
+    return _gq_call(
+        "flash_gq_fwd",
+        functools.partial(_gq_fwd_kernel, G=G, d=d, T=T, shift=shift),
+        sched, (b, nkv, len(sched[0])), [wide, kv, kv], [wide, stat],
+        [jax.ShapeDtypeStruct(q.shape, q.dtype),
+         jax.ShapeDtypeStruct((b, nkv, G, 1, S), jnp.float32)],
+        [pltpu.VMEM((d, T), v.dtype),                   # v.T
+         pltpu.VMEM((G, 1, T), jnp.float32),            # m
+         pltpu.VMEM((G, 1, T), jnp.float32),            # l
+         pltpu.VMEM((G, d, T), jnp.float32)],           # acc
+        interpret)(*sched, q, k, v)
+
+
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11))
+def _gq_bwd_call(q, k, v, o, lse, do, nkv, mask, n, shift, scale,
+                 interpret):
+    from jax.experimental.pallas import tpu as pltpu
+    b, S, hd = k.shape
+    d = hd // nkv
+    G = q.shape[2] // hd
+    T = S // (2 * n if mask == "block_diffusion" else n)
+    delta = jnp.sum((do.astype(jnp.float32) * o.astype(jnp.float32)
+                     ).reshape(b, S, nkv, G, d), axis=-1)
+    delta = delta.transpose(0, 2, 3, 1)[:, :, :, None, :]   # as lse
+    wide, kv, stat = _gq_specs(G, d, T)
+    sq = gq_schedule(mask, n, "q")
+    dq = _gq_call(
+        "flash_gq_dq",
+        functools.partial(_gq_dq_kernel, G=G, d=d, T=T, shift=shift,
+                          scale=scale),
+        sq, (b, nkv, len(sq[0])), [wide, kv, kv, wide, stat, stat], wide,
+        jax.ShapeDtypeStruct(q.shape, q.dtype),
+        [pltpu.VMEM((d, T), k.dtype),                   # k.T
+         pltpu.VMEM((G, d, T), jnp.float32)],           # dq.T
+        interpret)(*sq, q, k, v, do, lse, delta)
+    sk = gq_schedule(mask, n, "k")
+    dk, dv = _gq_call(
+        "flash_gq_dkv",
+        functools.partial(_gq_dkv_kernel, G=G, d=d, T=T, shift=shift),
+        sk, (b, nkv, len(sk[0])), [wide, kv, kv, wide, stat, stat],
+        [kv, kv],
+        [jax.ShapeDtypeStruct(k.shape, k.dtype),
+         jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        [pltpu.VMEM((T, d), jnp.float32),
+         pltpu.VMEM((T, d), jnp.float32)],
+        interpret)(*sk, q, k, v, do, lse, delta)
+    return dq, dk, dv
+
+
+def _gq_plan(S, qw, hd, nkv, mask, block_len, tile):
+    """-> (segments, positions a segment, tile, tiles a segment, shift)
+    of a call on ``S`` positions, q ``qw`` and k ``hd`` wide, checked."""
+    segs = 2 if mask == "block_diffusion" else 1
+    B = block_len if mask == "block_diffusion" else 1
+    if hd % nkv or qw % hd or (hd // nkv) % LANES:
+        raise ValueError(
+            "flash_attention_gq: q %d wide and k %d wide do not split "
+            "into %d kv heads of whole 128-lane head size with whole "
+            "groups" % (qw, hd, nkv))
+    if S % segs or (S // segs) % B or B & (B - 1) or B > LANES:
+        raise ValueError(
+            "flash_attention_gq: %d positions are not %d segments of "
+            "whole blocks of %d (a power of two up to 128)"
+            % (S, segs, B))
+    L = S // segs
+    T = gq_tile(L, tile)
+    return segs, L, T, -(-L // T), B.bit_length() - 1
+
+
+def _gq_pad(x, segs, L, Lp):
+    """Each segment padded to whole tiles (a padded key lies in a later
+    block than every real query of its tile, so every mask hides it)."""
+    if Lp == L:
+        return x
+    b, _, w = x.shape
+    x = jnp.pad(x.reshape(b, segs, L, w), ((0, 0), (0, 0), (0, Lp - L),
+                                           (0, 0)))
+    return x.reshape(b, segs * Lp, w)
+
+
+def _gq_unpad(x, segs, L, Lp):
+    if Lp == L:
+        return x
+    b, _, w = x.shape
+    return x.reshape(b, segs, Lp, w)[:, :, :L].reshape(b, segs * L, w)
+
+
+def flash_attention_gq(q, k, v, nkv: int, mask: str = "causal",
+                       block_len: int = 1, scale=None, interpret=None,
+                       tile: int = 0):
+    """Grouped-query attention under a tile-scheduled mask, O(S d)
+    memory: q (b, S, heads * d), k, v (b, S, nkv * d) in the
+    projections' own layout -> (b, S, heads * d); q head j reads kv head
+    j // (heads / nkv). ``mask`` as ``gq_pairs`` has it; under
+    ``block_diffusion`` S is ``[x_t ; x_0]`` and ``block_len`` the
+    diffusion block."""
+    if interpret is None:
+        interpret = _interpret()
+    if scale is None:
+        scale = (k.shape[2] // nkv) ** -0.5
+    return _flash_gq(q, k, v, nkv, mask, block_len, float(scale),
+                     bool(interpret), tile)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_gq(q, k, v, nkv, mask, block_len, scale, interpret, tile):
+    return _flash_gq_fwd(q, k, v, nkv, mask, block_len, scale, interpret,
+                         tile)[0]
+
+
+def _gq_mark(kernels, S, qw, hd, nkv, mask, block_len, plan):
+    segs, L, T, n, _ = plan
+    return {"kernels": kernels, "s": S, "h": qw * nkv // hd,
+            "kv_heads": nkv, "d": hd // nkv,
+            "mask": mask, "block_len": block_len, "block_q": T,
+            "block_k": T, "tile_pairs": len(gq_pairs(mask, n)),
+            "tile_pairs_dense": (segs * n) ** 2}
+
+
+def _flash_gq_fwd(q, k, v, nkv, mask, block_len, scale, interpret, tile):
+    from ..obs import trace
+    dims = (q.shape[1], q.shape[2], k.shape[2])
+    plan = _gq_plan(*dims, nkv, mask, block_len, tile)
+    segs, L, T, n, shift = plan
+    # the scale folded into q once; the scaled q is what the backward
+    # kernels take (the chain rule's factor goes on dq at its flush)
+    qs, ks, vs = (_gq_pad(x, segs, L, n * T)
+                  for x in (q * jnp.asarray(scale, q.dtype), k, v))
+    with trace.span("flash.plan", "kernel",
+                    _gq_mark("fwd", *dims, nkv, mask, block_len, plan)):
+        o, lse = _gq_fwd_call(qs, ks, vs, nkv, mask, n, shift, interpret)
+    return _gq_unpad(o, segs, L, n * T), (qs, ks, vs, o, lse)
+
+
+def _flash_gq_bwd(nkv, mask, block_len, scale, interpret, tile, res, g):
+    from ..obs import trace
+    qs, ks, vs, o, lse = res
+    dims = (g.shape[1], g.shape[2], ks.shape[2])
+    plan = _gq_plan(*dims, nkv, mask, block_len, tile)
+    segs, L, T, n, shift = plan
+    do = _gq_pad(g, segs, L, n * T)
+    with trace.span("flash.plan", "kernel",
+                    _gq_mark("bwd", *dims, nkv, mask, block_len, plan)):
+        dq, dk, dv = _gq_bwd_call(qs, ks, vs, o, lse, do, nkv, mask, n,
+                                  shift, scale, interpret)
+    return tuple(_gq_unpad(x, segs, L, n * T) for x in (dq, dk, dv))
+
+
+_flash_gq.defvjp(_flash_gq_fwd, _flash_gq_bwd)
+
+
+def attention_gq_dense(q, k, v, nkv: int, mask: str = "causal",
+                       block_len: int = 1, scale=None):
+    """``flash_attention_gq``'s result by a dense mask in plain XLA: the
+    path off the TPU, the kernels' twin in the tests, and the one path
+    of ``mask = "full"`` (no tile of it is ever empty)."""
+    b, S, hd = k.shape
+    d = hd // nkv
+    G = q.shape[2] // hd
+    if scale is None:
+        scale = d ** -0.5
+    idx = jnp.arange(S)
+    if mask == "full":
+        keep = jnp.ones((S, S), jnp.bool_)
+    elif mask == "causal":
+        keep = idx[None, :] <= idx[:, None]
+    else:
+        L = S // 2
+        qn, kn = idx[:, None] < L, idx[None, :] < L
+        qb = (idx[:, None] % L) // block_len
+        kb = (idx[None, :] % L) // block_len
+        keep = (qn & kn & (qb == kb)) | (qn & ~kn & (kb < qb)) \
+            | (~qn & ~kn & (kb <= qb))
+    sc = jnp.einsum("bqkgd,bskd->bkgqs", q.reshape(b, S, nkv, G, d),
+                    k.reshape(b, S, nkv, d),
+                    preferred_element_type=jnp.float32) * scale
+    p = jax.nn.softmax(jnp.where(keep, sc, NEG_INF), axis=-1)
+    out = jnp.einsum("bkgqs,bskd->bqkgd", p.astype(v.dtype),
+                     v.reshape(b, S, nkv, d))
+    return out.reshape(b, S, nkv * G * d)
+
+
+# ----------------------------------------------------------------------
 def flash_attention(q, k, v, causal: bool = False, scale=None,
                     interpret=None):
     """(b, h, s, d) attention, O(s*d) memory. Exact — same math as

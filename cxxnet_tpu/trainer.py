@@ -12,6 +12,7 @@ buffer and applies the updaters every k-th call.
 
 from __future__ import annotations
 
+import collections
 import os
 import sys
 from functools import partial
@@ -177,6 +178,9 @@ class Trainer:
         self.opt_state = None
         self.grad_accum = None
         self.last_loss = None
+        # (step number, {(layer, name): device array}) of the steps whose
+        # layer counters have not been read yet (Trainer._drain_stats)
+        self._stats_flight = collections.deque()
         self._step_count = 0
         self._step_specs = None
         self._train_multi = None
@@ -529,32 +533,35 @@ class Trainer:
 
         def fwd_bwd(params, data, extras, labels, rng, epoch):
             def loss_fn(p):
-                supd = {}
+                supd, seen = {}, {}
                 values, loss = net.apply(
                     p, data, extra_data=extras, labels=labels, train=True,
-                    rng=rng, epoch=epoch, state_out=supd)
-                return loss, (tuple(values[i] for i in eval_req), supd)
-            (loss, (evals, supd)), grads = jax.value_and_grad(
+                    rng=rng, epoch=epoch, state_out=supd, stats_out=seen)
+                return loss, (tuple(values[i] for i in eval_req), supd,
+                              seen)
+            (loss, (evals, supd, seen)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(params)
-            return loss, evals, supd, grads
+            return loss, evals, supd, grads, seen
 
 
         def train_step(params, opt_state, rng, epoch, maccum,
                        data, extras, labels):
             use, nxt = jax.random.split(rng)
-            loss, evals, supd, grads = fwd_bwd(params, data, extras,
-                                               labels, use, epoch)
+            loss, evals, supd, grads, stats = fwd_bwd(
+                params, data, extras, labels, use, epoch)
             grads = _strip_nones(grads)
             params2, opt2 = opt_.apply(params, grads, opt_state, epoch)
             params2 = _merge_state(params2, supd)
             maccum = fold_train_metric(maccum, evals, labels, loss)
-            return params2, opt2, nxt, epoch + 1, maccum, loss
+            # the layers' device-side counters ride out with the loss
+            # (an empty dict for a net that has none: the same program)
+            return params2, opt2, nxt, epoch + 1, maccum, loss, stats
 
         def accum_step(grad_accum, rng, maccum, params, epoch,
                        data, extras, labels):
             use, nxt = jax.random.split(rng)
-            loss, evals, supd, grads = fwd_bwd(params, data, extras,
-                                               labels, use, epoch)
+            loss, evals, supd, grads, _ = fwd_bwd(params, data, extras,
+                                                  labels, use, epoch)
             grads = _strip_nones(grads)
             acc = jax.tree.map(jnp.add, grad_accum, grads)
             maccum = fold_train_metric(maccum, evals, labels, loss)
@@ -626,7 +633,7 @@ class Trainer:
             _jitcheck.make_donating(jax.jit(
                 train_step, donate_argnums=(0, 1, 2, 3, 4) + don_data,
                 in_shardings=in_train,
-                out_shardings=(psh, osh, rep, rep, rep, None)),
+                out_shardings=(psh, osh, rep, rep, rep, None, None)),
                 argnums=(0, 1, 2, 3, 4) + don_data,
                 site="Trainer._train_step"),
             in_shardings=in_train, site="Trainer._train_step")
@@ -692,7 +699,9 @@ class Trainer:
                 # short against it (not measured on the chip).
                 def body(carry, x):
                     p, o, r, e, m = carry
-                    p, o, r, e, m, loss = train_step(p, o, r, e, m, *x)
+                    # (the layers' counters stay inside a fused group)
+                    p, o, r, e, m, loss, _ = train_step(p, o, r, e, m,
+                                                        *x)
                     return (p, o, r, e, m), loss
 
                 # fuse_unroll > 1 unrolls the scan body: the group
@@ -1030,10 +1039,46 @@ class Trainer:
         if isinstance(batch, StagedBatch) and batch.fused:
             return self.update_fused(batch)
         self._step_count += 1
-        with _trace.phase("trainer.update", "train",
-                          {"step_num": self._step_count, "fused": 0,
-                           "step": getattr(batch, "step", None)}):
+        args = {"step_num": self._step_count, "fused": 0,
+                "step": getattr(batch, "step", None)}
+        if self._stats_flight:
+            args.update(self._drain_stats())
+        with _trace.phase("trainer.update", "train", args):
             self._update(batch)
+
+    def _drain_stats(self) -> dict:
+        """The layers' counters (``ApplyContext.stats``) of the steps
+        that have ended since the last call, read without waiting on the
+        device (a step still running stays in flight). Each value goes
+        to the registry by the name its layer gave it, one series a row:
+        a name that ends in ``_max`` to the gauge ``cxxnet_<name>``,
+        any other to the counter ``cxxnet_<name>_total``. The newest
+        ended step's come back as span arguments: ``stats_step`` and,
+        for every name, its sum (its largest, for ``_max``) over the
+        layers."""
+        from .obs.registry import get_registry
+        reg, newest = get_registry(), {}
+        while self._stats_flight:
+            step, stats = self._stats_flight[0]
+            if not all(x.is_ready() for x in jax.tree.leaves(stats)):
+                break
+            self._stats_flight.popleft()
+            newest = {"stats_step": step}
+            for (layer, name), v in sorted(stats.items()):
+                largest = name.endswith("_max")
+                rows = np.asarray(v, np.float64).reshape(-1)
+                for i, x in enumerate(rows):
+                    where = "%d.%d" % (layer, i)
+                    if largest:
+                        reg.gauge("cxxnet_" + name, _STAT_HELP,
+                                  ("layer",)).set(float(x), layer=where)
+                    else:
+                        reg.counter("cxxnet_%s_total" % name, _STAT_HELP,
+                                    ("layer",)).inc(float(x), layer=where)
+                fold = max if largest else sum
+                seen = [newest[name]] if name in newest else []
+                newest[name] = float(fold(seen + [fold(rows)]))
+        return newest
 
     def _update(self, batch) -> None:
         if isinstance(batch, StagedBatch):
@@ -1050,9 +1095,11 @@ class Trainer:
                     (self.params, self.opt_state, self._rng,
                      self._epoch_dev, self._maccum, data, extras, labels))
             (self.params, self.opt_state, self._rng, self._epoch_dev,
-             self._maccum, loss) = self._train_step(
+             self._maccum, loss, stats) = self._train_step(
                 self.params, self.opt_state, self._rng, self._epoch_dev,
                 self._maccum, data, extras, labels)
+            if stats:
+                self._stats_flight.append((self._step_count, stats))
         else:
             (self.grad_accum, self._rng, self._maccum,
              loss, supd) = self._accum_step(
@@ -1774,6 +1821,10 @@ class Trainer:
                 cur[tag] = jnp.asarray(arr)
             params[j] = cur
         self.params = jax.device_put(params, self._psh)
+
+
+_STAT_HELP = ("a counter a layer computes on the device in the train "
+              "step (ApplyContext.stats): layer = <net layer>.<row>")
 
 
 def _strip_nones(tree):

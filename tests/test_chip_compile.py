@@ -155,3 +155,47 @@ def test_lrn_fwd_bwd_compiles(one_chip):
 
     _compile(jax.value_and_grad(loss), one_chip,
              ((256, 96, 27, 27), jnp.bfloat16))
+
+
+def test_flash_attention_gq_block_diffusion_compiles(one_chip):
+    """The benchmark's cell train.sdar_30b_a3b.seq4096: 2 rows of
+    [x_t ; x_0] = 8,192 positions, 32 q heads on 4 kv heads of d 128,
+    the block-diffusion mask as a schedule of 512-position tiles. Each
+    kernel carries the name ``bd_attn_*_roofline.train`` match."""
+    from cxxnet_tpu.ops import flash_attention as fa
+    b, S, nh, nkv, d = 2, 8192, 32, 4, 128
+
+    def loss(q, k, v):
+        return fa.flash_attention_gq(
+            q, k, v, nkv, "block_diffusion", 4,
+            interpret=False).astype(jnp.float32).sum()
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip,
+                    ((b, S, nh * d), jnp.bfloat16),
+                    ((b, S, nkv * d), jnp.bfloat16),
+                    ((b, S, nkv * d), jnp.bfloat16))
+    assert _kernel_names(text) == {"flash_gq_fwd", "flash_gq_dq",
+                                   "flash_gq_dkv"}
+
+
+def test_moe_sorted_compiles(one_chip):
+    """The same cell's routed layer: 16,384 positions, top 8 of 128
+    experts, 16 held, walked in pieces; megablox's kernels under the
+    names ``moe_expert_roofline.train`` matches, with or without a
+    ``jax.checkpoint`` around the layer."""
+    from cxxnet_tpu.ops import moe_sorted as ms
+    P, e, m, total, held, topk = 16384, 2048, 768, 128, 16, 8
+
+    def layer(x, gate, w1, w2):
+        return ms.moe_sorted(
+            x, {"gate": gate, "w1": w1, "w2": w2}, topk=topk, total=total,
+            first=0, held=held, norm_topk=True, dt=jnp.bfloat16,
+            interpret=False)[0]
+
+    shapes = (((P, e), jnp.bfloat16), ((total, e), jnp.float32),
+              ((held, e, 2 * m), jnp.float32), ((held, m, e), jnp.float32))
+    for fn in (layer, jax.checkpoint(layer)):
+        text = _compile(jax.grad(
+            lambda *a: fn(*a).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2, 3)), one_chip, *shapes)
+        assert _kernel_names(text) == {"moe_gmm", "moe_tgmm"}
