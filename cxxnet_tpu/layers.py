@@ -2343,9 +2343,11 @@ class TransformerStackLayer(Layer):
         MLP or the sorted expert dispatch, a scheduled mask. -> block(lp,
         h) -> (h, aux), aux the routed layer's counters
         (``ops.moe_sorted.STATS``) or 0."""
+        from jax.sharding import PartitionSpec as P
         from .ops import flash_attention as fa
         from .ops import moe_sorted as ms
         from .ops import pallas_env
+        from .ops import qk_prep as qp
         nh, nkv, d = self.nhead, self.nkv, self.hd
         mask, blen = self.mask, self.block_len
         rows = pallas_env.rows_spec(mesh)
@@ -2365,32 +2367,24 @@ class TransformerStackLayer(Layer):
                 % dict(mesh.shape))
         flash = use_flash and d % 128 == 0 and mask != "full"
 
-        tables = {}
+        # the q/k norms and the rotary positions between the projection
+        # and the attend: one Pallas pass each way where the kernels are
+        # in use and a head is whole lane tiles, plain XLA elsewhere
+        prep = dict(rope_theta=float(self.rope_theta or 0.0),
+                    segments=2 if mask == "block_diffusion" else 1)
+        fused = use_flash and d % 128 == 0 and bool(
+            self.qk_norm or self.rope_theta)
 
-        def rope(x, s):
-            """Rotate-half rotary positions on (b, s, heads, d); under
-            block diffusion both halves sit at positions 0..s/2-1."""
-            if s not in tables:     # one pair of constants a trace
-                half = s // 2 if mask == "block_diffusion" else s
-                inv = self.rope_theta ** (-np.arange(0, d, 2) / float(d))
-                ang = (np.arange(s) % half)[:, None] * inv[None]
-                tables[s] = tuple(
-                    np.concatenate([f(ang)] * 2, -1).astype(np.float32)[
-                        None, :, None] for f in (np.cos, np.sin))
-            cos, sin = tables[s]
-            x = x.astype(jnp.float32)
-            turned = jnp.concatenate([-x[..., d // 2:], x[..., :d // 2]],
-                                     -1)
-            return x * cos + turned * sin
-
-        def heads(x, g, s):
-            """(b, s, heads, d) -> normed and rotated, (b, s, heads*d)."""
-            b = x.shape[0]
-            if self.qk_norm:
-                x = rmsnorm(x, g)
-            if self.rope_theta:
-                x = rope(x, s)
-            return x.astype(dt).reshape(b, s, -1)
+        def prepare(lp, qkv):
+            """qkv -> (q, k, v), q and k normed and rotated."""
+            gains = (lp["qnorm"], lp["knorm"]) if self.qk_norm \
+                else (None, None)
+            if not fused:
+                return qp.qk_prep_plain(qkv, *gains, nh, nkv, **prep)
+            return pallas_env.per_shard(
+                mesh, lambda qkv, gains: qp.qk_prep(
+                    qkv, *gains, nh, nkv, interpret=interpret, **prep),
+                (rows, P()), (rows, rows, rows))(qkv, gains)
 
         def attend(q, k, v):
             if flash:
@@ -2417,7 +2411,6 @@ class TransformerStackLayer(Layer):
                         norm_topk=bool(self.moe_norm_topk), dt=dt,
                         interpret=interpret)
                     return y.reshape(x.shape), stats[None]
-                from jax.sharding import PartitionSpec as P
                 y, stats = pallas_env.per_shard(
                     mesh, routed, (rows, P(), P(), P()), (rows, rows))(
                         x, lp["gate"], lp["w1"], lp["w2"])
@@ -2435,15 +2428,9 @@ class TransformerStackLayer(Layer):
             return jnp.einsum("bsm,em->bse", a, lp["w2"].astype(dt)), 0.0
 
         def block(lp, h):
-            b, s, e = h.shape
             x = rmsnorm(h, None)          # gain folded into wqkv
             qkv = jnp.einsum("bse,fe->bsf", x, lp["wqkv"].astype(dt))
-            nq, nk = nh * d, nkv * d
-            q = heads(qkv[..., :nq].reshape(b, s, nh, d),
-                      lp.get("qnorm"), s)
-            k = heads(qkv[..., nq:nq + nk].reshape(b, s, nkv, d),
-                      lp.get("knorm"), s)
-            att = attend(q, k, qkv[..., nq + nk:])
+            att = attend(*prepare(lp, qkv))
             h = h + jnp.einsum("bsf,ef->bse", att, lp["wo"].astype(dt))
             # the routed layer gates on the gained activations: its
             # gain is applied, not folded (_fold_norms)

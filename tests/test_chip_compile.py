@@ -178,6 +178,34 @@ def test_flash_attention_gq_block_diffusion_compiles(one_chip):
                                    "flash_gq_dkv"}
 
 
+def test_qk_prep_fwd_bwd_compiles(one_chip):
+    """The same cell's q/k norms and rotary positions between the qkv
+    projection and the kernels above: 2 x 8,192 rows of 32 + 4 + 4 heads
+    of d 128, both halves of a row at positions 0..4,095. Each kernel
+    carries the name ``qk_prep_roofline.train`` matches, and no q-shaped
+    float32 array (2 x 8,192 x 4,096 elements: 268 MB) is anywhere in
+    the compiled program: the float32 lives in the kernels' registers."""
+    import re
+
+    from cxxnet_tpu.ops import qk_prep as qp
+    b, S, nh, nkv, d = 2, 8192, 32, 4, 128
+
+    def both(qkv, qnorm, knorm, dq, dk, dv):
+        out, pull = jax.vjp(lambda *a: qp.qk_prep(
+            *a, nh, nkv, rope_theta=1e6, segments=2, interpret=False),
+            qkv, qnorm, knorm)
+        return out, pull((dq, dk, dv))
+
+    wide = lambda heads: ((b, S, heads * d), jnp.bfloat16)
+    text = _compile(both, one_chip, wide(nh + 2 * nkv),
+                    ((d,), jnp.float32), ((d,), jnp.float32),
+                    wide(nh), wide(nkv), wide(nkv))
+    assert _kernel_names(text) == {"qk_prep_fwd", "qk_prep_bwd"}
+    sizes = [int(np.prod([int(n) for n in m.group(1).split(",")]))
+             for m in re.finditer(r"f32\[([\d,]+)\]", text)]
+    assert sizes and max(sizes) < b * S * nkv * d
+
+
 def test_moe_sorted_compiles(one_chip):
     """The same cell's routed layer: 16,384 positions, top 8 of 128
     experts, 16 held, walked in pieces; megablox's kernels under the

@@ -56,6 +56,22 @@ def tiny(tiny_cell):
     return cfg
 
 
+def _on_kernels(cfg):
+    """The tiny configuration with a head of one whole lane tile and
+    ``attn_impl = pallas``: the grouped block then takes its Pallas path
+    (``qk_prep`` and the ``flash_gq`` kernels), here in interpret mode.
+    Nothing else changes, and the reference reads the same sizes."""
+    cfg = json.loads(json.dumps(cfg))
+    cfg["sizes"]["head_dim"] = 128
+    conf = cfg["program"]["conf"]
+    at = conf.index("  head_dim = 16")
+    conf[at:at + 1] = ["  head_dim = 128", "  attn_impl = pallas"]
+    return cfg
+
+
+PATHS = {"plain": lambda cfg: cfg, "kernels": _on_kernels}
+
+
 def _trainer(cfg, dtype="float32", dev="cpu:0"):
     """The tiny configuration's trainer as ``cli.main`` builds it, the
     reference's seeded weights in its tree; -> (trainer, slots). On one
@@ -86,10 +102,13 @@ def _step_key(tr):
     return jax.random.split(jax.random.PRNGKey(tr.seed * 2243 + 7))[0]
 
 
-@pytest.fixture(scope="module")
-def first_step(ref, tiny):
+@pytest.fixture(scope="module", params=list(PATHS))
+def first_step(ref, tiny, request):
     """Program and reference on the first batch: log-probabilities of
-    the noisy half, loss, gradients by leaf."""
+    the noisy half, loss, gradients by leaf; on the block's plain path
+    and on its Pallas path."""
+    tiny = PATHS[request.param](tiny)
+
     def both(dtype):
         tr, slots = _trainer(tiny, dtype)
         tokens, labels = _batches(tiny, 1)[0]
@@ -167,9 +186,10 @@ def test_bfloat16_for_float32_fails_the_tolerances(first_step):
     assert max(grads.values()) > GRAD_TOL
 
 
-@pytest.mark.parametrize("as_the_cell", [False, True])
+@pytest.mark.parametrize("as_the_cell,path", [
+    (False, "plain"), (True, "plain"), (True, "kernels")])
 def test_three_adamw_steps_match_reference(ref, tiny, tiny_cell,
-                                           as_the_cell):
+                                           as_the_cell, path):
     """Weights after three optimizer steps, leaf by leaf. Adam divides by
     the root of the second moment, so a leaf whose gradient is round-off
     moves by round-off's sign: the gap is read against the leaf's
@@ -178,7 +198,7 @@ def test_three_adamw_steps_match_reference(ref, tiny, tiny_cell,
     the router stays to the bit and every position sends this share
     ``topk * held / total`` = 1 pair a layer."""
     from cxxnet_tpu.io import DataBatch
-    tiny = tiny_cell if as_the_cell else tiny
+    tiny = PATHS[path](tiny_cell if as_the_cell else tiny)
     tr, slots = _trainer(tiny, dev="cpu")       # four replicas of a row
     batches = _batches(tiny)
     router0 = np.array(tr.params[slots["router"][0]][slots["router"][1]])
@@ -425,6 +445,41 @@ def _stack(**keys):
                         [(k, str(v)) for k, v in cfg.items()])
     st.infer_shape([(2, 1, 16, 32)])
     return st
+
+
+@pytest.mark.parametrize("head_dim,plans", [(128, 4), (16, 0)])
+def test_grouped_block_says_when_its_qk_prep_kernels_engage(head_dim,
+                                                            plans):
+    """A traced step of the grouped block on its Pallas path: a head of
+    whole lane tiles goes through ``qk_prep`` and each layer's forward
+    and backward call leaves a ``qk_prep.plan`` span with the plan; a
+    head of 16 lanes takes the plain path and leaves none."""
+    from cxxnet_tpu import layers as L
+    from cxxnet_tpu.obs import trace as obs_trace
+    st = L.create_layer("transformer_stack", [(k, str(v)) for k, v in dict(
+        nlayer=2, nhead=4, nkvhead=2, head_dim=head_dim, qk_norm=1,
+        rope_theta=1e6, attn_mask="block_diffusion", attn_impl="pallas",
+        mlp_act="swiglu", nhidden_mlp=32, scan_unroll=2).items()])
+    st.infer_shape([(2, 1, 16, 32)])
+    params = st.init_params(jax.random.PRNGKey(0))
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 1, 16, 32))
+    tr = obs_trace.start()
+    try:
+        jax.grad(lambda p: st.apply(p, [x], L.ApplyContext(train=True))[
+            0].sum())(params)
+        marks = [e["args"] for e in tr.trace_events()
+                 if e.get("name") == "qk_prep.plan"]
+    finally:
+        obs_trace.stop()
+    assert len(marks) == plans
+    assert [m["kernels"] for m in marks] == ["fwd", "fwd", "bwd", "bwd"][
+        :plans]
+    for m in marks:
+        assert {k: m[k] for k in ("rows", "heads", "kv_heads", "d", "norm",
+                                  "rope", "block_rows")} == dict(
+            rows=32, heads=4, kv_heads=2, d=128, norm=True, rope=True,
+            block_rows=8)
+        assert m["vmem_bytes"] > 0
 
 
 @pytest.mark.parametrize("axis,needle", [
