@@ -5,7 +5,6 @@
 #   make            - build the native IO runtime (libcxxnet_native.so)
 #   make wrapper    - C ABI library + demo + native im2bin
 #   make test       - full pytest suite (virtual 8-device CPU mesh)
-#   make bench      - AlexNet images/sec benchmark (one JSON line)
 #   make clean
 
 all: native
@@ -25,10 +24,7 @@ test:
 test-fast:
 	python -m pytest tests/ -q --ignore=tests/test_multihost.py 		--ignore=tests/test_reference_configs.py 		--ignore=tests/test_capi.py
 
-bench:
-	python bench.py
-
 clean:
 	$(MAKE) -C native clean
 
-.PHONY: all native wrapper test test-fast bench clean
+.PHONY: all native wrapper test test-fast clean

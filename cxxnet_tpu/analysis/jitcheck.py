@@ -18,9 +18,8 @@ chatter — and counts compiles per program. Lifecycle:
   thread-local: a replica warming on its build thread never excuses a
   compile on a dispatch thread.
 * :meth:`JitMonitor.arm` declares steady state: from here, any
-  compile outside an ``allow`` region is a **violation** (and
-  ``bench.py serve``/``decode`` and the chaos/scenario smokes fail
-  hard on it).
+  compile outside an ``allow`` region is a **violation** (and the
+  chaos/scenario smokes fail hard on it).
 
 ``obs/registry.py::watch_jitcheck`` exports the counts as
 ``cxxnet_jit_compiles_total`` / ``cxxnet_recompiles_total``.
@@ -231,8 +230,7 @@ class JitMonitor:
             return sum(self.steady.values())
 
     def summary(self, **extra) -> Dict:
-        """The ``recompile_sentinel`` dict the bench ledger and the
-        chaos/scenario smokes record — one shape, built in one place
+        """The ``recompile_sentinel`` dict the chaos smoke records
         (``extra`` carries per-consumer fields)."""
         with self._lock:
             total = sum(self.compiles.values())

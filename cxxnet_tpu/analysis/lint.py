@@ -1791,8 +1791,7 @@ def check_source(source: str, path: str = "<snippet>.py",
 
 def iter_py_files(root: str,
                   subdirs: Sequence[str] = ("cxxnet_tpu", "tools",
-                                            "tests"),
-                  extra_files: Sequence[str] = ("bench.py",)
+                                            "tests")
                   ) -> List[str]:
     """Repo-relative paths of the tree the gate lints. ``tests/`` is
     scanned too (r10): conftest + fixture helpers ship real seams
@@ -1809,9 +1808,6 @@ def iter_py_files(root: str,
                 if fn.endswith(".py"):
                     out.append(os.path.relpath(
                         os.path.join(dirpath, fn), root))
-    for f in extra_files:
-        if os.path.exists(os.path.join(root, f)):
-            out.append(f)
     return sorted(p.replace(os.sep, "/") for p in out)
 
 

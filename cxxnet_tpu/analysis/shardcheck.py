@@ -38,9 +38,9 @@ reshards (counting, not failing — the jitcheck lifecycle).
 
 ``obs/registry.py::watch_shardcheck`` exports the counts as
 ``cxxnet_implicit_transfers_total`` / ``cxxnet_reshards_total`` /
-``cxxnet_shard_programs``; ``bench.py`` train/multichip/serve legs arm
-the sentinel and hard-fail on a nonzero steady state (the
-``_shard_gate`` helper, mirroring ``_jit_gate``).
+``cxxnet_shard_programs``; ``tools/multichip_report.py`` and
+``tools/analysis_gate.py --sharded`` arm the sentinel and fail on a
+nonzero steady state.
 
 Like lockcheck/jitcheck: callables wrapped *before* ``enable()`` stay
 uninstrumented unless they passed ``always=True``; wrappers resolve
@@ -319,8 +319,8 @@ class ShardMonitor:
                 % (len(v), "\n  ".join(map(repr, v))))
 
     def summary(self, **extra) -> Dict:
-        """The ``shard_sentinel`` dict the bench ledger and the
-        multichip report record — one shape, built in one place."""
+        """The ``shard_sentinel`` dict the multichip report
+        records."""
         with self._lock:
             out = {
                 "steady_state_transfers":

@@ -259,11 +259,8 @@ def prefill_attend_impl(st, platform: str, sl: int, e: int) -> str:
     nh = st.nhead
     d = e // nh
     impl = fa.resolve_impl(st.attn_impl, platform, sl)
-    # honor the stack's attn_flat=off escape hatch exactly like
-    # the training dispatch (layers._block_fn) does
-    if impl == "pallas" and getattr(st, "attn_flat", "auto") != "off" \
-            and (fa.supports_flat(sl, nh, d)
-                 or fa.flat_blocked_plan(sl, nh, d)):
+    if impl == "pallas" and (fa.supports_flat(sl, nh, d)
+                             or fa.flat_blocked_plan(sl, nh, d)):
         return "pallas-flat"
     return impl
 

@@ -1962,7 +1962,6 @@ class TransformerStackLayer(Layer):
         self.capacity_factor = 1.25
         self.moe_loss = None        # unset: 0.01 one-hot, 0 sorted
         self.attn_impl = "auto"
-        self.attn_flat = "auto"
         self.scan_unroll = 1
         self.nkvhead = 0
         self.head_dim = 0
@@ -1996,13 +1995,6 @@ class TransformerStackLayer(Layer):
             setattr(self, name, val)
         elif name == "nlayer":
             self.nlayer = int(val)
-        elif name == "attn_flat":
-            # auto: flat kernels whenever the shape supports them;
-            # off: force the generic (b,h,s,d) kernels — the ablation
-            # knob tools/tlab.py's longseq experiment isolates with
-            if val not in ("auto", "off"):
-                raise ValueError("attn_flat must be auto|off")
-            self.attn_flat = val
         elif name == "scan_unroll":
             # unroll factor for the layer scan (straight-line XLA can
             # overlap across block boundaries; costs compile time)
@@ -2287,8 +2279,7 @@ class TransformerStackLayer(Layer):
             d = e // nh
             x = rmsnorm(h, None)          # gain folded into wqkv
             qkv = jnp.einsum("bse,fe->bsf", x, lp["wqkv"].astype(dt))
-            if use_flash and not seq_sharded \
-                    and self.attn_flat != "off":
+            if use_flash and not seq_sharded:
                 from .ops import flash_attention as fa
                 if fa.supports_flat(s, nh, d) \
                         or fa.flat_blocked_plan(s, nh, d):

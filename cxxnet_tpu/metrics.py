@@ -16,8 +16,8 @@ Two execution paths with identical math and the identical
 serving telemetry (serve/stats.py) shares this module's statistics
 conventions rather than growing its own. ``StallClock`` (per-stage
 wait/busy wall-time ledger) is the feed-pipeline counterpart: the
-overlapped input pipeline (io/prefetch.py), the train loop, and
-``bench.py feed`` all account stall time through it.
+overlapped input pipeline (io/prefetch.py) and the train loop both
+account stall time through it.
 """
 
 from __future__ import annotations

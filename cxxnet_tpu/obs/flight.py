@@ -72,8 +72,7 @@ class FlightRecorder:
         self._wall0 = time.time()
         # allocation counter instead of `recorded += 1`: a plain
         # read-modify-write from every instrumented thread loses
-        # increments, and this total is published (bench ledger, dump
-        # headers). next() hands out exact dense values; the attribute
+        # increments, and this total is published (dump headers). next() hands out exact dense values; the attribute
         # snapshot can lag an in-flight append by at most #threads
         self._rec_count = itertools.count(1)
         self.recorded = 0          # events ever appended (evicted incl.)

@@ -96,8 +96,6 @@ def _pick_block(s: int, target: int = None) -> int:
     bigger blocks amortize the k-loop and keep the MXU busier, while
     2048-wide blocks blow the VMEM budget and fail to compile."""
     if target is None:
-        # resolved at call time so experiments / future knobs can
-        # retarget without re-importing (tools/tlab.py block sweep)
         target = DEFAULT_BLOCK_TARGET
     b = (min(s, target) // 128) * 128
     while b >= 128:
@@ -114,7 +112,7 @@ def analytic_flops(b, h, s, d, causal):
     XLA's HLO cost model cannot see inside a pallas_call (it lowers to
     an opaque custom_call), so every net using this kernel under-reports
     ``lowered.cost_analysis()['flops']`` — these analytic counts are
-    what bench.py/perf_lab add back (VERDICT r3 #2).
+    what ``Trainer.step_cost_analysis`` adds back (VERDICT r3 #2).
 
     fwd = 2 MXU matmuls per (q, k) block pair (QK^T and PV) = 4*b*h*s²*d.
     bwd at a single block (s <= 512-class, _pick_block(s) == s): the
@@ -809,26 +807,6 @@ def flat_blocked_plan(s: int, h: int, d: int,
         return None                  # single-block: the fused path
     if LANES % d and d % LANES:
         return None                  # a head would straddle a window
-    import os
-    ov = os.environ.get("CXXNET_FLATB_PLAN")
-    if ov:
-        # experiment override "g,block[,sub | ,sub_fwd,sub_dq,sub_dkv]"
-        # — checked BEFORE the length gate (its whole point is probing
-        # past the crossover), and validated: an un-checked g would
-        # silently skip heads (hg = h // g truncates) and a
-        # non-dividing block only fails with a cryptic Mosaic grid
-        # error
-        g, block, *subs = (int(x) for x in ov.split(","))
-        subs = tuple(min(x, block) for x in (
-            subs * 3 if len(subs) == 1 else subs or FLATB_SUBS))
-        if h % g or (g * d) % 128 or s % block or len(subs) != 3 \
-                or any(block % x or x % LANES for x in subs):
-            raise ValueError(
-                "CXXNET_FLATB_PLAN=%s invalid for s=%d h=%d d=%d: "
-                "need h %% g == 0, (g*d) %% 128 == 0, s %% block == 0, "
-                "one or three subs with block %% sub == 0 and "
-                "sub %% 128 == 0" % (ov, s, h, d))
-        return (g, block) + subs
     if s > 3072:
         return None
     for block in FLATB_BLOCKS:

@@ -72,7 +72,7 @@ def place_compile_cache() -> str:
     not, the cache goes to ``<checkout>/.jax-cache``, resolved from this
     package's own path — the path is part of the cache's key, so it
     must never hold a temporary name, a pid or a time. Called by every
-    entry point that compiles for a device (``cli.main``, ``bench.py``,
+    entry point that compiles for a device (``cli.main``,
     ``chip_smoke.py``); the test suite keeps the cache off
     (tests/conftest.py records why)."""
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")

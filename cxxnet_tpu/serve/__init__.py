@@ -28,8 +28,7 @@ that artifact into a trafficable service —
 * :mod:`.loadgen` — open-loop trace replay: the scenario catalog
   (bursty / mixed-priority / mixed predict+generate / slow-client /
   mixed-prompt-length), a replayable JSONL trace format the access log
-  can produce, and the scoring behind ``bench.py scenario``
-  (docs/scenarios.md);
+  can produce, and the scoring of a replay (docs/scenarios.md);
 * :mod:`.continuous` — :class:`ContinuousDecodeEngine`: iteration-
   level continuous batching over a split-phase ``export_decode_step``
   artifact — paged KV pool (:mod:`.kvpool`), prefill/decode phase

@@ -84,7 +84,7 @@ reports ``ttft_p50/p99_ms``, ``tpot_p50_ms``, ``tokens_out`` and
 
 Replay (:class:`LoadGen`) schedules arrivals on one pacer thread and
 hands each request to a worker pool; ``score()`` turns the outcomes
-into the ledger row fields — p50/p99 latency, SLO attainment
+into the scored fields — p50/p99 latency, SLO attainment
 (answered requests inside ``slo_ms``), shed/timeout/error counts, and
 the max pacer lag (a nonzero lag means the generator itself fell
 behind and the numbers understate the burst).

@@ -1097,7 +1097,7 @@ def export_decode_step(trainer, path: str, max_new: int = 32,
                 "scale_dtype": "float32" if kvd == "int8" else None,
                 # bytes ONE slot's attend streams per decoded token
                 # (K + V pages, plus the scale planes on int8) — the
-                # per-rung traffic the bench ledger attributes
+                # per-rung traffic (``ExportedStepDecoder.rung()``)
                 "kv_bytes_per_step": 2 * Ltot * nh * Sp * (d * isz
                                                            + ssz),
                 # bytes one sequence's pages occupy in the pool — the

@@ -146,7 +146,8 @@ class Trainer:
         # activations — right for a feed that stages every batch fresh
         # (the CLI's device-prefetch loop turns it on). 0 (default):
         # inputs stay live after dispatch, so a staged batch may be
-        # dispatched repeatedly (bench.py cycles a fixed staged set)
+        # dispatched repeatedly (tools/perf_lab.py and the tests cycle
+        # a fixed staged set)
         self.donate_inputs = 0
         self.eval_train = 1
         self.seed = 0
@@ -762,8 +763,8 @@ class Trainer:
             xsh_s = parallel.stacked_sharding(xsh)
             dsh_s = parallel.stacked_sharding(dsh)
             # data args are NOT donated by default: a group staged once
-            # may legally be dispatched again (bench cycles a fixed
-            # staged set); donate_inputs=1 (the single-dispatch
+            # may legally be dispatched again (callers that cycle a
+            # fixed staged set); donate_inputs=1 (the single-dispatch
             # device-prefetch feed) hands the group's HBM to XLA
             in_multi = (psh, osh, rep, rep, rep, xsh_s, dsh_s, dsh_s)
             self._train_multi = _shardcheck.make_sharded(

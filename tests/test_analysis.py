@@ -860,7 +860,7 @@ def test_tree_gate_is_clean():
 
 def test_gate_json_summary_shape():
     """--json machine output: files scanned, per-rule and per-family
-    counts — the fields the net=analysis ledger row records."""
+    counts."""
     from analysis_gate import gate_summary
     findings_all, unwaived, stale, waivers, files = run_gate(REPO)
     s = gate_summary(findings_all, unwaived, stale, waivers, files)
@@ -873,22 +873,6 @@ def test_gate_json_summary_shape():
                                   "OBS", "PARSE"}
     assert "SHARD" in s["families"]       # the r13 family is counted
     assert sum(s["families"].values()) == s["findings"]
-
-
-def test_ledger_carries_analysis_row():
-    """tools/analysis_gate.py --ledger records the gate surface as a
-    net=analysis row; the committed ledger must carry one so BENCH
-    history tracks checker-surface growth."""
-    import json
-    with open(os.path.join(REPO, "docs", "bench_history.json")) as f:
-        row = json.load(f)["best_by_net"]["analysis"]
-    assert row["files_scanned"] >= 100
-    assert row["waivers"] >= 1 and not row["stale_waivers"]
-    assert sum(row["rules"].values()) == row["findings"]
-    assert "JIT" in row["families"]
-    # the committed row carries the SHARD family's counts (r13): the
-    # ledger pins that the gate surface grew with the new checker
-    assert "SHARD" in row["families"]
 
 
 # ----------------------------------------------------------------------

@@ -40,7 +40,7 @@ from cxxnet_tpu.serve.kvpool import BlockPool
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-from tools.goodput_report import load_history, taxonomy_sum  # noqa: E402
+from tools.goodput_report import load_json, taxonomy_sum  # noqa: E402
 from tools.trace_report import phase_report, span_phase  # noqa: E402
 
 
@@ -438,74 +438,46 @@ def test_phase_report_fractions():
 # goodput_report (satellite CLI)
 
 
-def _fake_history(tmp_path, goodput=0.8):
+def _fake_summary(tmp_path, goodput=0.8):
+    """A saved ``/debug/attrib`` body (what ``--json`` reads)."""
     waste = {"pad_fill": 1.0 - goodput, "dummy_lane": 0.0,
              "overshoot": 0.0, "retry_duplicate": 0.0}
-    doc = {"runs": [
-        {"net": "serve", "timestamp": "2026-08-06T00:00:00Z",
-         "attrib": {"events": 10, "slot_tokens": 100,
-                    "goodput_tokens": int(100 * goodput),
-                    "goodput_frac": goodput, "waste_frac": waste,
-                    "per_phase": {}, "top_waste": []}},
-        {"net": "obs", "timestamp": "2026-08-06T00:01:00Z"},
-    ]}
-    p = tmp_path / "hist.json"
+    doc = {"events": 10, "slot_tokens": 100,
+           "goodput_tokens": int(100 * goodput),
+           "goodput_frac": goodput, "waste_frac": waste,
+           "per_phase": {}, "top_waste": []}
+    p = tmp_path / "attrib.json"
     p.write_text(json.dumps(doc))
     return str(p)
 
 
 def test_goodput_report_reads_newest_attrib_run(tmp_path):
-    path = _fake_history(tmp_path)
-    s, src, prof = load_history(path)
-    assert s["goodput_frac"] == 0.8 and "net=serve" in src
-    assert prof is None  # fixture run carries no profile stanza
+    path = _fake_summary(tmp_path)
+    s, src = load_json(path)
+    assert s["goodput_frac"] == 0.8 and src == path
     assert abs(taxonomy_sum(s) - 1.0) < 1e-9
+    # a body that is not an attribution summary is refused by name
+    other = tmp_path / "other.json"
+    other.write_text(json.dumps({"programs": []}))
+    with pytest.raises(SystemExit, match="no goodput_frac"):
+        load_json(str(other))
 
 
 def test_goodput_report_gate_exit_codes(tmp_path):
-    path = _fake_history(tmp_path, goodput=0.6)
+    path = _fake_summary(tmp_path, goodput=0.6)
     script = os.path.join(REPO, "tools", "goodput_report.py")
     ok = subprocess.run(
-        [sys.executable, script, "--history", path,
+        [sys.executable, script, "--json", path,
          "--assert-goodput-frac", "0.5", "--assert-taxonomy"],
         capture_output=True, text=True)
     assert ok.returncode == 0, ok.stderr
     assert "goodput" in ok.stdout
     bad = subprocess.run(
-        [sys.executable, script, "--history", path,
+        [sys.executable, script, "--json", path,
          "--assert-goodput-frac", "0.9"],
         capture_output=True, text=True)
     assert bad.returncode == 2
     assert "below the" in bad.stderr
-
-
-# ----------------------------------------------------------------------
-# the committed bench ledger stanza (acceptance pin)
-
-
-def test_bench_history_attrib_stanza_partition():
-    """The committed bench ledger's serve/decode rows carry the
-    attribution stanza and its taxonomy partitions to 1.0 — the
-    acceptance pin tying bench.py, the ledger, and goodput_report
-    to the same numbers."""
-    path = os.path.join(REPO, "docs", "bench_history.json")
-    with open(path) as f:
-        runs = json.load(f)["runs"]
-    with_attrib = [r for r in runs
-                   if isinstance(r.get("attrib"), dict)]
-    assert with_attrib, \
-        "no bench run carries an attrib stanza — run bench.py serve"
-    nets = {r["net"] for r in with_attrib}
-    assert "serve" in nets, nets
-    for run in with_attrib:
-        s = run["attrib"]
-        assert s["events"] > 0 and s["slot_tokens"] > 0, run["net"]
-        assert 0.0 < s["goodput_frac"] <= 1.0, run["net"]
-        assert abs(taxonomy_sum(s) - 1.0) < 1e-9, \
-            "net=%s taxonomy sums to %r" % (run["net"],
-                                            taxonomy_sum(s))
-        for k in WASTE_KINDS:
-            assert k in s["waste_frac"], (run["net"], k)
 
 
 # ----------------------------------------------------------------------

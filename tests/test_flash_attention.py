@@ -430,18 +430,25 @@ def test_stack_flat_blocked_matches_generic_trajectory(monkeypatch):
     from cxxnet_tpu.io import DataBatch
     from cxxnet_tpu.trainer import Trainer
 
-    monkeypatch.setattr(fa, "flat_blocked_plan",
-                        lambda s, h, d, budget=0:
-                        (2, 128, 128, 128, 128) if s == 256 else None)
     monkeypatch.setattr(fa, "supports_flat", lambda *a, **k: 0)
+    flat_calls = []
+    real_flat = fa.flash_attention_flat
+    monkeypatch.setattr(
+        fa, "flash_attention_flat",
+        lambda *a, **k: flat_calls.append(1) or real_flat(*a, **k))
 
-    def build(flat):
+    def train(plan):
+        """Two steps with ``flat_blocked_plan`` answering ``plan``
+        (None: both flat predicates refuse, so the stack takes the
+        generic (b, h, s, d) kernels); the trained wqkv."""
+        monkeypatch.setattr(fa, "flat_blocked_plan",
+                            lambda s, h, d, budget=0:
+                            plan if s == 256 else None)
         tr = Trainer()
         text = models.tiny_lm(seq_len=256, vocab=32, embed=128,
                               nlayer=1, nhead=2)
         text = text.replace("causal = 1",
-                            "causal = 1\n  attn_impl = pallas"
-                            + ("" if flat else "\n  attn_flat = off"))
+                            "causal = 1\n  attn_impl = pallas")
         for k, v in config.parse_string(text):
             tr.set_param(k, v)
         for k, v in (("dev", "cpu:0"), ("batch_size", "4"),
@@ -449,7 +456,9 @@ def test_stack_flat_blocked_matches_generic_trajectory(monkeypatch):
                      ("metric", "token_error")):
             tr.set_param(k, v)
         tr.init_model()
-        return tr
+        for _ in range(2):
+            tr.update(b)
+        return tr.get_weight("ts1", "wqkv")
 
     rs = np.random.RandomState(0)
     seq = (rs.randint(0, 32, size=(4, 1)) + np.arange(257)) % 32
@@ -457,10 +466,9 @@ def test_stack_flat_blocked_matches_generic_trajectory(monkeypatch):
         data=seq[:, :256, None, None].transpose(0, 2, 1, 3)
         .astype(np.float32).reshape(4, 1, 256, 1),
         label=seq[:, 1:].astype(np.float32))
-    t_flat, t_gen = build(True), build(False)
-    for _ in range(2):
-        t_flat.update(b)
-        t_gen.update(b)
-    np.testing.assert_allclose(
-        t_flat.get_weight("ts1", "wqkv"),
-        t_gen.get_weight("ts1", "wqkv"), rtol=2e-4, atol=2e-6)
+    w_flat = train((2, 128, 128, 128, 128))
+    assert flat_calls
+    del flat_calls[:]
+    w_gen = train(None)
+    assert not flat_calls
+    np.testing.assert_allclose(w_flat, w_gen, rtol=2e-4, atol=2e-6)

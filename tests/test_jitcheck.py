@@ -403,8 +403,8 @@ def test_continuous_engine_steady_state_compile_free(step_path):
 def test_decode_rung_gate_all_rungs_compile_free(step_path):
     """tools/analysis_gate.check_decode_rungs — the CI-facing form of
     the contract above, per RUNG: every exported kv_dtype rung serves
-    steady-state compile-free behind its own armed sentinel (the
-    --ledger row asserts this across the whole rung space)."""
+    steady-state compile-free behind its own armed sentinel (what
+    ``--rungs`` asserts across the whole rung space)."""
     import os
     import sys
     sys.path.insert(0, os.path.join(

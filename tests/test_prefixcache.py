@@ -441,30 +441,6 @@ def test_engine_failed_step_resets_trie_without_leaking(plm):
 
 
 # ----------------------------------------------------------------------
-# committed ledger pin: the bench prefix leg's acceptance numbers
-
-def test_ledger_carries_prefix_leg():
-    import json
-    import os
-    ledger = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "docs", "bench_history.json")
-    with open(ledger) as f:
-        runs = json.load(f)["runs"]
-    rows = [r for r in runs
-            if r.get("net") == "decode_serve" and r.get("prefix")]
-    assert rows, "no decode_serve run carries a prefix stanza"
-    p = rows[-1]["prefix"]
-    assert p["hit_rate"] >= 0.5                 # >= 50% template share
-    assert p["full_prefill_dispatch_ratio"] >= 1.3
-    assert p["prefill_compute_ratio"] > 1.0
-    assert p["ttft_p99_speedup"] > 1.0
-    assert p["ttft_p50_speedup"] > 1.0
-    for w in (p["prefix_on"], p["prefix_off"]):
-        assert w["pool_page_leaks"] == 0
-        assert w["timeouts"] == 0 and w["ok"] == w["requests"]
-
-
-# ----------------------------------------------------------------------
 # smoke (the tier-1 wiring, scenario_smoke pattern)
 
 def test_prefix_smoke_inprocess():

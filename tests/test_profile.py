@@ -16,8 +16,8 @@ Pins the contracts docs/observability.md states:
   exact keys serving.profile_cost_table registers;
 * ``REQUEST_PHASES`` is one vocabulary across obs/profile.py,
   serve/continuous.py timing() and tools/trace_report.py --phases;
-* tools/perf_report.py validates the committed bench ledger and its
-  regression gate exits 2 on a synthetically slowed replay.
+* tools/perf_report.py renders a live profiler's summary: the
+  per-program rows, the costed share and the uncosted list.
 """
 
 import json
@@ -38,12 +38,10 @@ from cxxnet_tpu.serving import profile_cost_table
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-from tools.perf_report import (  # noqa: E402
-    check_regression, load_history, validate_history)
+from tools.perf_report import human, load_json  # noqa: E402
 from tools.trace_report import (  # noqa: E402
     REQUEST_PHASES as TRACE_REQUEST_PHASES)
 
-HISTORY = os.path.join(REPO, "docs", "bench_history.json")
 PERF = os.path.join(REPO, "tools", "perf_report.py")
 
 
@@ -515,147 +513,62 @@ def test_profile_module_passes_its_own_gate():
 
 
 # ----------------------------------------------------------------------
-# perf_report: history validation + the regression gate (satellites)
+# perf_report: a live snapshot rendered
 
 
-def test_validate_history_on_committed_ledger():
-    """The committed bench ledger passes its own schema gate — the
-    tier-1 pin the --validate-history satellite asks for."""
-    problems = validate_history(HISTORY)
-    assert problems == [], problems
-
-
-def _perf_history(tmp_path, slow=False):
-    """Two serve runs with profile stanzas; ``slow=True`` replays the
-    newest run synthetically slowed (headline / 5, p50 x 10, program
-    medians x 15) past every gate threshold."""
-    def prog(med):
-        return [{"program": "engine forward/fixed b16 w1",
-                 "site": "engine", "phase": "forward", "rung": "fixed",
-                 "bucket": 16, "width": 1, "shard": -1, "events": 20,
-                 "wall_ms_total": med * 20, "wall_ms_median": med,
-                 "wall_ms_mean": med, "costed": True,
-                 "flops_per_event": 1.0e6, "flops_per_sec": 1.0e9,
-                 "mfu": 0.5, "bytes_per_event": None,
-                 "bytes_per_sec": None}]
-
-    def run(ts, commit, rps, p50, med):
-        return {"net": "serve", "timestamp": ts, "commit": commit,
-                "rows_per_sec": rps, "p50_1row_ms_bucketed": p50,
-                "pipelined_vs_serial": 1.2,
-                "profile": {"events": 20, "per_phase": {},
-                            "programs": prog(med)}}
-
-    base = run("2026-08-06T00:00:00Z", "aaa", 1000.0, 0.5, 1.0)
-    if slow:
-        cur = run("2026-08-06T01:00:00Z", "bbb", 200.0, 5.0, 15.0)
-    else:
-        cur = run("2026-08-06T01:00:00Z", "bbb", 990.0, 0.52, 1.1)
-    doc = {"runs": [base, cur],
-           "best_by_net": {"serve": base}, "best": base}
-    p = tmp_path / "hist.json"
-    p.write_text(json.dumps(doc))
-    return str(p)
-
-
-def test_regression_gate_clean_and_breached(tmp_path):
-    clean = _perf_history(tmp_path)
-    assert check_regression(clean, "serve") == []
-    slow = _perf_history(tmp_path, slow=True)
-    breaches = check_regression(slow, "serve")
-    text = "\n".join(breaches)
-    # all three thresholds fire: headline floor, latency ceiling,
-    # per-program median ceiling
-    assert "rows_per_sec" in text
-    assert "p50_1row_ms_bucketed" in text
-    assert "engine forward/fixed b16 w1" in text
-
-
-def test_regression_gate_exit_codes(tmp_path):
-    ok = subprocess.run(
-        [sys.executable, PERF, "--history", _perf_history(tmp_path),
-         "--assert-no-regression", "--net", "serve"],
-        capture_output=True, text=True)
-    assert ok.returncode == 0, ok.stderr
-    assert "within regression thresholds" in ok.stdout
-    bad = subprocess.run(
-        [sys.executable, PERF,
-         "--history", _perf_history(tmp_path, slow=True),
-         "--assert-no-regression", "--net", "serve"],
-        capture_output=True, text=True)
-    assert bad.returncode == 2
-    assert "REGRESSION" in bad.stderr
-
-
-def test_regression_gate_on_committed_ledger():
-    """The newest committed serve/decode runs pass their own gate —
-    what bench.py enforces after every recording."""
-    for net in ("serve", "decode_serve"):
-        r = subprocess.run(
-            [sys.executable, PERF, "--assert-no-regression",
-             "--net", net], capture_output=True, text=True)
-        assert r.returncode == 0, (net, r.stdout, r.stderr)
-
-
-def test_validate_history_exit_code_on_malformed(tmp_path):
-    doc = {"runs": [
-        {"net": "serve", "timestamp": "2026-08-06T00:00:00Z",
-         "commit": "aaa"},                       # missing serve keys
-        {"timestamp": "2026-08-06T00:01:00Z"},   # missing net+commit
-        {"net": "obs", "timestamp": "2026-08-06T00:02:00Z",
-         "commit": "ccc", "requests_total": 1, "source": "serve",
-         "profile": {"nope": 1}},                # broken profile stanza
-    ], "best_by_net": {}}
-    p = tmp_path / "bad.json"
-    p.write_text(json.dumps(doc))
-    problems = validate_history(str(p))
-    text = "\n".join(problems)
-    assert "missing required stanza key" in text
-    assert "missing 'net'" in text
-    assert "profile stanza must carry events" in text
-    r = subprocess.run(
-        [sys.executable, PERF, "--history", str(p),
-         "--validate-history"], capture_output=True, text=True)
-    assert r.returncode == 2 and "perf_report:" in r.stderr
-    good = subprocess.run(
-        [sys.executable, PERF, "--validate-history"],
-        capture_output=True, text=True)
-    assert good.returncode == 0, good.stderr
-
-
-# ----------------------------------------------------------------------
-# the committed bench ledger stanza (acceptance pin)
-
-
-def test_bench_history_profile_stanza():
-    """The committed serve/decode bench runs carry the profile stanza
-    with at least 3 distinct program shapes, wall-ms medians, and a
-    costed MFU — the acceptance pin tying bench.py, the profiler, and
-    perf_report to the same numbers."""
-    with open(HISTORY) as f:
-        runs = json.load(f)["runs"]
-    with_prof = [r for r in runs if isinstance(r.get("profile"), dict)]
-    assert with_prof, \
-        "no bench run carries a profile stanza — run bench.py serve"
-    nets = {r["net"] for r in with_prof}
-    assert "serve" in nets, nets
-    for run in with_prof:
-        s = run["profile"]
-        assert s["events"] > 0, run["net"]
-        progs = s["programs"]
-        # the serve/decode legs exercise >= 3 distinct program shapes
-        # (bucket ladder / rung family); other nets may be single-shape
-        floor = 3 if run["net"] in ("serve", "decode_serve") else 1
-        assert len(progs) >= floor, \
-            "net=%s recorded only %d program shapes" \
-            % (run["net"], len(progs))
-        for d in progs:
-            assert d["wall_ms_median"] > 0.0, (run["net"], d)
-        costed = [d for d in progs if d.get("mfu") is not None]
-        assert costed, "net=%s has no costed program" % run["net"]
-        for d in costed:
-            assert d["mfu"] > 0.0, (run["net"], d)
-        assert s.get("peak_flops"), run["net"]
-    # perf_report renders the committed stanza end to end
-    s, src = load_history(HISTORY)
-    assert s["events"] > 0 and "net=" in src
+def test_perf_report_renders_a_live_snapshot(tmp_path, no_profile):
+    """A live profiler's summary, saved as a ``/debug/profile`` body,
+    through perf_report: three program shapes with their window
+    medians, the costed ones' MFU against the pinned peak, and the
+    uncosted one listed, never dropped."""
+    profile.set_peak(1.0e9)
+    prof = ProgramProfiler(capacity=64)
+    prof.register_costs({("engine", "forward", "fixed", 8, 1):
+                         (2.0e6, 4.0e5),
+                         ("engine", "forward", "fixed", 16, 1):
+                         (4.0e6, 8.0e5)})
+    for _ in range(4):
+        prof.record("engine", "forward", "fixed", 8, 1, -1, 2.0)
+    for _ in range(2):
+        prof.record("engine", "forward", "fixed", 16, 1, -1, 8.0)
+    prof.record("decoder", "prefill", "any", 8, 8, -1, 1.0)
+    path = tmp_path / "profile.json"
+    path.write_text(json.dumps(prof.summary()))
+    s, src = load_json(str(path))
+    assert src == str(path) and s["events"] == 7
+    progs = {d["program"]: d for d in s["programs"]}
+    assert len(progs) == 3
+    assert all(d["wall_ms_median"] > 0.0 for d in progs.values())
+    costed = {k: d for k, d in progs.items() if d["mfu"] is not None}
+    assert set(costed) == {"engine forward/fixed b8 w1",
+                           "engine forward/fixed b16 w1"}
+    assert s["peak_flops"] == 1.0e9
+    text = human(s, src)
+    lines = text.splitlines()
+    table = lines[lines.index("programs (window, by summed wall):") + 2:]
+    rows = {ln[2:38].strip(): ln.split() for ln in table[:3]}
+    # 2e6 flops in 2 ms = the pinned peak; 4e6 in 8 ms = half of it
+    assert rows["engine forward/fixed b8 w1"][-4:] \
+        == ["4", "2.000", "1.00G", "1.0000"]
+    assert rows["engine forward/fixed b16 w1"][-4:] \
+        == ["2", "8.000", "500.00M", "0.5000"]
+    assert rows["decoder prefill/any b8 w8"][-4:] \
+        == ["1", "1.000", "-", "-"]
+    assert "  peak 1.00GFLOP/s (published), overall MFU" in text
+    # the worst costed shape leads the bottom list; the uncosted one
+    # is named at the end
+    assert table[3] == "bottom MFU shapes (the autoscaling unit):"
+    assert table[4].split()[:5] \
+        == ["engine", "forward/fixed", "b16", "w1", "mfu"]
+    assert lines[-1] == "  decoder prefill/any b8 w8"
+    # the CLI prints the same rendering, and one JSON line on request
+    r = subprocess.run([sys.executable, PERF, "--json", str(path)],
+                       capture_output=True, text=True)
+    assert r.returncode == 0 and r.stdout.rstrip("\n") == text, r.stderr
+    r = subprocess.run([sys.executable, PERF, "--json", str(path),
+                        "--json-out"], capture_output=True, text=True)
+    assert r.returncode == 0 and json.loads(r.stdout) == s, r.stderr
+    # neither source named: argparse refuses, nothing is read
+    r = subprocess.run([sys.executable, PERF],
+                       capture_output=True, text=True)
+    assert r.returncode == 2 and "--url" in r.stderr

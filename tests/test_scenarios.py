@@ -1,4 +1,4 @@
-"""Trace-replay scenario bench (serve/loadgen.py, bench.py scenario,
+"""Trace-replay scenarios (serve/loadgen.py,
 tools/scenario_smoke.py):
 
 * the JSONL trace format roundtrips and the access log converts into
@@ -9,9 +9,8 @@ tools/scenario_smoke.py):
 * open-loop replay against a real engine answers everything and
   scores p99/SLO-attainment;
 * the full scenario smoke (live HTTP server, forced incident, flight
-  dump, committed ledger baseline) runs green in-process — the
-  analysis-gate pattern for CI tools;
-* the committed bench ledger carries the net=scenario baseline row.
+  dump) runs green in-process — the analysis-gate pattern for CI
+  tools.
 """
 
 import json
@@ -28,8 +27,6 @@ from cxxnet_tpu.serve.loadgen import (SCENARIOS, EngineTarget,
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 # ----------------------------------------------------------------------
@@ -245,78 +242,13 @@ def test_loadgen_timeouts_surface_as_timeouts(tiny_engine):
 
 
 # ----------------------------------------------------------------------
-# the smoke + the committed baseline
+# the smoke
 
 
 def test_scenario_smoke_inprocess():
     """The whole workload -> objective -> evidence loop against a live
     HTTP server (tools/scenario_smoke.py), in-process like the
     analysis gate: bursty replay, forced burn-rate incident, verified
-    flight dump, /slo + /healthz surfaces, ledger baseline."""
+    flight dump, /slo + /healthz surfaces."""
     from tools import scenario_smoke
     assert scenario_smoke.run(duration_s=1.2, rps=50.0) == 0
-
-
-def test_committed_ledger_has_scenario_baseline():
-    with open(os.path.join(REPO, "docs", "bench_history.json")) as f:
-        hist = json.load(f)
-    row = hist["best_by_net"]["scenario"]
-    for name in ("bursty", "mixed_priority", "mixed_kinds",
-                 "slow_client", "mixed_prompt_len"):
-        s = row["scenarios"][name]
-        assert s["p99_ms"] is not None
-        assert 0.0 <= s["slo_attainment"] <= 1.0
-        assert s["requests"] > 0
-        # the capacity frontier: attainment vs offered load, recorded
-        # past the steady point (the r10 sweep satellite)
-        fr = s["frontier"]
-        assert len(fr) >= 2
-        assert fr[-1]["offered_rps"] > row["offered_rps"]
-        assert all(0.0 <= f["slo_attainment"] <= 1.0 for f in fr)
-    # streaming scenarios carry honest first-token numbers
-    s = row["scenarios"]["mixed_prompt_len"]
-    assert s["ttft_p99_ms"] is not None and s["tok_per_sec"] > 0
-
-
-def test_committed_ledger_has_decode_serve_baseline():
-    """The net=decode_serve row: the paged continuous path beats the
-    fixed-shape decoder on the mixed-prompt-length trace in BOTH
-    sustained goodput tokens/s and p99 TTFT (the r10 acceptance), with
-    the capacity frontier recorded for both paths; since r12 the row
-    also attributes each path's attend kernel + KV bytes and pins the
-    fused-paged and int8-rung acceptances."""
-    with open(os.path.join(REPO, "docs", "bench_history.json")) as f:
-        hist = json.load(f)
-    row = hist["best_by_net"]["decode_serve"]
-    assert row["tok_per_sec_speedup"] > 1.0
-    assert row["ttft_p99_speedup"] > 1.0
-    assert row["tok_per_sec"] > row["tok_per_sec_fixed"] > 0
-    assert row["ttft_p99_ms"] < row["ttft_p99_ms_fixed"]
-    for path in ("fixed", "paged_fused"):
-        fr = row["frontier"][path]
-        assert len(fr) >= 3
-        assert all(f["tok_per_sec"] > 0 for f in fr)
-    # frontier entries are kernel-attributed since r12 (the frontier
-    # ran the FUSED engine even in the r10-named rows; the key and
-    # annotation make that explicit)
-    assert all(f["attend_kernel"] == "fused-paged"
-               for f in row["frontier"]["paged_fused"])
-    # r12: the fused-paged kernel beats the gather-paged baseline on
-    # the committed run (>= 1.15x was the acceptance bar; the pin
-    # guards against silently recording a regressed window)
-    assert row["fused_vs_gather_speedup"] > 1.0
-    assert row["attend_kernels"]["paged_fused"] == "fused-paged"
-    assert row["attend_kernels"]["paged"] == "gather-xla"
-    assert row["attend_kernels"]["paged_fused_q8"] == "fused-paged-q8"
-    # rung attribution: the int8 rung moves fewer KV bytes per step...
-    kb = row["kv_bytes_per_step"]
-    assert kb["paged_fused_q8"] < kb["paged_fused"]
-    # ...and fits >= 1.9x the KV state of native in the same pool
-    # bytes, demonstrated live with 2x the sequences resident
-    assert row["int8_pool"]["kv_state_per_byte_ratio"] >= 1.9
-    assert row["int8_pool"]["seqs_vs_native_ratio"] >= 1.9
-    assert row["int8_pool"]["int8_pool_bytes"] \
-        < row["int8_pool"]["native_pool_bytes"]
-    # the committed run served traffic through every rung
-    assert row["tok_per_sec_q8"] and row["tok_per_sec_q8"] > 0
-    assert row["recompile_sentinel"]["steady_state_compiles"] == 0

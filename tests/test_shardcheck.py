@@ -296,7 +296,7 @@ def mesh_trainer():
 
 
 def test_armed_mesh_train_leg_is_clean(mesh_trainer):
-    """The MULTICHIP train-leg contract (bench.py scaling_main): an
+    """The MULTICHIP train-leg contract: an
     armed dp mesh trainer runs steady-state steps with ZERO implicit
     transfers and ZERO reshards — explicit staging + declared
     placements carried through the step outputs."""

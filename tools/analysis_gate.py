@@ -1,9 +1,8 @@
 """Run the analysis lint checkers (CONC/SYNC/JIT/SHARD/OBS) over the
 tree against the committed waiver baseline — the standing CI gate
-(docs/analysis.md). ``--json``/``--ledger`` report per-rule AND
-per-family counts, so the net=analysis ledger row tracks each
-family's surface (the SHARD family landed in r13 alongside the
-runtime shardcheck sentinel).
+(docs/analysis.md). ``--json`` reports per-rule AND per-family
+counts (the SHARD family landed in r13 alongside the runtime
+shardcheck sentinel).
 
 Usage:
   python tools/analysis_gate.py                # gate: exit 1 if dirty
@@ -35,20 +34,6 @@ Usage:
                                                # 0 reshards; sharded
                                                # program count
                                                # recorded)
-  python tools/analysis_gate.py --ledger       # also record the gate
-                                               # surface as a
-                                               # net=analysis row in
-                                               # docs/bench_history
-                                               # .json (rule counts,
-                                               # waivers, files, the
-                                               # rung gate AND the
-                                               # sharded-serving gate
-                                               # with its sharded
-                                               # program count —
-                                               # --ledger implies
-                                               # --rungs + --sharded)
-                                               # so BENCH history
-                                               # tracks its growth
 
 The baseline lives at ``docs/analysis_waivers.txt``; one waiver per
 line::
@@ -129,8 +114,7 @@ def run_gate(root=None, waiver_path=None, extra_hot=()):
 
 
 def gate_summary(findings, unwaived, stale, waivers, files):
-    """The machine-readable gate surface: what --json prints and what
-    the net=analysis ledger row records."""
+    """The machine-readable gate surface: what --json prints."""
     rules = {}
     for f in findings:
         rules[f.rule] = rules.get(f.rule, 0) + 1
@@ -192,7 +176,7 @@ def check_decode_rungs(step_path=None, traffic_rows=(1, 2)):
     rows-bucket: a combo the engine warmup misses is a guaranteed
     scheduler-thread compile under load). With no ``step_path`` a
     tiny two-rung artifact is built in a tempdir. Returns the
-    summary dict the --ledger row records; ``ok`` is the gate bit."""
+    summary dict --json carries; ``ok`` is the gate bit."""
     import tempfile
 
     import numpy as np
@@ -251,9 +235,8 @@ def check_sharded_serving(devices: int = 4):
     tiny forward on a ``devices``-way data mesh, serve it through a
     warmed ServingEngine with BOTH sentinels armed, and demand zero
     steady-state compiles, zero implicit host transfers, and zero
-    implicit reshards — plus the SHARDED PROGRAM COUNT the --ledger
-    row carries, so BENCH history tracks the mesh-carrying program
-    surface alongside the rule families. Needs >= ``devices`` local
+    implicit reshards — plus the SHARDED PROGRAM COUNT, the
+    mesh-carrying program surface alongside the rule families. Needs >= ``devices`` local
     devices (the tier-1 suite and this tool's CLI both run under
     ``force_host_cpu(8)``)."""
     import tempfile
@@ -337,20 +320,6 @@ eta = 0.01
             shardcheck.disable()
 
 
-def record_ledger(summary):
-    """Append the gate surface to the bench ledger (net=analysis,
-    newest snapshot wins — the same convention as the net=obs rows):
-    BENCH history then shows the checker surface growing alongside
-    the perf headlines."""
-    import time as _time
-    from bench import _update_history
-    entry = dict(summary,
-                 timestamp=_time.strftime("%Y-%m-%dT%H:%M:%SZ",
-                                          _time.gmtime()))
-    entry.pop("unwaived", None)          # keys only matter when dirty
-    return _update_history(entry, net="analysis", metric="timestamp")
-
-
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--list", action="store_true",
@@ -366,22 +335,17 @@ def main(argv=None):
                     help="also run the dynamic sharded-serving gate: "
                          "a dp4 mesh-carrying export served armed "
                          "(0 compiles / transfers / reshards; the "
-                         "sharded program count lands in --ledger)")
+                         "sharded program count is reported)")
     ap.add_argument("--step-artifact", default=None,
                     help="existing split-phase artifact for --rungs "
                          "(default: build a tiny two-rung one)")
-    ap.add_argument("--ledger", action="store_true",
-                    help="record the gate surface as a net=analysis "
-                         "row in docs/bench_history.json (implies "
-                         "--rungs: the row asserts zero steady-state "
-                         "compiles across ALL exported rungs)")
     ap.add_argument("--root", default=_ROOT)
     ap.add_argument("--waivers", default=None,
                     help="waiver file (default docs/analysis_waivers"
                          ".txt under --root)")
     args = ap.parse_args(argv)
 
-    if args.rungs or args.ledger or args.sharded:
+    if args.rungs or args.sharded:
         # the dynamic gates initialize jax; the sharded one needs a
         # multi-device topology — force the 8-way virtual CPU mesh
         # BEFORE any backend comes up (tolerated no-op afterwards)
@@ -394,7 +358,7 @@ def main(argv=None):
     summary = gate_summary(findings, unwaived, stale, res.waivers,
                            res.files)
     rungs_ok = True
-    if args.rungs or args.ledger:
+    if args.rungs:
         rung_res = check_decode_rungs(args.step_artifact)
         summary["decode_rungs"] = rung_res
         rungs_ok = rung_res["ok"]
@@ -410,7 +374,7 @@ def main(argv=None):
                              "\n    ".join(r["violations"])),
                           file=sys.stderr)
     sharded_ok = True
-    if args.sharded or args.ledger:
+    if args.sharded:
         shard_res = check_sharded_serving()
         summary["sharded_serving"] = shard_res
         sharded_ok = shard_res["ok"]
@@ -419,8 +383,6 @@ def main(argv=None):
                   % (shard_res.get("skipped")
                      or "; ".join(shard_res.get("violations", []))),
                   file=sys.stderr)
-    if args.ledger:
-        record_ledger(summary)
     if args.json:
         print(json.dumps(summary))
     else:

@@ -1,16 +1,11 @@
 """Render the goodput attribution ledger (obs/attrib.py) as a report.
 
-Three sources, first match wins:
+Two sources:
 
   python tools/goodput_report.py --url http://127.0.0.1:8000/debug/attrib
                                           # live serving process
   python tools/goodput_report.py --json summary.json
                                           # a saved /debug/attrib body
-  python tools/goodput_report.py          # committed bench ledger:
-                                          # newest docs/bench_history.json
-                                          # run carrying an "attrib"
-                                          # stanza (--history to point
-                                          # elsewhere)
 
 The report answers the capacity question the raw metrics only imply:
 of every slot-token the serving stack dispatched, what fraction was
@@ -24,9 +19,6 @@ add or remove capacity for).
 CI gates:
 
   --assert-goodput-frac F   exit 2 when overall goodput_frac < F
-                            (run against the committed bench history,
-                            this pins the serving stack's efficiency
-                            floor in CI)
   --assert-taxonomy         exit 2 unless goodput_frac + the four
                             waste fractions sum to 1.0 (the per-event
                             invariant, checked end to end)
@@ -37,11 +29,7 @@ tables (composable with both gates).
 
 import argparse
 import json
-import os
 import sys
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-HISTORY = os.path.join(REPO, "docs", "bench_history.json")
 
 WASTE_KINDS = ("pad_fill", "dummy_lane", "overshoot", "retry_duplicate")
 
@@ -65,31 +53,12 @@ def load_json(path):
     return body, path
 
 
-def load_history(path):
-    """Newest run in the bench ledger carrying an ``attrib`` stanza.
-    When the same run also carries a ``profile`` stanza
-    (obs/profile.py), it rides along so the report can join the two
-    ledgers (the device_time column)."""
-    with open(path) as f:
-        doc = json.load(f)
-    runs = doc.get("runs", []) if isinstance(doc, dict) else doc
-    for run in reversed(runs):
-        if isinstance(run, dict) and isinstance(run.get("attrib"), dict):
-            src = "%s (net=%s, %s)" % (path, run.get("net"),
-                                       run.get("timestamp", "?")[:19])
-            prof = run.get("profile")
-            return run["attrib"], src, \
-                prof if isinstance(prof, dict) else None
-    raise SystemExit("goodput_report: no run in %s carries an attrib "
-                     "stanza — run `python bench.py serve` first" % path)
-
-
 def taxonomy_sum(s):
     return s.get("goodput_frac", 0.0) + sum(
         s.get("waste_frac", {}).get(k, 0.0) for k in WASTE_KINDS)
 
 
-def human(s, source, profile=None):
+def human(s, source):
     out = ["goodput attribution — %s" % source]
     slot = s.get("slot_tokens", 0)
     out.append("  %d events, %d slot-tokens dispatched"
@@ -101,35 +70,16 @@ def human(s, source, profile=None):
     for kind in WASTE_KINDS:
         out.append("  %-16s %6.2f%%" % (kind, 100.0 * wf.get(kind, 0.0)))
     pp = s.get("per_phase", {})
-    # device_time join (obs/profile.py): when a profile stanza from
-    # the same bench run is present, each phase's attributed goodput
-    # tokens meet its profiled wall-ms — tokens/s and ms/token per
-    # phase, the two ledgers rendered as one table
-    prof_pp = (profile or {}).get("per_phase", {})
     if pp:
         out.append("per phase:")
-        hdr = "  %-14s %8s %14s %14s %9s" % \
-              ("phase", "events", "slot_tokens", "goodput", "frac")
-        if prof_pp:
-            hdr += " %12s %10s %10s" % ("device_time", "tok/s",
-                                        "ms/tok")
-        out.append(hdr)
+        out.append("  %-14s %8s %14s %14s %9s" %
+                   ("phase", "events", "slot_tokens", "goodput", "frac"))
         for p in sorted(pp):
             t = pp[p]
-            line = "  %-14s %8d %14d %14d %8.2f%%" \
-                % (p, t.get("events", 0), t.get("slot_tokens", 0),
-                   t.get("goodput_tokens", 0),
-                   100.0 * t.get("goodput_frac", 0.0))
-            if prof_pp:
-                w = prof_pp.get(p, {}).get("wall_ms")
-                good = t.get("goodput_tokens", 0)
-                if w:
-                    line += " %10.1fms %10.1f %10.4f" \
-                        % (w, good / (w * 1e-3),
-                           w / good if good else float("inf"))
-                else:
-                    line += " %12s %10s %10s" % ("-", "-", "-")
-            out.append(line)
+            out.append("  %-14s %8d %14d %14d %8.2f%%"
+                       % (p, t.get("events", 0), t.get("slot_tokens", 0),
+                          t.get("goodput_tokens", 0),
+                          100.0 * t.get("goodput_frac", 0.0)))
     top = s.get("top_waste", [])
     if top:
         out.append("top waste sources (ring window, by wasted tokens):")
@@ -145,14 +95,12 @@ def human(s, source, profile=None):
 
 def main():
     ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("--url", help="/debug/attrib endpoint of a live "
-                                  "serving or telemetry process")
-    ap.add_argument("--json", dest="json_path",
-                    help="a saved attribution summary (a /debug/attrib "
-                         "response body)")
-    ap.add_argument("--history", default=HISTORY,
-                    help="bench ledger to read when neither --url nor "
-                         "--json is given (default %(default)s)")
+    src = ap.add_mutually_exclusive_group(required=True)
+    src.add_argument("--url", help="/debug/attrib endpoint of a live "
+                                   "serving or telemetry process")
+    src.add_argument("--json", dest="json_path",
+                     help="a saved attribution summary (a /debug/attrib "
+                          "response body)")
     ap.add_argument("--json-out", action="store_true",
                     help="print the summary as one JSON line")
     ap.add_argument("--assert-goodput-frac", type=float, default=None,
@@ -162,15 +110,11 @@ def main():
                     help="exit 2 unless goodput + waste fractions sum "
                          "to 1.0")
     args = ap.parse_args()
-    profile = None
     if args.url:
         s, source = load_url(args.url)
-    elif args.json_path:
-        s, source = load_json(args.json_path)
     else:
-        s, source, profile = load_history(args.history)
-    print(json.dumps(s) if args.json_out
-          else human(s, source, profile=profile))
+        s, source = load_json(args.json_path)
+    print(json.dumps(s) if args.json_out else human(s, source))
     rc = 0
     if args.assert_taxonomy:
         total = taxonomy_sum(s)

@@ -160,7 +160,6 @@ def time_steps(tr, staged, iters):
         # pre-stacked fuse_steps groups (tr.stage_fused): one jitted
         # call per K steps; >= 2 groups per trial so the one-shot D2H
         # fence and host jitter never land on a single sample
-        # (mirrors bench.py)
         k = staged[0].fused
         groups = max(2, (iters + k - 1) // k)
         for g in range(groups):
@@ -339,15 +338,6 @@ def cmd_zoo(args):
         entries.append((name, tr, staged))
         meta[name] = (batch, shape[1] if is_lm else None)
     best = interleave(entries, args.iters, args.trials, args.warmup)
-    bench = None
-    if getattr(args, "ledger", False) and peaks:
-        import importlib.util
-        import os as _os
-        spec = importlib.util.spec_from_file_location(
-            "bench", _os.path.join(_os.path.dirname(_os.path.dirname(
-                _os.path.abspath(__file__))), "bench.py"))
-        bench = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(bench)
     for name, tr, _ in entries:
         batch, seq = meta[name]
         ms = best[name]
@@ -376,23 +366,6 @@ def cmd_zoo(args):
         if seq:
             row["tokens_per_sec"] = round(batch * seq / ms * 1000.0, 1)
         print(json.dumps(row))
-        if bench is not None:
-            # record this window as a per-net ledger entry
-            # (docs/bench_history.json best_by_net — VERDICT r4 #4)
-            entry = {
-                "timestamp": time.strftime("%Y-%m-%dT%H:%M:%SZ",
-                                           time.gmtime()),
-                "images_per_sec": row["images_per_sec"],
-                "step_ms": row["step_ms"],
-                "mode": "zoo_fuse%d" % args.fuse,
-                "mfu_model_flops": row["mfu_vs_published_bf16_peak"],
-            }
-            if seq:
-                entry["tokens_per_sec"] = row["tokens_per_sec"]
-            lbest = bench._update_history(entry, net=name)
-            sys.stderr.write("ledger[%s]: best %.1f img/s (this run "
-                             "%.1f)\n" % (name, lbest["images_per_sec"],
-                                          row["images_per_sec"]))
 
 
 def main():
@@ -408,9 +381,6 @@ def main():
     a.set_defaults(fn=cmd_ablate)
     z = sub.add_parser("zoo")
     z.add_argument("--net", nargs="*", help="subset of net names")
-    z.add_argument("--ledger", action="store_true",
-                   help="record each row into docs/bench_history.json "
-                        "(per-net bests, VERDICT r4 #4)")
     z.add_argument("--fuse", type=int, default=1,
                    help="fuse_steps: optimizer steps per dispatch "
                         "(amortizes the host's per-dispatch cost)")

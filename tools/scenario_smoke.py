@@ -12,10 +12,8 @@ chain (docs/scenarios.md, docs/observability.md):
    GUARANTEED — the forced incident that proves the paging path;
 2. replay a short bursty scenario (serve/loadgen.py catalog)
    open-loop over HTTP, slow-client entries included;
-3. assert: the replay answered (no errors), the committed bench
-   ledger carries a net=scenario baseline row with p99 +
-   SLO-attainment per scenario, the forced objective opened >= 1
-   incident whose record + retroactive flight dump verify under
+3. assert: the replay answered (no errors), the forced objective
+   opened >= 1 incident whose record + retroactive flight dump verify under
    ``tools/trace_report.py --incident`` semantics (dump present,
    spans balanced, every exemplar request id present as a span), and
    the live ``/slo`` + ``/healthz`` endpoints report the incident.
@@ -41,9 +39,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
-LEDGER = os.path.join(REPO, "docs", "bench_history.json")
-SCEN_REQUIRED = ("bursty", "mixed_priority", "mixed_kinds",
-                 "slow_client")
 
 
 def _watchdog(seconds: int):
@@ -230,21 +225,6 @@ def run(duration_s: float = 2.0, rps: float = 60.0) -> int:
                 eng.close()
             obs_trace.set_flight(None)
             jitcheck.disable()
-
-    # the committed baseline: the bench ledger must carry a
-    # net=scenario row with every catalog scenario scored
-    try:
-        with open(LEDGER) as f:
-            row = json.load(f)["best_by_net"]["scenario"]
-        scens = row.get("scenarios", {})
-        check("ledger_scenario_baseline",
-              all(s in scens
-                  and scens[s].get("p99_ms") is not None
-                  and scens[s].get("slo_attainment") is not None
-                  for s in SCEN_REQUIRED),
-              sorted(scens))
-    except (OSError, KeyError, ValueError) as e:
-        check("ledger_scenario_baseline", False, repr(e))
 
     for name, ok, detail in checks:
         print("scenario_smoke[%s]: %s %s"
