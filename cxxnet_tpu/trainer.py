@@ -125,9 +125,25 @@ class GroupStager:
         return out
 
 
+def _placed(attr: str, shardings: str) -> property:
+    """A tree of the train state, committed on assignment to the
+    shardings the steps were jitted with (a device array is re-wrapped,
+    not copied). A jit keys its build on whether a donated input is
+    committed, and a step's outputs are: any other first input costs a
+    build at step 2. The steps store their placed outputs to ``attr``."""
+    def assign(self, tree):
+        if tree is not None:
+            tree = jax.device_put(tree, getattr(self, shardings))
+        setattr(self, attr, tree)
+    return property(lambda self: getattr(self, attr), assign)
+
+
 class Trainer:
     """Config-driven trainer; mirrors the INetTrainer contract
     (reference: src/nnet/nnet.h:18-92)."""
+
+    params = _placed("_params", "_psh")
+    opt_state = _placed("_opt_state", "_osh")
 
     def __init__(self) -> None:
         self.cfg: List[ConfigEntry] = []
@@ -175,9 +191,8 @@ class Trainer:
         self.eval_nodes: List[Tuple[str, int]] = []
         self.net_cfg: Optional[NetConfig] = None
         self.net: Optional[Network] = None
-        self.params = None
-        self.opt_state = None
-        self.grad_accum = None
+        self._psh = self._osh = None
+        self.params = self.opt_state = self.grad_accum = None
         self.last_loss = None
         # (step number, {(layer, name): device array}) of the steps whose
         # layer counters have not been read yet (Trainer._drain_stats)
@@ -452,25 +467,12 @@ class Trainer:
                 return base
             return parallel.zero_sharding(
                 self.mesh, base, tuple(np.shape(params[li][tag])))
-        osh = []
-        for li, s in enumerate(opt_state):
-            if s is None:
-                osh.append(None)
-            else:
-                osh.append({tag: {slot: slot_sharding(li, tag)
-                                  for slot in slots}
-                            for tag, slots in s.items()})
-        if self.n_devices == 1 and jax.process_count() == 1:
-            # placement on a 1-device mesh is trivially correct, and the
-            # sharded-commit path costs ~1s per large tensor on the CPU
-            # backend (40s of AlexNet startup measured) — same
-            # optimization as _put_batch's uncommitted put
-            self.params = jax.device_put(params)
-            self.opt_state = jax.device_put(opt_state)
-        else:
-            self.params = jax.device_put(params, psh)
-            self.opt_state = jax.device_put(opt_state, osh)
-        self._psh, self._dsh, self._xsh = psh, dsh, xsh
+        osh = [None if s is None else
+               {tag: {slot: slot_sharding(li, tag) for slot in slots}
+                for tag, slots in s.items()}
+               for li, s in enumerate(opt_state)]
+        self._psh, self._osh, self._dsh, self._xsh = psh, osh, dsh, xsh
+        self.params, self.opt_state = params, opt_state
         gsh = [s or {} for s in psh]  # grad tree shardings (None -> {})
         if self.zero >= 2:
             # ZeRO-2: the gradient-accumulation buffers shard over the
@@ -543,7 +545,6 @@ class Trainer:
             (loss, (evals, supd, seen)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(params)
             return loss, evals, supd, grads, seen
-
 
         def train_step(params, opt_state, rng, epoch, maccum,
                        data, extras, labels):
@@ -1093,24 +1094,24 @@ class Trainer:
                 # before the call: donation invalidates the buffers)
                 self._step_specs = jax.tree.map(
                     lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
-                    (self.params, self.opt_state, self._rng,
+                    (self._params, self._opt_state, self._rng,
                      self._epoch_dev, self._maccum, data, extras, labels))
-            (self.params, self.opt_state, self._rng, self._epoch_dev,
+            (self._params, self._opt_state, self._rng, self._epoch_dev,
              self._maccum, loss, stats) = self._train_step(
-                self.params, self.opt_state, self._rng, self._epoch_dev,
+                self._params, self._opt_state, self._rng, self._epoch_dev,
                 self._maccum, data, extras, labels)
             if stats:
                 self._stats_flight.append((self._step_count, stats))
         else:
             (self.grad_accum, self._rng, self._maccum,
              loss, supd) = self._accum_step(
-                self.grad_accum, self._rng, self._maccum, self.params,
+                self.grad_accum, self._rng, self._maccum, self._params,
                 self._epoch_dev, data, extras, labels)
-            self.params = _merge_state(self.params, supd)
+            self._params = _merge_state(self._params, supd)
             if (self.sample_counter + 1) % self.update_period == 0:
-                (self.params, self.opt_state, self.grad_accum,
+                (self._params, self._opt_state, self.grad_accum,
                  self._epoch_dev) = self._apply_accum(
-                    self.params, self.opt_state, self.grad_accum,
+                    self._params, self._opt_state, self.grad_accum,
                     self._epoch_dev)
         # the step's loss, kept as a device scalar (no sync): readable
         # by whoever needs per-step values (chip_smoke.py)
@@ -1192,11 +1193,11 @@ class Trainer:
                 (data_s, extras_s, labels_s))
             self._step_specs = jax.tree.map(
                 lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
-                (self.params, self.opt_state, self._rng,
+                (self._params, self._opt_state, self._rng,
                  self._epoch_dev, self._maccum)) + elem
-        (self.params, self.opt_state, self._rng, self._epoch_dev,
+        (self._params, self._opt_state, self._rng, self._epoch_dev,
          self._maccum, self.last_loss) = self._train_multi(
-            self.params, self.opt_state, self._rng, self._epoch_dev,
+            self._params, self._opt_state, self._rng, self._epoch_dev,
             self._maccum, data_s, extras_s, labels_s)
         # one epoch (= optimizer apply) per accumulation window
         self.epoch_counter += k // self.update_period
@@ -1663,12 +1664,9 @@ class Trainer:
         idx = self.net_cfg.get_layer_index(layer_name)
         if self.params[idx] is None or tag not in self.params[idx]:
             raise ValueError("layer %s has no %s" % (layer_name, tag))
-        cur = self.params[idx][tag]
-        arr = jnp.asarray(weight, jnp.float32).reshape(cur.shape)
-        params = list(self.params)
-        params[idx] = dict(params[idx], **{tag: arr})
-        self.params = jax.device_put(params, self._psh)
-
+        shape = self.params[idx][tag].shape
+        self.params = _merge_state(self.params, {
+            (idx, tag): jnp.asarray(weight, jnp.float32).reshape(shape)})
 
     # ------------------------------------------------------------------
     # checkpointing (reference: nnet_impl-inl.hpp:82-134, SURVEY.md §3.3)
@@ -1821,7 +1819,7 @@ class Trainer:
                         % (old.name, tag, cur[tag].shape, arr.shape))
                 cur[tag] = jnp.asarray(arr)
             params[j] = cur
-        self.params = jax.device_put(params, self._psh)
+        self.params = params
 
 
 _STAT_HELP = ("a counter a layer computes on the device in the train "
