@@ -1943,10 +1943,38 @@ class TransformerStackLayer(Layer):
     ``ops/moe_sorted.py`` over this share's experts, ``expert_first`` and
     ``expert_held`` of ``nexpert`` (the router stays ``nexpert`` wide),
     ``moe_norm_topk`` (renormalise the chosen weights).
+
+    ``attn = mla`` (latent attention as DeepSeek-V2/V3 train it, causal):
+    queries through a latent of ``q_rank`` (tags ``wqa``, its RMSNorm's
+    gain ``qanorm``, ``wqb``: a head's rows ``d_nope`` then ``d_rope``),
+    keys and values through one of ``kv_rank`` (``wkva``: the latent's
+    rows then ``d_rope`` rows of the one rotated key a position that all
+    heads share; ``kvnorm``; ``wkvb``: a head's rows ``d_nope`` of key
+    then ``d_v`` of value), rotary positions (``rope_theta``, neighbour
+    pairs) on the ``d_rope`` dims alone, scores over ``d_nope + d_rope``
+    dims, ``wo`` from ``nhead * d_v``. Kernels: ``ops.flash_attention.
+    flash_attention_mla``; its dense twin off the TPU and for heads the
+    kernels refuse. On the sorted dispatch: ``moe_score = softmax |
+    sigmoid``, ``moe_bias = 1`` (a selection bias, tag ``gbias``: it
+    enters the choice and never a weight; no gradient reaches it and no
+    rule here updates it), ``moe_scale`` (the chosen weights' factor),
+    ``moe_shared = n`` (a shared expert ``n * nhidden_mlp`` wide beside
+    the routed ones: tags ``ws1``, ``ws2``), ``moe_load`` (pairs a
+    position this share is planned for, for ``analytic_flops`` alone;
+    default the mean, ``moe_topk * expert_held / nexpert``).
+    ``dense_first = 1`` with ``nhidden_dense``: layer 0 has a dense
+    gated MLP (tags ``w1d``, ``w2d``) in place of experts and runs
+    outside the scan; the expert leaves are then ``nlayer - 1`` deep.
+    ``raw_out = 1``: a second output node, the residual stream before
+    the final norm (what an ``mtp`` layer reads).
     """
     has_params = True
     param_tags = ("wqkv", "wo", "w1", "w2", "norm1", "norm2", "gate",
-                  "qnorm", "knorm", "normf")
+                  "qnorm", "knorm", "normf", "wqa", "qanorm", "wqb",
+                  "wkva", "kvnorm", "wkvb", "gbias", "ws1", "ws2", "w1d",
+                  "w2d")
+    # the leaves of the routed MLP (``nlayer - dense_first`` deep)
+    _EXPERT_TAGS = ("gate", "gbias", "w1", "w2", "ws1", "ws2")
 
     def __init__(self):
         super().__init__()
@@ -1975,19 +2003,35 @@ class TransformerStackLayer(Layer):
         self.expert_first = 0
         self.expert_held = 0
         self.moe_norm_topk = 0
+        self.attn = "mha"
+        self.q_rank = self.kv_rank = 0
+        self.d_nope = self.d_rope = self.d_v = 0
+        self.moe_score = "softmax"
+        self.moe_bias = 0
+        self.moe_scale = 1.0
+        self.moe_shared = 0
+        self.moe_load = 0.0
+        self.dense_first = 0
+        self.nhidden_dense = 0
+        self.raw_out = 0
 
     _INT_KEYS = ("nkvhead", "head_dim", "qk_norm", "final_norm",
                  "block_len", "expert_first", "expert_held",
-                 "moe_norm_topk")
+                 "moe_norm_topk", "q_rank", "kv_rank", "d_nope", "d_rope",
+                 "d_v", "moe_bias", "moe_shared", "dense_first",
+                 "nhidden_dense", "raw_out")
+    _FLOAT_KEYS = ("rope_theta", "moe_scale", "moe_load")
     _CHOICES = {"mlp_act": ("relu", "swiglu"),
                 "attn_mask": ("full", "causal", "block_diffusion"),
-                "moe_dispatch": ("onehot", "sorted")}
+                "moe_dispatch": ("onehot", "sorted"),
+                "attn": ("mha", "mla"),
+                "moe_score": ("softmax", "sigmoid")}
 
     def set_param(self, name, val):
         if name in self._INT_KEYS:
             setattr(self, name, int(val))
-        elif name == "rope_theta":
-            self.rope_theta = float(val)
+        elif name in self._FLOAT_KEYS:
+            setattr(self, name, float(val))
         elif name in self._CHOICES:
             if val not in self._CHOICES[name]:
                 raise ValueError("%s must be %s" % (
@@ -2040,7 +2084,7 @@ class TransformerStackLayer(Layer):
             self.moe_loss = 0.0 if self.sorted else 0.01
         if self.grouped:
             self._check_grouped(s, e)
-        return [(n, 1, s, e)]
+        return [(n, 1, s, e)] * (2 if self.raw_out else 1)
 
     @property
     def mask(self) -> str:
@@ -2056,7 +2100,8 @@ class TransformerStackLayer(Layer):
         return bool(
             self.nkvhead or self.head_dim or self.qk_norm
             or self.rope_theta or self.final_norm or self.sorted
-            or self.mlp_act != "relu" or self.mask == "block_diffusion")
+            or self.mlp_act != "relu" or self.mask == "block_diffusion"
+            or self.attn == "mla" or self.dense_first or self.raw_out)
 
     def _check_grouped(self, s, e):
         """The grouped block's sizes, and what it does not do, said
@@ -2097,6 +2142,39 @@ class TransformerStackLayer(Layer):
             if self.topk > self.nexpert:
                 raise err("moe_topk %d > nexpert %d"
                           % (self.topk, self.nexpert))
+        elif (self.moe_score != "softmax" or self.moe_bias
+              or self.moe_scale != 1.0 or self.moe_shared):
+            raise err("moe_score, moe_bias, moe_scale and moe_shared are "
+                      "options of moe_dispatch = sorted: the one-hot "
+                      "dispatch scores by softmax and has no shared "
+                      "expert")
+        if self.attn == "mla":
+            if not (self.q_rank and self.kv_rank and self.d_nope
+                    and self.d_rope and self.d_v and self.rope_theta):
+                raise err("attn = mla needs q_rank, kv_rank, d_nope, "
+                          "d_rope, d_v and rope_theta")
+            if self.d_rope % 2:
+                raise err("d_rope %d is not whole pairs" % self.d_rope)
+            if self.nkvhead or self.head_dim or self.qk_norm:
+                raise err("attn = mla has one key and value a head from "
+                          "its latent and norms on the latents: nkvhead, "
+                          "head_dim and qk_norm do not apply")
+            if self.mask != "causal":
+                raise err("attn = mla is causal (set causal = 1): its "
+                          "kernels know no other mask, not attn_mask = %s"
+                          % self.mask)
+        elif self.q_rank or self.kv_rank or self.d_nope or self.d_rope \
+                or self.d_v:
+            raise err("q_rank, kv_rank, d_nope, d_rope and d_v are "
+                      "options of attn = mla")
+        if self.dense_first:
+            if not self.sorted or self.nlayer < 2 \
+                    or self.nhidden_dense <= 0:
+                raise err("dense_first = 1 puts a dense gated MLP "
+                          "(nhidden_dense) in layer 0 of a stack whose "
+                          "other layers route by moe_dispatch = sorted: "
+                          "it needs moe = 1, nlayer >= 2 and "
+                          "nhidden_dense")
 
     def decode_blocker(self) -> str:
         """Why ``task = generate``, ``export_model`` and ``serve`` cannot
@@ -2106,6 +2184,17 @@ class TransformerStackLayer(Layer):
         if not self.grouped:
             return ""
         what = [name for name, on in (
+            ("latent attention (attn = mla): the decode would cache the "
+             "latent and absorb the up-projections", self.attn == "mla"),
+            ("a sigmoid router with a selection bias (moe_score, "
+             "moe_bias, moe_scale)", self.moe_score != "softmax"
+             or self.moe_bias or self.moe_scale != 1.0),
+            ("a shared expert (moe_shared)", self.moe_shared),
+            ("a leading dense layer (dense_first): two kinds of layer "
+             "in one cache", self.dense_first),
+            ("a multi-token prediction module (mtp): a training loss, "
+             "or a draft head the decode does not run",
+             isinstance(self, MTPLayer) or self.raw_out),
             ("rotary positions (rope_theta)", self.rope_theta),
             ("grouped-query heads (nkvhead / head_dim)",
              self.nkvhead or self.head_dim),
@@ -2159,12 +2248,29 @@ class TransformerStackLayer(Layer):
         p = self.param
         nq, nkv = self.nhead * self.hd, self.nkv * self.hd
         wide = m * (2 if self.mlp_act == "swiglu" else 1)
-        out = {
-            "wqkv": p.rand_init_weight(ks[0], (L, nq + 2 * nkv, e), e,
-                                       nq + 2 * nkv),
-            "wo": p.rand_init_weight(ks[1], (L, e, nq), nq, e),
-            "norm1": jnp.ones((L, e), jnp.float32),
-            "norm2": jnp.ones((L, e), jnp.float32)}
+        if self.attn == "mla":
+            nh, dn, dr, dv = self.nhead, self.d_nope, self.d_rope, self.d_v
+            qr, kr = self.q_rank, self.kv_rank
+            ka = jax.random.split(ks[0], 4)
+            out = {
+                "wqa": p.rand_init_weight(ka[0], (L, qr, e), e, qr),
+                "qanorm": jnp.ones((L, qr), jnp.float32),
+                "wqb": p.rand_init_weight(ka[1], (L, nh * (dn + dr), qr),
+                                          qr, nh * (dn + dr)),
+                "wkva": p.rand_init_weight(ka[2], (L, kr + dr, e), e,
+                                           kr + dr),
+                "kvnorm": jnp.ones((L, kr), jnp.float32),
+                "wkvb": p.rand_init_weight(ka[3], (L, nh * (dn + dv), kr),
+                                           kr, nh * (dn + dv)),
+                "wo": p.rand_init_weight(ks[1], (L, e, nh * dv), nh * dv,
+                                         e)}
+        else:
+            out = {
+                "wqkv": p.rand_init_weight(ks[0], (L, nq + 2 * nkv, e), e,
+                                           nq + 2 * nkv),
+                "wo": p.rand_init_weight(ks[1], (L, e, nq), nq, e)}
+        out["norm1"] = jnp.ones((L, e), jnp.float32)
+        out["norm2"] = jnp.ones((L, e), jnp.float32)
         if self.qk_norm:
             out["qnorm"] = jnp.ones((L, self.hd), jnp.float32)
             out["knorm"] = jnp.ones((L, self.hd), jnp.float32)
@@ -2172,12 +2278,27 @@ class TransformerStackLayer(Layer):
             out["normf"] = jnp.ones((e,), jnp.float32)
         if self.sorted:
             H = self.held
+            if self.dense_first:
+                kd = jax.random.split(jax.random.fold_in(ks[2], 1))
+                md = self.nhidden_dense
+                out["w1d"] = p.rand_init_weight(kd[0], (2 * md, e), e,
+                                                2 * md)
+                out["w2d"] = p.rand_init_weight(kd[1], (e, md), md, e)
+                L -= 1
             # an expert's matrices as (in, out), the grouped products'
             # own layout (ops/moe_sorted.py)
             out["w1"] = p.rand_init_weight(ks[2], (L, H, e, wide), e, wide)
             out["w2"] = p.rand_init_weight(ks[3], (L, H, m, e), m, e)
             out["gate"] = p.rand_init_weight(
                 ks[4], (L, self.nexpert, e), e, self.nexpert)
+            if self.moe_bias:
+                out["gbias"] = jnp.zeros((L, self.nexpert), jnp.float32)
+            if self.moe_shared:
+                ms_ = self.moe_shared * m
+                kh = jax.random.split(jax.random.fold_in(ks[3], 1))
+                out["ws1"] = p.rand_init_weight(kh[0], (L, 2 * ms_, e), e,
+                                                2 * ms_)
+                out["ws2"] = p.rand_init_weight(kh[1], (L, e, ms_), ms_, e)
         else:
             out["w1"] = p.rand_init_weight(ks[2], (L, wide, e), e, wide)
             out["w2"] = p.rand_init_weight(ks[3], (L, e, m), m, e)
@@ -2195,16 +2316,31 @@ class TransformerStackLayer(Layer):
         n, _, s, e = self.in_shapes[0]
         m = self.nhidden_mlp or 4 * e
         if self.grouped:
-            nq, nkv = self.nhead * self.hd, self.nkv * self.hd
-            proj = 2.0 * n * s * e * (nq + 2 * nkv) + 2.0 * n * s * nq * e
-            attend = 4.0 * self._attend_pairs(s) * n * s * s * nq
+            if self.attn == "mla":
+                nh, dn, dr, dv = (self.nhead, self.d_nope, self.d_rope,
+                                  self.d_v)
+                proj = 2.0 * n * s * (
+                    e * self.q_rank + self.q_rank * nh * (dn + dr)
+                    + e * (self.kv_rank + dr)
+                    + self.kv_rank * nh * (dn + dv) + nh * dv * e)
+                attend = 2.0 * self._attend_pairs(s) * n * s * s * nh * (
+                    dn + dr + dv)
+            else:
+                nq, nkv = self.nhead * self.hd, self.nkv * self.hd
+                proj = 2.0 * n * s * e * (nq + 2 * nkv) \
+                    + 2.0 * n * s * nq * e
+                attend = 4.0 * self._attend_pairs(s) * n * s * s * nq
             wide = m * (3 if self.mlp_act == "swiglu" else 2)
-            if self.sorted:     # the mean load of this share
+            if self.sorted:     # this share's load: planned, or the mean
+                load = self.moe_load or (self.topk * self.held
+                                         / self.nexpert)
                 mlp = 2.0 * n * s * self.nexpert * e + 2.0 * n * s * (
-                    self.topk * self.held / self.nexpert) * wide * e
+                    load + self.moe_shared) * wide * e
             else:
                 mlp = 2.0 * n * s * wide * e
             fwd = self.nlayer * (proj + attend + mlp)
+            if self.dense_first:
+                fwd += 2.0 * n * s * 3 * self.nhidden_dense * e - mlp
             return fwd, 2.0 * fwd
         c = 0.5 if self.causal else 1.0              # useful causal half
         proj = 2.0 * n * s * e * (3 * e) + 2.0 * n * s * e * e
@@ -2385,26 +2521,38 @@ class TransformerStackLayer(Layer):
                     (rows, rows, rows), rows)(q, k, v)
             return fa.attention_gq_dense(q, k, v, nkv, mask, blen)
 
+        def attention(lp, h):
+            """h + the block's attention."""
+            x = rmsnorm(h, None)          # gain folded into wqkv
+            qkv = jnp.einsum("bse,fe->bsf", x, lp["wqkv"].astype(dt))
+            att = attend(*prepare(lp, qkv))
+            return h + jnp.einsum("bsf,ef->bse", att, lp["wo"].astype(dt))
+
+        if self.attn == "mla":
+            attention = self._mla_attention(dt, interpret, mesh, use_flash,
+                                            rmsnorm)
+
         m = self.nhidden_mlp
 
         def mlp(lp, x):
             b, s, e = x.shape
             if self.sorted:
-                def routed(x, gate, w1, w2):
+                def routed(x, ep):
                     # each data-parallel replica routes its own rows
                     # (the grouped products are Pallas kernels)
                     n = x.shape[0] * s
                     y, stats = ms.moe_sorted(
-                        x.reshape(n, e), {"gate": gate, "w1": w1,
-                                          "w2": w2},
+                        x.reshape(n, e), ep,
                         topk=self.topk, total=self.nexpert,
                         first=self.expert_first, held=self.held,
                         norm_topk=bool(self.moe_norm_topk), dt=dt,
-                        interpret=interpret)
+                        interpret=interpret, score=self.moe_score,
+                        scale=self.moe_scale)
                     return y.reshape(x.shape), stats[None]
                 y, stats = pallas_env.per_shard(
-                    mesh, routed, (rows, P(), P(), P()), (rows, rows))(
-                        x, lp["gate"], lp["w1"], lp["w2"])
+                    mesh, routed, (rows, P()), (rows, rows))(
+                        x, {k: lp[k] for k in self._EXPERT_TAGS
+                            if k in lp})
                 # the replicas' counters: sums, and the largest load
                 stats = jnp.where(
                     jnp.arange(len(ms.STATS)) == ms.STATS.index(
@@ -2419,16 +2567,63 @@ class TransformerStackLayer(Layer):
             return jnp.einsum("bsm,em->bse", a, lp["w2"].astype(dt)), 0.0
 
         def block(lp, h):
-            x = rmsnorm(h, None)          # gain folded into wqkv
-            qkv = jnp.einsum("bse,fe->bsf", x, lp["wqkv"].astype(dt))
-            att = attend(*prepare(lp, qkv))
-            h = h + jnp.einsum("bsf,ef->bse", att, lp["wo"].astype(dt))
+            h = attention(lp, h)
+            if "w1d" in lp:
+                # the leading dense layer (dense_first): a gated MLP of
+                # its own width where the others route
+                x = rmsnorm(h, lp["norm2"])
+                return h + ms.shared_expert(
+                    x.reshape(-1, x.shape[-1]), lp["w1d"], lp["w2d"],
+                    dt).reshape(x.shape), 0.0
             # the routed layer gates on the gained activations: its
             # gain is applied, not folded (_fold_norms)
             y, aux = mlp(lp, rmsnorm(h, lp["norm2"] if self.moe
                                      else None))
             return h + y, aux
         return block
+
+    def _mla_attention(self, dt, interpret, mesh, use_flash, rmsnorm):
+        """-> attention(lp, h) -> h + latent attention (``attn = mla``),
+        on the leaves ``_fold_norms`` derives: ``wqn``, ``wqr`` (the
+        heads' nope and rope rows of ``wqb``), ``wkc``, ``wkr`` (the
+        latent's and the shared key's rows of ``wkva``), ``wkn``, ``wv``
+        (the heads' key and value rows of ``wkvb``), the rope rows with
+        their even dims first (``ops.flash_attention.rope_pairs``)."""
+        from jax.sharding import PartitionSpec as P
+        from .ops import flash_attention as fa
+        from .ops import pallas_env
+        nh, dr = self.nhead, self.d_rope
+        theta = float(self.rope_theta)
+        rows = pallas_env.rows_spec(mesh)
+        flash = use_flash and fa.mla_supported(nh, self.d_nope, dr,
+                                               self.d_v)
+
+        def attend(*ops):
+            if flash:
+                return pallas_env.per_shard(
+                    mesh, lambda *ops: fa.flash_attention_mla(
+                        *ops, nh, interpret=interpret,
+                        mark=(("q_rank", self.q_rank),
+                              ("kv_rank", self.kv_rank))),
+                    (rows,) * 5, rows)(*ops)
+            return fa.attention_mla_dense(*ops, nh)
+
+        def attention(lp, h):
+            b, s, _ = h.shape
+            proj = lambda x, w: jnp.einsum("bse,fe->bsf", x,
+                                           lp[w].astype(dt))
+            pos = jnp.arange(s)
+            x = rmsnorm(h, lp["norm1"])
+            cq = rmsnorm(proj(x, "wqa"), lp["qanorm"])
+            ckv = rmsnorm(proj(x, "wkc"), lp["kvnorm"])
+            qr = fa.rope_pairs(proj(cq, "wqr").reshape(b, s, nh, dr), pos,
+                               theta, True).reshape(b, s, nh * dr)
+            kr = fa.rope_pairs(proj(x, "wkr")[:, :, None], pos, theta,
+                               True)[:, :, 0]
+            att = attend(proj(cq, "wqn"), qr, proj(ckv, "wkn"), kr,
+                         proj(ckv, "wv"))
+            return h + proj(att, "wo")
+        return attention
 
     def _fold_norms(self, params, dt):
         """Fold the rmsnorm gains into the weight matrices they feed:
@@ -2442,8 +2637,32 @@ class TransformerStackLayer(Layer):
         selection (and diverge from generate.py's cached decode) —
         the block applies that gain explicitly instead."""
         out = dict(params)
-        out["wqkv"] = (params["wqkv"]
-                       * params["norm1"][:, None, :]).astype(dt)
+        if self.attn == "mla":
+            # the gains stay where they are (norm1 feeds two products);
+            # the projections' rows are split by what the kernels read,
+            # the rope rows' even dims first, once a step on the weights
+            nh, dn, dr, dv = self.nhead, self.d_nope, self.d_rope, self.d_v
+            L, kr = params["wqb"].shape[0], self.kv_rank
+            evens_first = lambda w: w.reshape(
+                w.shape[:-2] + (dr // 2, 2, w.shape[-1])).swapaxes(
+                    -2, -3).reshape(w.shape)
+            wqb = out.pop("wqb").reshape(L, nh, dn + dr, -1)
+            out["wqn"] = wqb[:, :, :dn].reshape(L, nh * dn, -1)
+            out["wqr"] = evens_first(wqb[:, :, dn:]).reshape(
+                L, nh * dr, -1)
+            wkva = out.pop("wkva")
+            out["wkc"] = wkva[:, :kr]
+            out["wkr"] = evens_first(wkva[:, kr:])
+            wkvb = out.pop("wkvb").reshape(L, nh, dn + dv, -1)
+            out["wkn"] = wkvb[:, :, :dn].reshape(L, nh * dn, -1)
+            out["wv"] = wkvb[:, :, dn:].reshape(L, nh * dv, -1)
+            for k in ("wqa", "wqn", "wqr", "wkc", "wkr", "wkn", "wv",
+                      "ws1", "ws2"):
+                if k in out:
+                    out[k] = out[k].astype(dt)
+        else:
+            out["wqkv"] = (params["wqkv"]
+                           * params["norm1"][:, None, :]).astype(dt)
         if not self.moe:
             out["w1"] = (params["w1"]
                          * params["norm2"][:, None, :]).astype(dt)
@@ -2457,8 +2676,9 @@ class TransformerStackLayer(Layer):
         for k in ("wo", "w2", "w1") + (() if self.sorted else ("gate",)):
             if k in out and out[k].dtype != dt and out[k].ndim > 2:
                 out[k] = out[k].astype(dt)
-        # the one leaf that is not stacked over depth
-        out.pop("normf", None)
+        # the leaves that are not stacked over depth
+        for k in ("normf", "w1d", "w2d", "enorm", "hnorm", "ehproj"):
+            out.pop(k, None)
         return out
 
     def apply(self, params, inputs, ctx):
@@ -2503,6 +2723,20 @@ class TransformerStackLayer(Layer):
                                use_flash=use_flash)
         if self.remat:
             block = jax.checkpoint(block)
+        depth = self.nlayer
+        if pipe == 1:
+            folded = self._fold_norms(params, dt)
+        if self.dense_first:
+            # layer 0, with the dense MLP's leaves; the scan (or the
+            # unrolled loop) takes the rest, whose expert leaves are
+            # nlayer - 1 deep already
+            lp0 = {k: v[0] for k, v in folded.items()
+                   if k not in self._EXPERT_TAGS}
+            lp0.update(w1d=params["w1d"], w2d=params["w2d"])
+            h, _ = block(lp0, h)
+            folded = {k: v if k in self._EXPERT_TAGS else v[1:]
+                      for k, v in folded.items()}
+            depth -= 1
         if pipe > 1:
             if self.nlayer % pipe != 0:
                 raise ValueError(
@@ -2522,7 +2756,7 @@ class TransformerStackLayer(Layer):
             h = pipeline.sharded_pipeline(
                 mesh, lambda lp, hh: block(lp, hh)[0], cast, h, nmb,
                 contains_pallas=use_flash)
-        elif self.scan_unroll >= self.nlayer > 1:
+        elif self.scan_unroll >= depth > 1:
             # FULL Python unroll (scan_unroll >= nlayer): no lax.scan
             # at all — each layer's weights become independent
             # constants XLA can schedule and prefetch freely, where
@@ -2533,9 +2767,8 @@ class TransformerStackLayer(Layer):
             # r3's scan_unroll=4 lost 22% — because it keeps the
             # sliced-stack access without removing the loop).
             # Costs compile time ~linear in depth; opt-in by knob.
-            folded = self._fold_norms(params, dt)
             auxs = []
-            for i in range(self.nlayer):
+            for i in range(depth):
                 lp = jax.tree.map(lambda v, i=i: v[i], folded)
                 h, a = block(lp, h)
                 auxs.append(jnp.asarray(a, jnp.float32))
@@ -2545,8 +2778,8 @@ class TransformerStackLayer(Layer):
                 h2, a = block(lp, hh)
                 return h2, jnp.asarray(a, jnp.float32)
             h, auxs = jax.lax.scan(
-                body, h, self._fold_norms(params, dt),
-                unroll=max(1, min(self.scan_unroll, self.nlayer)))
+                body, h, folded,
+                unroll=max(1, min(self.scan_unroll, depth)))
         # a layer's aux, one a layer: the one-hot dispatch's load-balance
         # loss, or the sorted dispatch's counters (the pipeline branch
         # rejects moe above)
@@ -2556,11 +2789,103 @@ class TransformerStackLayer(Layer):
                 ctx.stats[(ctx.layer_index, "moe_" + name)] = auxs[:, j]
         elif pipe == 1 and self.moe and ctx.train and self.moe_loss > 0.0:
             ctx.losses.append(self.moe_loss * jnp.sum(auxs) / self.nlayer)
+        raw = h
         if self.final_norm:
             h = (h.astype(jnp.float32) * jax.lax.rsqrt(jnp.mean(
                 jnp.square(h.astype(jnp.float32)), -1, keepdims=True)
                 + 1e-6) * params["normf"])
-        return [h.astype(jnp.float32).reshape(b, 1, s, e)]
+        out = [h.astype(jnp.float32).reshape(b, 1, s, e)]
+        if self.raw_out:
+            out.append(raw.astype(jnp.float32).reshape(b, 1, s, e))
+        return out
+
+
+@register("seq_shift")
+class SeqShiftLayer(Layer):
+    """(b, 1, s, w) -> the same sequence ``shift`` positions on:
+    ``out[i] = in[i + shift]``, the last ``shift`` positions 0 (a token
+    model's ids one step ahead, for an ``mtp`` layer's embedding: those
+    positions have no target and enter no loss). No parameters."""
+
+    def __init__(self):
+        super().__init__()
+        self.shift = 1
+
+    def set_param(self, name, val):
+        if name == "shift":
+            self.shift = int(val)
+        else:
+            super().set_param(name, val)
+
+    def _infer(self, in_shapes):
+        n, c, s, w = in_shapes[0]
+        if c != 1 or not 0 < self.shift < s:
+            raise ValueError("seq_shift: input must be (batch,1,seq,w) "
+                             "and 0 < shift < seq")
+        return [in_shapes[0]]
+
+    def apply(self, params, inputs, ctx):
+        x = inputs[0]
+        return [jnp.pad(x[:, :, self.shift:],
+                        ((0, 0), (0, 0), (0, self.shift), (0, 0)))]
+
+
+@register("mtp")
+class MTPLayer(TransformerStackLayer):
+    """Multi-token prediction module, depth 1 (DeepSeek-V3 section 2.2):
+    inputs the trunk's residual stream before its final norm (a
+    ``transformer_stack`` with ``raw_out = 1``) and the embedding of the
+    NEXT token (``share`` of the embedding on ``seq_shift``-ed ids);
+    ``h' = W_eh [rmsnorm(emb; enorm) ; rmsnorm(h; hnorm)]`` (tag
+    ``ehproj``, (e, 2e)), one block of the stack's own function under
+    this layer's options (every option of ``transformer_stack``; the
+    block's leaves stacked 1 deep), and the final norm (``normf``).
+    Output: the hidden stream an ``lm_head`` with ``mtp_weight`` reads
+    as its second input, against the labels one step on. A routed block
+    counts into the same counters as the trunk's."""
+    param_tags = TransformerStackLayer.param_tags + ("enorm", "hnorm",
+                                                     "ehproj")
+
+    def infer_shape(self, in_shapes):
+        self._check_arity(in_shapes, 2, 1)
+        if in_shapes[0] != in_shapes[1]:
+            raise ValueError("mtp: reads the trunk's (batch,1,seq,embed) "
+                             "and the next tokens' embedding of the same "
+                             "shape; got %s and %s" % tuple(in_shapes))
+        if self.nlayer != 1 or self.dense_first or self.raw_out:
+            raise ValueError("mtp: one block (nlayer = 1), no dense_first, "
+                             "no raw_out")
+        self.final_norm = 1
+        out = self._infer(in_shapes[:1])
+        self.in_shapes, self.out_shapes = list(in_shapes), out
+        return out
+
+    def init_params(self, rng) -> Params:
+        e = self.in_shapes[0][3]
+        out = super().init_params(jax.random.fold_in(rng, 0))
+        out["enorm"] = jnp.ones((e,), jnp.float32)
+        out["hnorm"] = jnp.ones((e,), jnp.float32)
+        out["ehproj"] = self.param.rand_init_weight(
+            jax.random.fold_in(rng, 1), (e, 2 * e), 2 * e, e)
+        return out
+
+    def analytic_flops(self, skip_dx=False):
+        n, _, s, e = self.in_shapes[0]
+        fwd, bwd = super().analytic_flops(skip_dx)
+        return fwd + 4.0 * n * s * e * e, bwd + 8.0 * n * s * e * e
+
+    def apply(self, params, inputs, ctx):
+        dt = ctx.compute_dtype
+
+        def normed(x, g):
+            x = x.astype(jnp.float32)
+            return (x * jax.lax.rsqrt(jnp.mean(jnp.square(x), -1,
+                                               keepdims=True) + 1e-6)
+                    ).astype(dt) * params[g].astype(dt)
+        both = jnp.concatenate([normed(inputs[1], "enorm"),
+                                normed(inputs[0], "hnorm")], -1)
+        h = jnp.einsum("bcsf,ef->bcse", both, params["ehproj"].astype(dt))
+        return super().apply(params, [h], ctx)
 
 
 def _stable_logits(logits: jnp.ndarray) -> jnp.ndarray:
@@ -2646,6 +2971,12 @@ class LMHeadLayer(_LossLayer):
     (``target``, ``grad_scale``). Params ``wmat``/``bias`` in fullc
     layout. No reference analogue (cxxnet has no token models,
     SURVEY.md §5).
+
+    ``mtp_weight = w`` (> 0): a second input node, an ``mtp`` layer's
+    hidden stream, passes the same head; its cross entropy against the
+    labels one step on (position i against label i + 1, over the s - 1
+    positions that have one) is added ``w`` times. The unweighted value
+    rides out as the step's stat ``mtp_loss``.
     """
     has_params = True
 
@@ -2654,6 +2985,7 @@ class LMHeadLayer(_LossLayer):
         self.ce_chunk = 0
         self.logit_dtype = "compute"
         self.objective = "next_token"
+        self.mtp_weight = 0.0
 
     def set_param(self, name, val):
         if name == "objective":
@@ -2665,6 +2997,8 @@ class LMHeadLayer(_LossLayer):
                 raise ValueError(
                     "lm_head: objective must be next_token|block_diffusion")
             self.objective = val
+        elif name == "mtp_weight":
+            self.mtp_weight = float(val)
         elif name == "ce_chunk":
             self.ce_chunk = int(val)
         elif name == "logit_dtype":
@@ -2676,6 +3010,16 @@ class LMHeadLayer(_LossLayer):
             super().set_param(name, val)
 
     def infer_shape(self, in_shapes):
+        if self.mtp_weight > 0.0:
+            if self.objective != "next_token" or len(in_shapes) != 2 \
+                    or in_shapes[0] != in_shapes[1] or in_shapes[0][2] < 2:
+                raise ValueError(
+                    "lm_head: mtp_weight reads the trunk's and an mtp "
+                    "layer's (batch,1,seq,embed) under objective = "
+                    "next_token; got %s" % (in_shapes,))
+            out = self._infer(in_shapes[:1])
+            self.in_shapes, self.out_shapes = list(in_shapes), out
+            return out
         if self.objective != "block_diffusion":
             return super().infer_shape(in_shapes)
         self._check_arity(in_shapes, 2, 1)
@@ -2714,6 +3058,8 @@ class LMHeadLayer(_LossLayer):
         n, _, s, e = self.in_shapes[0]
         if self.objective == "block_diffusion":
             s //= 2                     # the noisy half alone
+        if self.mtp_weight > 0.0:
+            s += s - 1                  # the second stream's positions
         f = 2.0 * n * s * e * self.param.num_hidden
         return f, f if skip_dx else 2.0 * f
 
@@ -2762,33 +3108,50 @@ class LMHeadLayer(_LossLayer):
             rows = n * s
             c = self._chunks(rows, v)
             chunk = -(-rows // c)        # pad + mask the ragged tail
-            yf = y.reshape(rows)
-            wf = side[:, 1] if side is not None \
-                else jnp.ones((rows,), jnp.float32)
-            if c * chunk != rows:
-                extra = c * chunk - rows
-                x = jnp.pad(x, ((0, extra), (0, 0)))
-                yf = jnp.pad(yf, (0, extra))
-                wf = jnp.pad(wf, (0, extra))
-            xc = x.reshape(c, chunk, e)
-            yc = yf.reshape(c, chunk)
-            wc = wf.reshape(c, chunk)
 
-            def chunk_ce(acc, t):
-                xx, yy, ww = t
-                # max-subtract in the matmul dtype, upcast after: every
-                # exp argument is <= 0 (the r2 TPU softmax hazard)
-                lg = logits_of(xx)
-                lg = (lg - jax.lax.stop_gradient(
-                    lg.max(-1, keepdims=True))).astype(jnp.float32)
-                lp = jax.nn.log_softmax(lg, axis=-1)
-                picked = jnp.take_along_axis(lp, yy[:, None], axis=1)
-                return acc - (picked[:, 0] * ww).sum(), None
+            def chunked_ce(x, yf, wf):
+                """Summed weighted cross entropy of ``rows`` rows."""
+                if c * chunk != rows:
+                    extra = c * chunk - rows
+                    x = jnp.pad(x, ((0, extra), (0, 0)))
+                    yf = jnp.pad(yf, (0, extra))
+                    wf = jnp.pad(wf, (0, extra))
+                xc = x.reshape(c, chunk, e)
+                yc = yf.reshape(c, chunk)
+                wc = wf.reshape(c, chunk)
 
-            ce, _ = jax.lax.scan(jax.checkpoint(chunk_ce),
-                                 jnp.zeros((), jnp.float32),
-                                 (xc, yc, wc))
+                def chunk_ce(acc, t):
+                    xx, yy, ww = t
+                    # max-subtract in the matmul dtype, upcast after:
+                    # every exp argument is <= 0 (the r2 TPU softmax
+                    # hazard)
+                    lg = logits_of(xx)
+                    lg = (lg - jax.lax.stop_gradient(
+                        lg.max(-1, keepdims=True))).astype(jnp.float32)
+                    lp = jax.nn.log_softmax(lg, axis=-1)
+                    picked = jnp.take_along_axis(lp, yy[:, None], axis=1)
+                    return acc - (picked[:, 0] * ww).sum(), None
+
+                return jax.lax.scan(jax.checkpoint(chunk_ce),
+                                    jnp.zeros((), jnp.float32),
+                                    (xc, yc, wc))[0]
+
+            ce = chunked_ce(x, y.reshape(rows),
+                            side[:, 1] if side is not None
+                            else jnp.ones((rows,), jnp.float32))
             ctx.losses.append(ce * self._scale(ctx) / (s if s > 1 else 1))
+            if self.mtp_weight > 0.0:
+                # the mtp stream: position i against label i + 1, the
+                # last position of a row against nothing
+                y2 = jnp.pad(y.reshape(n, s)[:, 1:], ((0, 0), (0, 1)))
+                w2 = jnp.broadcast_to(
+                    (jnp.arange(s) < s - 1).astype(jnp.float32), (n, s))
+                ce2 = chunked_ce(inputs[1].reshape(rows, e).astype(dt),
+                                 y2.reshape(rows), w2.reshape(rows))
+                ctx.losses.append(self.mtp_weight * ce2 * self._scale(ctx)
+                                  / (s - 1))
+                ctx.stats[(ctx.layer_index, "mtp_loss")] = \
+                    ce2 / (n * (s - 1))
         return [probs.reshape(n, 1, s, v)]
 
 

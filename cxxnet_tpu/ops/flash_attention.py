@@ -1695,6 +1695,385 @@ def attention_gq_dense(q, k, v, nkv: int, mask: str = "causal",
 
 
 # ----------------------------------------------------------------------
+# latent attention (MLA) as it trains: every head has keys and values of
+# its own (``kn``, ``v``: the latent's up-projection), and one rotated
+# key a position (``kr``) that all heads share. A head's score is the sum
+# of two products, q_nope . k_nope + q_rope . k_rope, and its values are
+# narrower than its query-key dims, so the kernels above do not fit: they
+# take one head size. These take the five operands as the projections
+# leave them, nothing copied a head and nothing padded in HBM (a rope
+# part of 64 lanes fills half a tile of the MXU's contraction, in VMEM).
+# Causal only. Tiles, schedule, layouts of the statistics and the two
+# bodies of a masked tile are the grouped-query kernels'; a grid step
+# takes ``G`` heads, so that their rope parts are whole lane tiles. The
+# shared key's gradient sums over the heads: a grid step's heads add up
+# in its accumulator, and the steps' parts (one a group of heads) are
+# summed outside.
+# ----------------------------------------------------------------------
+MLA_GROUP = 4           # heads a grid step, where the head count allows
+
+
+def mla_group(nhead: int, d_rope: int) -> int:
+    """Heads a grid step takes: the largest of ``MLA_GROUP``, 2, 1 that
+    divides ``nhead`` and makes the group's rope parts whole lane tiles;
+    0 where none does."""
+    for g in (MLA_GROUP, 2, 1):
+        if nhead % g == 0 and (g * d_rope) % LANES == 0:
+            return g
+    return 0
+
+
+def mla_supported(nhead, d_nope, d_rope, d_v) -> bool:
+    """Do the ``flash_mla_*`` kernels take these heads? (The layer's
+    dense twin, ``attention_mla_dense``, takes any.)"""
+    return (d_nope % LANES == 0 and d_v % LANES == 0
+            and d_rope % 8 == 0 and mla_group(nhead, d_rope) > 0)
+
+
+def _mla_scores(kn, kr, qn_ref, qr_ref, g, dn, dr, keep):
+    """(keys, queries) scores of head ``g`` of the step's group."""
+    st = _dot(kn, qn_ref[0, :, g * dn:(g + 1) * dn], _NT) \
+        + _dot(kr, qr_ref[0, :, g * dr:(g + 1) * dr], _NT)
+    return st if keep is None else jnp.where(keep, st, NEG_INF)
+
+
+def _mla_fwd_kernel(qt_ref, kt_ref, lo_ref, hi_ref, first_ref, last_ref,
+                    qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, lse_ref,
+                    vt_s, m_s, l_s, acc_s, *, G, dn, dr, dv, T):
+    si = pl.program_id(2)
+
+    @pl.when(first_ref[si] == 1)
+    def _init():
+        m_s[...] = jnp.full_like(m_s, NEG_INF)
+        l_s[...] = jnp.zeros_like(l_s)
+        acc_s[...] = jnp.zeros_like(acc_s)
+
+    vt_s[...] = v_ref[0].T                               # (G * dv, keys)
+
+    def work(keep):
+        kr = kr_ref[0]
+        for g in range(G):
+            st = _mla_scores(kn_ref[0, :, g * dn:(g + 1) * dn], kr,
+                             qn_ref, qr_ref, g, dn, dr, keep)
+            m1 = m_s[g]                                  # (1, queries)
+            m2 = jnp.maximum(m1, jnp.max(st, axis=0, keepdims=True))
+            p = jnp.exp(st - m2)
+            corr = jnp.exp(m1 - m2)
+            l_s[g] = l_s[g] * corr + jnp.sum(p, axis=0, keepdims=True)
+            # acc[c, i] += sum_j v[j, c] p[j, i]
+            acc_s[g] = acc_s[g] * corr + _dot(
+                vt_s[g * dv:(g + 1) * dv, :], p.astype(vt_s.dtype), _NN)
+            m_s[g] = m2
+
+    _gq_bodies(lo_ref, hi_ref, si, T, 0, work)
+
+    @pl.when(last_ref[si] == 1)
+    def _flush():
+        lsafe = jnp.maximum(l_s[...], 1e-30)             # (G, 1, T)
+        o_ref[0] = (acc_s[...] / lsafe).reshape(G * dv, T).T.astype(
+            o_ref.dtype)
+        lse_ref[0, 0] = m_s[...] + jnp.log(lsafe)
+
+
+def _mla_dq_kernel(qt_ref, kt_ref, lo_ref, hi_ref, first_ref, last_ref,
+                   qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref, lse_ref,
+                   delta_ref, dqn_ref, dqr_ref, knt_s, krt_s, an_s, ar_s,
+                   *, G, dn, dr, dv, T, scale):
+    si = pl.program_id(2)
+
+    @pl.when(first_ref[si] == 1)
+    def _init():
+        an_s[...] = jnp.zeros_like(an_s)
+        ar_s[...] = jnp.zeros_like(ar_s)
+
+    knt_s[...] = kn_ref[0].T                             # (G * dn, keys)
+    krt_s[...] = kr_ref[0].T                             # (dr, keys)
+
+    def work(keep):
+        kr = kr_ref[0]
+        for g in range(G):
+            p = jnp.exp(_mla_scores(kn_ref[0, :, g * dn:(g + 1) * dn], kr,
+                                    qn_ref, qr_ref, g, dn, dr, keep)
+                        - lse_ref[0, 0, g])
+            dp = _dot(v_ref[0, :, g * dv:(g + 1) * dv],
+                      do_ref[0, :, g * dv:(g + 1) * dv], _NT)
+            ds = (p * (dp - delta_ref[0, 0, g])).astype(knt_s.dtype)
+            # dq[c, i] += sum_j k[j, c] ds[j, i], both parts of the key
+            an_s[g] = an_s[g] + _dot(knt_s[g * dn:(g + 1) * dn, :], ds,
+                                     _NN)
+            ar_s[g] = ar_s[g] + _dot(krt_s[...], ds, _NN)
+
+    _gq_bodies(lo_ref, hi_ref, si, T, 0, work)
+
+    @pl.when(last_ref[si] == 1)
+    def _flush():
+        # q came in scaled: the chain rule's factor goes on here
+        dqn_ref[0] = (an_s[...] * scale).reshape(G * dn, T).T.astype(
+            dqn_ref.dtype)
+        dqr_ref[0] = (ar_s[...] * scale).reshape(G * dr, T).T.astype(
+            dqr_ref.dtype)
+
+
+def _mla_dkv_kernel(qt_ref, kt_ref, lo_ref, hi_ref, first_ref, last_ref,
+                    qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref, lse_ref,
+                    delta_ref, dkn_ref, dkr_ref, dv_ref, dkn_s, dkr_s,
+                    dv_s, *, G, dn, dr, dv, T):
+    si = pl.program_id(2)
+
+    @pl.when(first_ref[si] == 1)
+    def _init():
+        dkn_s[...] = jnp.zeros_like(dkn_s)
+        dkr_s[...] = jnp.zeros_like(dkr_s)
+        dv_s[...] = jnp.zeros_like(dv_s)
+
+    def work(keep):
+        kr = kr_ref[0]
+        dkr = dkr_s[...]
+        for g in range(G):
+            qn = qn_ref[0, :, g * dn:(g + 1) * dn]
+            dog = do_ref[0, :, g * dv:(g + 1) * dv]
+            p = jnp.exp(_mla_scores(kn_ref[0, :, g * dn:(g + 1) * dn], kr,
+                                    qn_ref, qr_ref, g, dn, dr, keep)
+                        - lse_ref[0, 0, g])
+            dv_s[g] = dv_s[g] + _dot(p.astype(dog.dtype), dog, _NN)
+            dp = _dot(v_ref[0, :, g * dv:(g + 1) * dv], dog, _NT)
+            ds = (p * (dp - delta_ref[0, 0, g])).astype(qn.dtype)
+            # against the scaled q: dk carries the factor already; the
+            # shared key's part sums over the group's heads
+            dkn_s[g] = dkn_s[g] + _dot(ds, qn, _NN)
+            dkr = dkr + _dot(ds, qr_ref[0, :, g * dr:(g + 1) * dr], _NN)
+        dkr_s[...] = dkr
+
+    _gq_bodies(lo_ref, hi_ref, si, T, 0, work)
+
+    @pl.when(last_ref[si] == 1)
+    def _flush():
+        for g in range(G):
+            dkn_ref[0, :, g * dn:(g + 1) * dn] = dkn_s[g].astype(
+                dkn_ref.dtype)
+            dv_ref[0, :, g * dv:(g + 1) * dv] = dv_s[g].astype(
+                dv_ref.dtype)
+        dkr_ref[0, 0] = dkr_s[...]
+
+
+def _mla_specs(G, dn, dr, dv, T):
+    """BlockSpecs by role (q side by the step's q tile, k side by its k
+    tile); an index map takes the grid's (row, head group, step) and
+    then the schedule's six rows."""
+    by_q = lambda w: pl.BlockSpec((1, T, w),
+                                  lambda b, h, s, qt, *_: (b, qt[s], h))
+    by_k = lambda w: pl.BlockSpec(
+        (1, T, w), lambda b, h, s, qt, kt, *_: (b, kt[s], h))
+    shared = pl.BlockSpec((1, T, dr),
+                          lambda b, h, s, qt, kt, *_: (b, kt[s], 0))
+    stat = pl.BlockSpec((1, 1, G, 1, T),
+                        lambda b, h, s, qt, *_: (b, h, 0, 0, qt[s]))
+    part = pl.BlockSpec((1, 1, T, dr),
+                        lambda b, h, s, qt, kt, *_: (b, h, kt[s], 0))
+    return {"qn": by_q(G * dn), "qr": by_q(G * dr), "o": by_q(G * dv),
+            "kn": by_k(G * dn), "v": by_k(G * dv), "kr": shared,
+            "stat": stat, "dkr": part}
+
+
+def _mla_dims(qn, qr, kr, v, nhead):
+    dn, dr, dv = (qn.shape[2] // nhead, kr.shape[2], v.shape[2] // nhead)
+    if qr.shape[2] != nhead * dr:
+        raise ValueError("flash_attention_mla: q's rope part is %d wide, "
+                         "not %d heads of the shared key's %d"
+                         % (qr.shape[2], nhead, dr))
+    return dn, dr, dv
+
+
+# jitted on their own, the plan static, so that a model's layers trace
+# and lower these kernels once (see _flatb_fwd_call)
+@functools.partial(jax.jit, static_argnums=(5, 6, 7, 8))
+def _mla_fwd_call(qn, qr, kn, kr, v, nhead, G, n, interpret):
+    from jax.experimental.pallas import tpu as pltpu
+    b, S, _ = kr.shape
+    dn, dr, dv = _mla_dims(qn, qr, kr, v, nhead)
+    T = S // n
+    sched = gq_schedule("causal", n, "q")
+    sp = _mla_specs(G, dn, dr, dv, T)
+    return _gq_call(
+        "flash_mla_fwd",
+        functools.partial(_mla_fwd_kernel, G=G, dn=dn, dr=dr, dv=dv, T=T),
+        sched, (b, nhead // G, len(sched[0])),
+        [sp["qn"], sp["qr"], sp["kn"], sp["kr"], sp["v"]],
+        [sp["o"], sp["stat"]],
+        [jax.ShapeDtypeStruct(v.shape, v.dtype),
+         jax.ShapeDtypeStruct((b, nhead // G, G, 1, S), jnp.float32)],
+        [pltpu.VMEM((G * dv, T), v.dtype),              # v.T
+         pltpu.VMEM((G, 1, T), jnp.float32),            # m
+         pltpu.VMEM((G, 1, T), jnp.float32),            # l
+         pltpu.VMEM((G, dv, T), jnp.float32)],          # acc
+        interpret)(*sched, qn, qr, kn, kr, v)
+
+
+@functools.partial(jax.jit, static_argnums=(8, 9, 10, 11, 12))
+def _mla_bwd_call(qn, qr, kn, kr, v, o, lse, do, nhead, G, n, scale,
+                  interpret):
+    from jax.experimental.pallas import tpu as pltpu
+    b, S, _ = kr.shape
+    dn, dr, dv = _mla_dims(qn, qr, kr, v, nhead)
+    T = S // n
+    delta = jnp.sum((do.astype(jnp.float32) * o.astype(jnp.float32)
+                     ).reshape(b, S, nhead // G, G, dv), axis=-1)
+    delta = delta.transpose(0, 2, 3, 1)[:, :, :, None, :]   # as lse
+    sp = _mla_specs(G, dn, dr, dv, T)
+    ins = [sp["qn"], sp["qr"], sp["kn"], sp["kr"], sp["v"], sp["o"],
+           sp["stat"], sp["stat"]]
+    args = (qn, qr, kn, kr, v, do, lse, delta)
+    sq = gq_schedule("causal", n, "q")
+    dqn, dqr = _gq_call(
+        "flash_mla_dq",
+        functools.partial(_mla_dq_kernel, G=G, dn=dn, dr=dr, dv=dv, T=T,
+                          scale=scale),
+        sq, (b, nhead // G, len(sq[0])), ins, [sp["qn"], sp["qr"]],
+        [jax.ShapeDtypeStruct(qn.shape, qn.dtype),
+         jax.ShapeDtypeStruct(qr.shape, qr.dtype)],
+        [pltpu.VMEM((G * dn, T), kn.dtype),             # kn.T
+         pltpu.VMEM((dr, T), kr.dtype),                 # kr.T
+         pltpu.VMEM((G, dn, T), jnp.float32),           # dqn.T
+         pltpu.VMEM((G, dr, T), jnp.float32)],          # dqr.T
+        interpret)(*sq, *args)
+    sk = gq_schedule("causal", n, "k")
+    dkn, dkr, dv_ = _gq_call(
+        "flash_mla_dkv",
+        functools.partial(_mla_dkv_kernel, G=G, dn=dn, dr=dr, dv=dv, T=T),
+        sk, (b, nhead // G, len(sk[0])), ins,
+        [sp["kn"], sp["dkr"], sp["v"]],
+        [jax.ShapeDtypeStruct(kn.shape, kn.dtype),
+         jax.ShapeDtypeStruct((b, nhead // G, S, dr), jnp.float32),
+         jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        [pltpu.VMEM((G, T, dn), jnp.float32),
+         pltpu.VMEM((T, dr), jnp.float32),
+         pltpu.VMEM((G, T, dv), jnp.float32)],
+        interpret)(*sk, *args)
+    return dqn, dqr, dkn, jnp.sum(dkr, axis=1).astype(kr.dtype), dv_
+
+
+def flash_attention_mla(qn, qr, kn, kr, v, nhead: int, scale=None,
+                        interpret=None, tile: int = 0, mark=()):
+    """Causal latent attention, O(S d) memory: qn (b, S, heads * d_nope)
+    and qr (b, S, heads * d_rope) the two parts of the queries, kn (b, S,
+    heads * d_nope) the heads' own keys, kr (b, S, d_rope) the one
+    rotated key a position all heads share, v (b, S, heads * d_v), each
+    as its projection leaves it (qr and kr rotated) -> (b, S, heads *
+    d_v). ``scale`` defaults to (d_nope + d_rope) ** -0.5. ``mark``:
+    ((name, value), ...) the caller adds to the ``mla.plan`` span (the
+    layer's latent ranks, which the kernels never see)."""
+    if interpret is None:
+        interpret = _interpret()
+    if scale is None:
+        scale = (qn.shape[2] // nhead + kr.shape[2]) ** -0.5
+    return _flash_mla(qn, qr, kn, kr, v, nhead, float(scale),
+                      bool(interpret), tile, tuple(mark))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+def _flash_mla(qn, qr, kn, kr, v, nhead, scale, interpret, tile, mark):
+    return _flash_mla_fwd(qn, qr, kn, kr, v, nhead, scale, interpret,
+                          tile, mark)[0]
+
+
+def _mla_plan(kernels, S, qn, qr, kr, v, nhead, tile):
+    """-> (tile, tiles, heads a step, the ``mla.plan`` span's arguments)
+    of a call on ``S`` positions, checked."""
+    dn, dr, dv = _mla_dims(qn, qr, kr, v, nhead)
+    if not mla_supported(nhead, dn, dr, dv):
+        raise ValueError(
+            "flash_attention_mla: %d heads of d_nope %d, d_rope %d, d_v "
+            "%d: d_nope and d_v must be whole 128-lane tiles and a group "
+            "of heads' rope parts too (attention_mla_dense takes any)"
+            % (nhead, dn, dr, dv))
+    G = mla_group(nhead, dr)
+    T = gq_tile(S, tile)
+    n = -(-S // T)
+    item = jnp.dtype(v.dtype).itemsize
+    vmem = (2 * item * T * (2 * G * (dn + dv) + G * dr + dr)    # tiles x2
+            + 4 * T * G * (dn + dr + dv + 2) + 4 * T * T)
+    return T, n, G, {
+        "kernels": kernels, "s": S, "heads": nhead, "d_nope": dn,
+        "d_rope": dr, "d_v": dv, "group": G, "block_q": T, "block_k": T,
+        "tile_pairs": n * (n + 1) // 2, "tile_pairs_dense": n * n,
+        "vmem_bytes": int(vmem)}
+
+
+def _flash_mla_fwd(qn, qr, kn, kr, v, nhead, scale, interpret, tile,
+                   mark):
+    from ..obs import trace
+    S = kr.shape[1]
+    T, n, G, plan = _mla_plan("fwd", S, qn, qr, kr, v, nhead, tile)
+    # the scale folded into q once; the scaled q is what the backward
+    # kernels take (the chain rule's factor goes on dq at its flush)
+    sc = jnp.asarray(scale, qn.dtype)
+    ops = tuple(_gq_pad(x, 1, S, n * T)
+                for x in (qn * sc, qr * sc, kn, kr, v))
+    with trace.span("mla.plan", "kernel", dict(plan, **dict(mark))):
+        o, lse = _mla_fwd_call(*ops, nhead, G, n, interpret)
+    return _gq_unpad(o, 1, S, n * T), ops + (o, lse)
+
+
+def _flash_mla_bwd(nhead, scale, interpret, tile, mark, res, g):
+    from ..obs import trace
+    qn, qr, kn, kr, v, o, lse = res
+    S = g.shape[1]
+    T, n, G, plan = _mla_plan("bwd", S, qn, qr, kr, v, nhead, tile)
+    do = _gq_pad(g, 1, S, n * T)
+    with trace.span("mla.plan", "kernel", dict(plan, **dict(mark))):
+        grads = _mla_bwd_call(qn, qr, kn, kr, v, o, lse, do, nhead, G, n,
+                              scale, interpret)
+    return tuple(_gq_unpad(x, 1, S, n * T) for x in grads)
+
+
+_flash_mla.defvjp(_flash_mla_fwd, _flash_mla_bwd)
+
+
+def rope_pairs(x, pos, theta: float, halves: bool = False):
+    """Rotary positions over the last axis of ``x`` (..., S, heads, d),
+    ``pos`` (S,). ``halves = False``: the pairs are neighbours (2i,
+    2i + 1), as ``rope_interleave`` has them. ``halves = True``: the same
+    rotation of a vector whose even dims come first and its odd dims
+    after them (what a projection gives once its rows are so ordered):
+    a dot product of two such vectors is that of the two they stand
+    for."""
+    d = x.shape[-1]
+    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = pos.astype(jnp.float32)[:, None] * inv[None]      # (S, d / 2)
+    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
+    xf = x.astype(jnp.float32)
+    if halves:
+        a, b = xf[..., :d // 2], xf[..., d // 2:]
+        out = jnp.concatenate([a * cos - b * sin, b * cos + a * sin], -1)
+    else:
+        a, b = xf[..., 0::2], xf[..., 1::2]
+        out = jnp.stack([a * cos - b * sin, b * cos + a * sin],
+                        -1).reshape(x.shape)
+    return out.astype(x.dtype)
+
+
+def attention_mla_dense(qn, qr, kn, kr, v, nhead: int, scale=None):
+    """``flash_attention_mla``'s result by a dense causal mask in plain
+    XLA: the path off the TPU, the kernels' twin in the tests, and the
+    path of heads the kernels refuse (``mla_supported``)."""
+    b, S, dr = kr.shape
+    dn, dv = qn.shape[2] // nhead, v.shape[2] // nhead
+    if scale is None:
+        scale = (dn + dr) ** -0.5
+    sc = jnp.einsum("bqhd,bshd->bhqs", qn.reshape(b, S, nhead, dn),
+                    kn.reshape(b, S, nhead, dn),
+                    preferred_element_type=jnp.float32) \
+        + jnp.einsum("bqhd,bsd->bhqs", qr.reshape(b, S, nhead, dr), kr,
+                     preferred_element_type=jnp.float32)
+    idx = jnp.arange(S)
+    keep = idx[None, :] <= idx[:, None]
+    p = jax.nn.softmax(jnp.where(keep, sc * scale, NEG_INF), axis=-1)
+    out = jnp.einsum("bhqs,bshd->bqhd", p.astype(v.dtype),
+                     v.reshape(b, S, nhead, dv))
+    return out.reshape(b, S, nhead * dv)
+
+
+# ----------------------------------------------------------------------
 def flash_attention(q, k, v, causal: bool = False, scale=None,
                     interpret=None):
     """(b, h, s, d) attention, O(s*d) memory. Exact — same math as

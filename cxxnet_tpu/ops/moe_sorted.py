@@ -34,16 +34,34 @@ CHUNK = 8192                    # rows of the sorted order a piece
 STATS = ("pairs", "load_max", "rows_computed")
 
 
-def route(x, router_w, topk: int, norm_topk: bool):
+def route(x, router_w, topk: int, norm_topk: bool, score: str = "softmax",
+          bias=None, scale: float = 1.0):
     """-> (weights (P, topk) f32, experts (P, topk) int32) over all the
-    router's experts, in float32 whatever ``x`` is."""
+    router's experts, in float32 whatever ``x`` is. ``score = "sigmoid"``
+    scores each expert on its own; ``bias`` (experts,) is added to the
+    scores for the choice alone (a selection bias: the weights are the
+    unbiased scores of the chosen, and no gradient reaches it);
+    ``scale`` multiplies the weights last. Experts are not grouped: a
+    router with ``n_group = topk_group = 1`` limits nothing."""
     logits = jnp.dot(x.astype(jnp.float32),
                      router_w.astype(jnp.float32).T,
                      precision=lax.Precision.HIGHEST)
-    w, idx = lax.top_k(jax.nn.softmax(logits, axis=-1), topk)
-    if norm_topk:
-        w = w / jnp.sum(w, axis=-1, keepdims=True)
-    return w, idx
+    if score == "softmax" and bias is None:
+        # (what the branch below computes for these arguments, kept in
+        # the form the softmax router's compiled step already has)
+        w, idx = lax.top_k(jax.nn.softmax(logits, axis=-1), topk)
+        if norm_topk:
+            w = w / jnp.sum(w, axis=-1, keepdims=True)
+    else:
+        sc = jax.nn.sigmoid(logits) if score == "sigmoid" \
+            else jax.nn.softmax(logits, axis=-1)
+        chosen = sc if bias is None else sc + lax.stop_gradient(
+            bias.astype(jnp.float32))
+        _, idx = lax.top_k(chosen, topk)
+        w = jnp.take_along_axis(sc, idx, axis=-1)
+        if norm_topk:
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return (w * scale if scale != 1.0 else w), idx
 
 
 def plan(idx, first: int, held: int, tile: int):
@@ -202,21 +220,41 @@ def _experts_bwd(topk, chunk, interpret, res, g):
 experts.defvjp(_experts_fwd, _experts_bwd)
 
 
+def shared_expert(x, ws1, ws2, dt):
+    """x (P, e) -> (P, e): the expert every token passes, a gated SiLU
+    MLP as the routed ones, in plain XLA: ``ws1`` (2m, e) the gate
+    projection's rows then the up projection's, ``ws2`` (e, m)."""
+    m = ws2.shape[1]
+    a = jnp.dot(x, ws1.astype(dt).T)
+    a = (jax.nn.silu(a[:, :m].astype(jnp.float32))
+         * a[:, m:].astype(jnp.float32)).astype(dt)
+    return jnp.dot(a, ws2.astype(dt).T)
+
+
 def moe_sorted(x, lp, *, topk: int, total: int, first: int, held: int,
-               norm_topk: bool, dt, interpret: bool):
+               norm_topk: bool, dt, interpret: bool,
+               score: str = "softmax", scale: float = 1.0):
     """x (P, e) tokens -> (this share's part of the experts' sum (P, e),
     stats (3,) f32 as ``STATS``). ``lp``: ``gate`` (total, e), ``w1``
     (held, e, 2m) (columns [0, m) the gate projection, [m, 2m) the up
-    projection), ``w2`` (held, m, e)."""
+    projection), ``w2`` (held, m, e); and where the layer has them
+    ``gbias`` (total,), the router's selection bias, and ``ws1``,
+    ``ws2``, the shared expert (``shared_expert``), whose output is
+    added whole: every share of a deployment computes it for the tokens
+    it holds, so it is counted once."""
     from ..obs import trace
     n = x.shape[0] * topk
     chunk = min(CHUNK, -(-n // GMM_TILE[0]) * GMM_TILE[0])
     with trace.span("moe.plan", "kernel", {
             "total": total, "held": held, "first": first, "topk": topk,
             "tokens": x.shape[0], "rows": n, "chunk": chunk,
-            "tile": GMM_TILE[0]}):
-        w, idx = route(x, lp["gate"], topk, norm_topk)
+            "tile": GMM_TILE[0], "score": score,
+            "shared": int("ws1" in lp)}):
+        w, idx = route(x, lp["gate"], topk, norm_topk, score,
+                       lp.get("gbias"), scale)
         order, counts, stats = plan(idx, first, held, GMM_TILE[0])
     y = experts(x.astype(dt), w.reshape(-1), lp["w1"].astype(dt),
                 lp["w2"].astype(dt), order, counts, topk, chunk, interpret)
+    if "ws1" in lp:
+        y = y + shared_expert(x.astype(dt), lp["ws1"], lp["ws2"], dt)
     return y, stats

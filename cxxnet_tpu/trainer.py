@@ -1053,11 +1053,12 @@ class Trainer:
         that have ended since the last call, read without waiting on the
         device (a step still running stays in flight). Each value goes
         to the registry by the name its layer gave it, one series a row:
-        a name that ends in ``_max`` to the gauge ``cxxnet_<name>``,
-        any other to the counter ``cxxnet_<name>_total``. The newest
-        ended step's come back as span arguments: ``stats_step`` and,
-        for every name, its sum (its largest, for ``_max``) over the
-        layers."""
+        a name that ends in ``_max`` or ``_loss`` to the gauge
+        ``cxxnet_<name>`` (a loss is the step's own value, not a
+        count), any other to the counter ``cxxnet_<name>_total``. The
+        newest ended step's come back as span arguments: ``stats_step``
+        and, for every name, its sum (its largest, for ``_max``) over
+        the layers."""
         from .obs.registry import get_registry
         reg, newest = get_registry(), {}
         while self._stats_flight:
@@ -1071,7 +1072,7 @@ class Trainer:
                 rows = np.asarray(v, np.float64).reshape(-1)
                 for i, x in enumerate(rows):
                     where = "%d.%d" % (layer, i)
-                    if largest:
+                    if largest or name.endswith("_loss"):
                         reg.gauge("cxxnet_" + name, _STAT_HELP,
                                   ("layer",)).set(float(x), layer=where)
                     else:

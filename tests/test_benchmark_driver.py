@@ -36,6 +36,10 @@ NODES = [
     "benchmark/tests/test_program_spans.py",
     "benchmark/tests/test_sdar.py"
     "::test_the_counters_readers_read_what_the_program_leaves",
+    "benchmark/tests/test_joyai.py::test_rehearsal_untraced",
+    "benchmark/tests/test_joyai.py::test_rehearsal_traced",
+    "benchmark/tests/test_joyai.py"
+    "::test_the_readers_read_what_the_program_leaves",
 ]
 
 

@@ -227,3 +227,51 @@ def test_moe_sorted_compiles(one_chip):
             lambda *a: fn(*a).astype(jnp.float32).sum(),
             argnums=(0, 1, 2, 3)), one_chip, *shapes)
         assert _kernel_names(text) == {"moe_gmm", "moe_tgmm"}
+
+
+def test_flash_attention_mla_compiles(one_chip):
+    """The benchmark's cell train.joyai_llm_flash.seq4096: 2 rows of
+    4,096 positions, 32 heads of 128 nope + 64 rope query-key dims and
+    128 value dims, one rotated key a position shared by all heads,
+    causal over 512-position tiles, four heads a grid step. Each kernel
+    carries the name ``mla_attn_*_roofline.train`` match, and nothing a
+    head wide is padded or copied in HBM: the five operands go in as the
+    projections leave them."""
+    from cxxnet_tpu.ops import flash_attention as fa
+    b, S, nh, dn, dr, dv = 2, 4096, 32, 128, 64, 128
+    assert fa.mla_supported(nh, dn, dr, dv) and fa.mla_group(nh, dr) == 4
+
+    def loss(*ops):
+        return fa.flash_attention_mla(
+            *ops, nh, interpret=False).astype(jnp.float32).sum()
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2, 3, 4)), one_chip,
+                    *(((b, S, w), jnp.bfloat16) for w in (
+                        nh * dn, nh * dr, nh * dn, dr, nh * dv)))
+    assert _kernel_names(text) == {"flash_mla_fwd", "flash_mla_dq",
+                                   "flash_mla_dkv"}
+
+
+def test_routed_layer_with_sigmoid_bias_and_shared_expert_compiles(
+        one_chip):
+    """The same cell's routed layer: 8,192 positions, top 8 of 256
+    experts by sigmoid scores and a selection bias, 16 held, the shared
+    expert beside the grouped products (plain XLA: no third kernel)."""
+    from cxxnet_tpu.ops import moe_sorted as ms
+    P, e, m, total, held, topk = 8192, 2048, 768, 256, 16, 8
+
+    def layer(x, gate, gbias, w1, w2, ws1, ws2):
+        return ms.moe_sorted(
+            x, {"gate": gate, "gbias": gbias, "w1": w1, "w2": w2,
+                "ws1": ws1, "ws2": ws2}, topk=topk, total=total, first=0,
+            held=held, norm_topk=True, dt=jnp.bfloat16, interpret=False,
+            score="sigmoid", scale=2.5)[0]
+
+    text = _compile(jax.grad(
+        lambda *a: layer(*a).astype(jnp.float32).sum(),
+        argnums=(0, 1, 3, 4, 5, 6)), one_chip,
+        ((P, e), jnp.bfloat16), ((total, e), jnp.float32),
+        ((total,), jnp.float32), ((held, e, 2 * m), jnp.float32),
+        ((held, m, e), jnp.float32), ((2 * m, e), jnp.float32),
+        ((e, m), jnp.float32))
+    assert _kernel_names(text) == {"moe_gmm", "moe_tgmm"}
