@@ -1922,9 +1922,15 @@ class TransformerStackLayer(Layer):
     ``nhead``, ``causal``, ``nhidden_mlp``, ``n_microbatch`` (pipeline
     microbatches per local batch, default = pipe size), ``remat``
     (rematerialize each block's intermediates in the backward pass —
-    jax.checkpoint — so only one (b, s, e) boundary activation per layer
-    is kept instead of every intra-block tensor; the standard
-    FLOPs-for-HBM trade for deep stacks).
+    jax.checkpoint — except its attend: two (b, s, e)-sized activations
+    per layer are kept instead of every intra-block tensor, the block's
+    input and its attention kernel's output (with the log-sum-exp, a
+    head's row a position), so the backward pass replays the
+    projections and the MLP but never the forward kernel, the costliest
+    value of a block per byte kept. Where no kernel is taken — the XLA
+    twins, ring, ulysses — only the input is kept and the whole block is
+    replayed. The standard FLOPs-for-HBM trade for deep stacks; a traced
+    step says what it keeps in a ``remat.plan`` span).
 
     Options of the same block (each off by default; any of them takes
     the grouped block, ``_block_fn``'s second body):
@@ -2687,6 +2693,7 @@ class TransformerStackLayer(Layer):
         h = inputs[0].reshape(b, s, e).astype(dt)
         mesh = ctx.mesh
         pipe = mesh.shape.get("pipe", 1) if mesh is not None else 1
+        from .obs import trace
         from .ops import flash_attention as fa
         use_flash = fa.resolve_impl(self.attn_impl, ctx.platform,
                                     s) == "pallas"
@@ -2694,7 +2701,8 @@ class TransformerStackLayer(Layer):
         # analytic hardware flops of the flash kernels XLA cannot count
         # (opaque custom_call AND a scan body it would count only once):
         # flash runs in every block unless seq sharding fell back to
-        # ring; remat replays each block's forward kernel in the bwd
+        # ring; under remat too the forward kernel runs once a block
+        # (its output and log-sum-exp are kept, below)
         seq_axis = getattr(ctx, "seq_axis", None)
         seq_sharded = (pipe == 1 and mesh is not None
                        and seq_axis is not None
@@ -2711,21 +2719,31 @@ class TransformerStackLayer(Layer):
             fhw, bhw = fa.analytic_flops(b, self.nhead, s,
                                          e // self.nhead,
                                          bool(self.causal))
-            bwd_hw = bhw + (fhw if self.remat else 0.0)
             ctx.add_pallas_flops(
                 "flash_attention", fhw * self.nlayer,
-                bwd_hw * self.nlayer if ctx.train else 0.0, interp)
+                bhw * self.nlayer if ctx.train else 0.0, interp)
         # the pipeline path reshards x to P(data) in its shard_map
         # in_specs, so only the scan path runs seq-parallel attends
         block = self._block_fn(dt, interpret=interp,
                                mesh=None if pipe > 1 else mesh,
                                seq_axis=getattr(ctx, "seq_axis", None),
                                use_flash=use_flash)
-        if self.remat:
-            block = jax.checkpoint(block)
         depth = self.nlayer
-        if pipe == 1:
-            folded = self._fold_norms(params, dt)
+        folded = self._fold_norms(params, dt)
+        if self.remat:
+            # replayed in the backward pass except the attend: the
+            # forward kernel's two results are kept by name (the class
+            # docstring), so it runs once a block
+            block = jax.checkpoint(
+                block, policy=jax.checkpoint_policies.save_only_these_names(
+                    *fa.KEPT))
+            with trace.span("remat.plan", "kernel") as sp:
+                if sp is not trace.NOOP_SPAN:
+                    one = jax.tree.map(lambda v: jax.ShapeDtypeStruct(
+                        v.shape[1:], v.dtype), folded)
+                    sp.note(layer=ctx.layer_index, blocks=depth,
+                            kept=",".join(fa.KEPT),
+                            kept_bytes=depth * fa.kept_bytes(block, one, h))
         if self.dense_first:
             # layer 0, with the dense MLP's leaves; the scan (or the
             # unrolled loop) takes the rest, whose expert leaves are
@@ -2750,7 +2768,6 @@ class TransformerStackLayer(Layer):
                     "via model_parallel instead")
             from .ops import pipeline
             nmb = self.n_microbatch or pipe
-            folded = self._fold_norms(params, dt)
             cast = {k: v.astype(dt) if v.ndim > 2 else v
                     for k, v in folded.items()}
             h = pipeline.sharded_pipeline(

@@ -36,9 +36,48 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
 NEG_INF = -1e30
+
+# The names under which every forward rule below hands out its attend's
+# output and log-sum-exp: ``jax.checkpoint(...,
+# policy=save_only_these_names(*KEPT))`` (``transformer_stack`` under
+# ``remat = 1``) keeps the two and replays the rest of its block, so the
+# forward kernel runs once a block. They are the costliest values of a
+# block per byte kept, and as wide as the boundary activation remat
+# already keeps.
+KEPT = ("attn_out", "attn_lse")
+
+
+def _kept(o, lse):
+    """A forward kernel's two results under their ``KEPT`` names. What
+    comes back is what goes into the primal output AND the residuals, so
+    nothing downstream holds an unnamed copy. Outside a ``jax.checkpoint``
+    a name is an identity that lowers to nothing."""
+    return checkpoint_name(o, KEPT[0]), checkpoint_name(lse, KEPT[1])
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr``, those of the jaxprs it holds (a
+    ``custom_vjp``'s, a ``jit``'s, a ``shard_map``'s) included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def kept_bytes(fn, *args) -> int:
+    """Bytes of the values one call of ``fn(*args)`` (arrays or their
+    shapes) hands out under the ``KEPT`` names: what a ``jax.checkpoint``
+    keeping those names holds of it beside its input. 0 where no kernel
+    of this file is taken. Traces ``fn`` once more: for a span, not for
+    a step's path."""
+    return sum(v.aval.size * v.aval.dtype.itemsize
+               for eqn in _eqns(jax.make_jaxpr(fn)(*args).jaxpr)
+               if eqn.primitive.name == "name" and eqn.params["name"] in KEPT
+               for v in eqn.outvars)
 
 
 def _named_call(name, kernel, **kw):
@@ -668,7 +707,7 @@ def _flash_flat_fwd(qkv, nhead, causal, scale, interpret):
         raise ValueError(
             "flash_attention_flat: unsupported shape s=%d h=%d d=%d "
             "(callers must consult supports_flat)" % (s, h, d))
-    o, lse = _named_call(
+    o, lse = _kept(*_named_call(
         "flash_fwd",
         functools.partial(_flat_fwd_kernel, scale=scale, causal=causal,
                           s=s, h=h, d=d, g=g),
@@ -683,7 +722,7 @@ def _flash_flat_fwd(qkv, nhead, causal, scale, interpret):
             jax.ShapeDtypeStruct((b, h, s), jnp.float32),
         ],
         interpret=interpret,
-    )(qkv)
+    )(qkv))
     return o, (qkv, o, lse)
 
 
@@ -1119,8 +1158,8 @@ def _flash_flatb_fwd(qkv, nhead, causal, scale, interpret):
     from ..obs import trace
     plan, mark = _flatb_plan("fwd", qkv, nhead)
     with trace.span("flash.plan", "kernel", mark):
-        o, lse5 = _flatb_fwd_call(qkv, nhead, causal, scale, interpret,
-                                  plan)
+        o, lse5 = _kept(*_flatb_fwd_call(qkv, nhead, causal, scale,
+                                         interpret, plan))
     return o, (qkv, o, lse5)
 
 
@@ -1642,7 +1681,8 @@ def _flash_gq_fwd(q, k, v, nkv, mask, block_len, scale, interpret, tile):
                   for x in (q * jnp.asarray(scale, q.dtype), k, v))
     with trace.span("flash.plan", "kernel",
                     _gq_mark("fwd", *dims, nkv, mask, block_len, plan)):
-        o, lse = _gq_fwd_call(qs, ks, vs, nkv, mask, n, shift, interpret)
+        o, lse = _kept(*_gq_fwd_call(qs, ks, vs, nkv, mask, n, shift,
+                                     interpret))
     return _gq_unpad(o, segs, L, n * T), (qs, ks, vs, o, lse)
 
 
@@ -2010,7 +2050,7 @@ def _flash_mla_fwd(qn, qr, kn, kr, v, nhead, scale, interpret, tile,
     ops = tuple(_gq_pad(x, 1, S, n * T)
                 for x in (qn * sc, qr * sc, kn, kr, v))
     with trace.span("mla.plan", "kernel", dict(plan, **dict(mark))):
-        o, lse = _mla_fwd_call(*ops, nhead, G, n, interpret)
+        o, lse = _kept(*_mla_fwd_call(*ops, nhead, G, n, interpret))
     return _gq_unpad(o, 1, S, n * T), ops + (o, lse)
 
 
@@ -2111,8 +2151,8 @@ def _flash_fwd(q, k, v, causal, scale, interpret):
     # kernels receive (see the chain-rule notes in them)
     q3 = _prep(q) * jnp.asarray(scale, q.dtype)
     k3, v3 = _prep(k), _prep(v)
-    o3, lse = _fwd_impl(q3, k3, v3, causal, block_q,
-                        block_k, interpret)
+    o3, lse = _kept(*_fwd_impl(q3, k3, v3, causal, block_q, block_k,
+                               interpret))
     out = o3.reshape(b, h, s, d)
     return out, (q3, k3, v3, o3, lse, out.shape)
 

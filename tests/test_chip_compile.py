@@ -59,12 +59,18 @@ def _compile(fn, one_chip, *shapes):
     return text
 
 
-def _kernel_names(text):
-    """The Mosaic kernels' HLO instruction names, numbers dropped: what
-    a device trace's ``XLA Ops`` events are called."""
+def _kernel_calls(text):
+    """{a Mosaic kernel's HLO instruction name, numbers dropped (what a
+    device trace's ``XLA Ops`` events are called): its calls}."""
+    import collections
     import re
-    return {re.sub(r"[.\d]+$", "", m.group(1)) for m in re.finditer(
-        r"^\s*(?:ROOT )?%?([\w.\-]+) = .*tpu_custom_call", text, re.M)}
+    return collections.Counter(
+        re.sub(r"[.\d]+$", "", m.group(1)) for m in re.finditer(
+            r"^\s*(?:ROOT )?%?([\w.\-]+) = .*tpu_custom_call", text, re.M))
+
+
+def _kernel_names(text):
+    return set(_kernel_calls(text))
 
 
 def _paged_shapes(B=8, seqs=5):
@@ -250,6 +256,51 @@ def test_flash_attention_mla_compiles(one_chip):
                         nh * dn, nh * dr, nh * dn, dr, nh * dv)))
     assert _kernel_names(text) == {"flash_mla_fwd", "flash_mla_dq",
                                    "flash_mla_dkv"}
+
+
+def test_mla_blocks_under_remat_run_their_forward_kernel_once(one_chip):
+    """The same cell's blocks as they train, ``remat = 1``: two layers of
+    the stack at the published widths (a dense MLP in the experts'
+    place), bfloat16, the gradient compiled. The backward pass replays a
+    block's projections and not its attend: ``flash_mla_fwd`` is called
+    once a block (three times in all with nothing kept by name: XLA
+    merges the last block's replay with the forward pass it follows, and
+    no other), and the ``remat.plan`` span counts what is kept for it: a
+    block's ``o``, (2, 4096, 32 x 128) bfloat16, and its log-sum-exp, a
+    float32 a head a position."""
+    from cxxnet_tpu import layers as L
+    from cxxnet_tpu.obs import trace as obs_trace
+    b, S, e, nh, dv, blocks = 2, 4096, 2048, 32, 128, 2
+    st = L.create_layer("transformer_stack", [
+        (k, str(v)) for k, v in dict(
+            nlayer=blocks, scan_unroll=blocks, nhead=nh, causal=1,
+            attn="mla", q_rank=1536, kv_rank=512, d_nope=128, d_rope=64,
+            d_v=dv, rope_theta=32000000, mlp_act="swiglu",
+            nhidden_mlp=768, remat=1).items()])
+    st.infer_shape([(b, 1, S, e)])
+
+    def loss(p, x):
+        ctx = L.ApplyContext(train=True, compute_dtype=jnp.bfloat16,
+                             platform="tpu")
+        return st.apply(p, [x], ctx)[0].sum()
+
+    on = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    shapes = jax.tree.map(on, (
+        jax.eval_shape(st.init_params, jax.random.PRNGKey(0)),
+        jax.ShapeDtypeStruct((b, 1, S, e), jnp.float32)))
+    tr = obs_trace.start()
+    try:
+        text = jax.jit(jax.grad(loss)).lower(*shapes).compile().as_text()
+        (plan,) = [ev["args"] for ev in tr.trace_events()
+                   if ev.get("name") == "remat.plan"]
+    finally:
+        obs_trace.stop()
+    assert _kernel_calls(text) == {
+        "flash_mla_fwd": blocks, "flash_mla_dq": blocks,
+        "flash_mla_dkv": blocks}
+    assert plan["blocks"] == blocks and plan["kept"] == "attn_out,attn_lse"
+    assert plan["kept_bytes"] / blocks \
+        == b * S * nh * dv * 2 + b * nh * S * 4 == 68157440
 
 
 def test_routed_layer_with_sigmoid_bias_and_shared_expert_compiles(
