@@ -1973,12 +1973,36 @@ class TransformerStackLayer(Layer):
     outside the scan; the expert leaves are then ``nlayer - 1`` deep.
     ``raw_out = 1``: a second output node, the residual stream before
     the final norm (what an ``mtp`` layer reads).
+
+    ``attn_sparse = dsa`` (learned sparse attention: DeepSeek-V3.2-Exp's
+    lightning indexer over the grouped-query heads, ``attn_mask =
+    causal``): beside the main heads an indexer reads the block's normed
+    input DETACHED: ``idx_heads`` query heads of ``idx_dim`` (tag
+    ``wiq``), one key head (``wik``) under a LayerNorm (``iknorm``: its
+    gain's row, then its bias's), both rotated over all their dims
+    (``rope_theta``, the first position stream), and a weight a head
+    (``wiw``, times ``idx_heads^-1/2 idx_dim^-1/2``); query t scores the
+    causal keys ``I[t, s] = sum_j w[t, j] relu(qI[t, j] . kI[s])``,
+    keeps the ``idx_topk`` largest (ties to the lower index; all where
+    it has no more) and the main heads attend over those alone
+    (``ops.dsa_attention``: kernels where a head is whole lane tiles,
+    the dense twin elsewhere). The indexer learns from ``idx_loss``
+    times the mean over positions, summed over the layers, of ``KL(p_t
+    || softmax over the kept keys of I[t, .])``, ``p_t`` the heads' mean
+    probability, detached: it joins the step's loss and rides out as the
+    stat ``dsa_index_loss`` a layer, beside the counters ``dsa_pairs``
+    (pairs the attend kept) and ``dsa_pairs_causal``. The indexer's four
+    leaves take their gradient from that term alone, every other leaf
+    none of it. ``mrope_section = t,h,w``: the rotation's ``head_dim /
+    2`` frequency pairs split over three position streams, read from an
+    optional second input node (batch,1,seq,3); without it the input is
+    text, the three streams are equal and the rotation is the plain one.
     """
     has_params = True
     param_tags = ("wqkv", "wo", "w1", "w2", "norm1", "norm2", "gate",
                   "qnorm", "knorm", "normf", "wqa", "qanorm", "wqb",
                   "wkva", "kvnorm", "wkvb", "gbias", "ws1", "ws2", "w1d",
-                  "w2d")
+                  "w2d", "wiq", "wik", "iknorm", "wiw")
     # the leaves of the routed MLP (``nlayer - dense_first`` deep)
     _EXPERT_TAGS = ("gate", "gbias", "w1", "w2", "ws1", "ws2")
 
@@ -2020,21 +2044,29 @@ class TransformerStackLayer(Layer):
         self.dense_first = 0
         self.nhidden_dense = 0
         self.raw_out = 0
+        self.attn_sparse = "none"
+        self.idx_heads = self.idx_dim = self.idx_topk = 0
+        self.idx_loss = 1.0
+        self.mrope_section = ()
 
     _INT_KEYS = ("nkvhead", "head_dim", "qk_norm", "final_norm",
                  "block_len", "expert_first", "expert_held",
                  "moe_norm_topk", "q_rank", "kv_rank", "d_nope", "d_rope",
                  "d_v", "moe_bias", "moe_shared", "dense_first",
-                 "nhidden_dense", "raw_out")
-    _FLOAT_KEYS = ("rope_theta", "moe_scale", "moe_load")
+                 "nhidden_dense", "raw_out", "idx_heads", "idx_dim",
+                 "idx_topk")
+    _FLOAT_KEYS = ("rope_theta", "moe_scale", "moe_load", "idx_loss")
     _CHOICES = {"mlp_act": ("relu", "swiglu"),
                 "attn_mask": ("full", "causal", "block_diffusion"),
                 "moe_dispatch": ("onehot", "sorted"),
                 "attn": ("mha", "mla"),
-                "moe_score": ("softmax", "sigmoid")}
+                "moe_score": ("softmax", "sigmoid"),
+                "attn_sparse": ("none", "dsa")}
 
     def set_param(self, name, val):
-        if name in self._INT_KEYS:
+        if name == "mrope_section":
+            self.mrope_section = tuple(int(x) for x in val.split(","))
+        elif name in self._INT_KEYS:
             setattr(self, name, int(val))
         elif name in self._FLOAT_KEYS:
             setattr(self, name, float(val))
@@ -2076,6 +2108,20 @@ class TransformerStackLayer(Layer):
         else:
             super().set_param(name, val)
 
+    def infer_shape(self, in_shapes):
+        if len(in_shapes) != 2 or not self.mrope_section:
+            return super().infer_shape(in_shapes)
+        # the second input: a position a stream (temporal, height, width)
+        n, _, s, _ = in_shapes[0]
+        if in_shapes[1] != (n, 1, s, 3):
+            raise ValueError(
+                "transformer_stack: mrope_section reads a second input "
+                "(batch,1,seq,3), a position a stream; got %s beside %s"
+                % (in_shapes[1], in_shapes[0]))
+        out = self._infer(in_shapes[:1])
+        self.in_shapes, self.out_shapes = list(in_shapes), out
+        return out
+
     def _infer(self, in_shapes):
         n, c, s, e = in_shapes[0]
         if c != 1:
@@ -2107,7 +2153,12 @@ class TransformerStackLayer(Layer):
             self.nkvhead or self.head_dim or self.qk_norm
             or self.rope_theta or self.final_norm or self.sorted
             or self.mlp_act != "relu" or self.mask == "block_diffusion"
-            or self.attn == "mla" or self.dense_first or self.raw_out)
+            or self.attn == "mla" or self.dense_first or self.raw_out
+            or self.dsa or self.mrope_section)
+
+    @property
+    def dsa(self) -> bool:
+        return self.attn_sparse == "dsa"
 
     def _check_grouped(self, s, e):
         """The grouped block's sizes, and what it does not do, said
@@ -2173,6 +2224,34 @@ class TransformerStackLayer(Layer):
                 or self.d_v:
             raise err("q_rank, kv_rank, d_nope, d_rope and d_v are "
                       "options of attn = mla")
+        if self.dsa:
+            if self.attn == "mla" or self.mask != "causal":
+                raise err("attn_sparse = dsa selects keys over the "
+                          "grouped-query heads under attn_mask = causal "
+                          "(not attn = %s, attn_mask = %s): the selection "
+                          "is by a causal query's own scores"
+                          % (self.attn, self.mask))
+            if not (self.idx_heads > 0 and self.idx_dim > 0
+                    and self.idx_topk > 0 and self.rope_theta) \
+                    or self.idx_dim % 2:
+                raise err("attn_sparse = dsa needs idx_heads, an even "
+                          "idx_dim, idx_topk and rope_theta")
+            if self.dense_first:
+                raise err("attn_sparse = dsa with dense_first = 1: the "
+                          "leading layer's KL term has no way out of the "
+                          "stack yet")
+        elif self.idx_heads or self.idx_dim or self.idx_topk:
+            raise err("idx_heads, idx_dim and idx_topk are options of "
+                      "attn_sparse = dsa")
+        if self.mrope_section:
+            if len(self.mrope_section) != 3 or not self.rope_theta \
+                    or sum(self.mrope_section) * 2 != self.hd \
+                    or self.attn == "mla":
+                raise err("mrope_section = t,h,w splits the %d frequency "
+                          "pairs of a rotated head of %d over three "
+                          "position streams (rope_theta; not attn = mla): "
+                          "got %s" % (self.hd // 2, self.hd,
+                                      self.mrope_section))
         if self.dense_first:
             if not self.sorted or self.nlayer < 2 \
                     or self.nhidden_dense <= 0:
@@ -2190,6 +2269,11 @@ class TransformerStackLayer(Layer):
         if not self.grouped:
             return ""
         what = [name for name, on in (
+            ("learned sparse attention (attn_sparse = dsa): the decode "
+             "would cache the index keys beside the kv pages and select "
+             "inside the paged attend", self.dsa),
+            ("three-stream rotary positions (mrope_section)",
+             self.mrope_section),
             ("latent attention (attn = mla): the decode would cache the "
              "latent and absorb the up-projections", self.attn == "mla"),
             ("a sigmoid router with a selection bias (moe_score, "
@@ -2277,6 +2361,16 @@ class TransformerStackLayer(Layer):
                 "wo": p.rand_init_weight(ks[1], (L, e, nq), nq, e)}
         out["norm1"] = jnp.ones((L, e), jnp.float32)
         out["norm2"] = jnp.ones((L, e), jnp.float32)
+        if self.dsa:
+            ih, idim = self.idx_heads, self.idx_dim
+            ki = jax.random.split(jax.random.fold_in(ks[0], 2), 3)
+            out["wiq"] = p.rand_init_weight(ki[0], (L, ih * idim, e), e,
+                                            ih * idim)
+            out["wik"] = p.rand_init_weight(ki[1], (L, idim, e), e, idim)
+            out["wiw"] = p.rand_init_weight(ki[2], (L, ih, e), e, ih)
+            # the index key's LayerNorm: its gain's row, then its bias's
+            out["iknorm"] = jnp.broadcast_to(
+                jnp.asarray([[1.0], [0.0]], jnp.float32), (L, 2, idim))
         if self.qk_norm:
             out["qnorm"] = jnp.ones((L, self.hd), jnp.float32)
             out["knorm"] = jnp.ones((L, self.hd), jnp.float32)
@@ -2336,6 +2430,16 @@ class TransformerStackLayer(Layer):
                 proj = 2.0 * n * s * e * (nq + 2 * nkv) \
                     + 2.0 * n * s * nq * e
                 attend = 4.0 * self._attend_pairs(s) * n * s * s * nq
+                if self.dsa:
+                    # the indexer's projections, its scores over every
+                    # causal pair, the attend over the pairs kept (the
+                    # KL term is the indexer's training, not counted)
+                    from .ops.dsa_attention import pairs_kept
+                    iw = self.idx_heads * self.idx_dim
+                    proj += 2.0 * n * s * e * (iw + self.idx_dim
+                                               + self.idx_heads)
+                    attend = n * (2.0 * iw * (s * (s + 1) // 2) + 4.0 * nq
+                                  * pairs_kept(s, self.idx_topk))
             wide = m * (3 if self.mlp_act == "swiglu" else 2)
             if self.sorted:     # this share's load: planned, or the mean
                 load = self.moe_load or (self.topk * self.held
@@ -2365,7 +2469,7 @@ class TransformerStackLayer(Layer):
         return fwd, 2.0 * fwd
 
     def _block_fn(self, dt, interpret=True, mesh=None, seq_axis=None,
-                  use_flash=False):
+                  use_flash=False, positions=None):
         from .ops import pallas_env
         from .ops import ring_attention as ra
         nh, causal = self.nhead, bool(self.causal)
@@ -2414,7 +2518,7 @@ class TransformerStackLayer(Layer):
 
         if self.grouped:
             return self._grouped_block(dt, interpret, mesh, seq_sharded,
-                                       use_flash, rmsnorm)
+                                       use_flash, rmsnorm, positions)
 
         def block(lp, h):
             b, s, e = h.shape
@@ -2470,12 +2574,16 @@ class TransformerStackLayer(Layer):
         return block
 
     def _grouped_block(self, dt, interpret, mesh, seq_sharded, use_flash,
-                       rmsnorm):
+                       rmsnorm, positions=None):
         """The block with the options of the class docstring: grouped
         heads of their own size, q/k norms, rotary positions, a gated
-        MLP or the sorted expert dispatch, a scheduled mask. -> block(lp,
-        h) -> (h, aux), aux the routed layer's counters
-        (``ops.moe_sorted.STATS``) or 0."""
+        MLP or the sorted expert dispatch, a scheduled mask or a learned
+        selection. -> block(lp, h) -> (h, aux), aux the routed layer's
+        counters (``ops.moe_sorted.STATS``) or 0; under ``attn_sparse =
+        dsa`` a dict of that (``moe``), the layer's KL term summed
+        (``dsa_kl``) and the pairs its attend kept (``dsa_pairs``).
+        ``positions`` (b, s, 3): the three position streams of
+        ``mrope_section`` (None: text)."""
         from jax.sharding import PartitionSpec as P
         from .ops import flash_attention as fa
         from .ops import moe_sorted as ms
@@ -2487,9 +2595,11 @@ class TransformerStackLayer(Layer):
         if seq_sharded:
             raise ValueError(
                 "transformer_stack: the grouped block (rotary positions, "
-                "grouped heads, attn_mask = block_diffusion) does not run "
-                "under sequence sharding: ring and ulysses attention "
-                "know neither the mask nor shared kv heads")
+                "grouped heads, attn_mask = block_diffusion, attn_sparse = "
+                "dsa) does not run under sequence sharding: ring and "
+                "ulysses attention know neither the mask nor shared kv "
+                "heads, and a learned selection reads every causal key of "
+                "a row")
         if self.sorted and mesh is not None and any(
                 n > 1 for ax, n in mesh.shape.items() if ax != "data"):
             raise ValueError(
@@ -2508,10 +2618,18 @@ class TransformerStackLayer(Layer):
         fused = use_flash and d % 128 == 0 and bool(
             self.qk_norm or self.rope_theta)
 
+        # three position streams that differ: the plain path with the
+        # angles of each (text takes the tables, and the kernels)
+        angles = None if positions is None else qp.rope_angles(
+            positions, d, float(self.rope_theta), self.mrope_section)
+
         def prepare(lp, qkv):
             """qkv -> (q, k, v), q and k normed and rotated."""
             gains = (lp["qnorm"], lp["knorm"]) if self.qk_norm \
                 else (None, None)
+            if angles is not None:
+                return qp.qk_prep_plain(qkv, *gains, nh, nkv, angles=angles,
+                                        **prep)
             if not fused:
                 return qp.qk_prep_plain(qkv, *gains, nh, nkv, **prep)
             return pallas_env.per_shard(
@@ -2537,6 +2655,9 @@ class TransformerStackLayer(Layer):
         if self.attn == "mla":
             attention = self._mla_attention(dt, interpret, mesh, use_flash,
                                             rmsnorm)
+        if self.dsa:
+            attention = self._dsa_attention(dt, interpret, mesh, use_flash,
+                                            rmsnorm, prepare, positions)
 
         m = self.nhidden_mlp
 
@@ -2574,6 +2695,8 @@ class TransformerStackLayer(Layer):
 
         def block(lp, h):
             h = attention(lp, h)
+            if self.dsa:
+                h, (kl, kept) = h
             if "w1d" in lp:
                 # the leading dense layer (dense_first): a gated MLP of
                 # its own width where the others route
@@ -2585,8 +2708,71 @@ class TransformerStackLayer(Layer):
             # gain is applied, not folded (_fold_norms)
             y, aux = mlp(lp, rmsnorm(h, lp["norm2"] if self.moe
                                      else None))
+            if self.dsa:
+                aux = {"moe": jnp.asarray(aux, jnp.float32),
+                       "dsa_kl": kl, "dsa_pairs": kept}
             return h + y, aux
         return block
+
+    def _dsa_attention(self, dt, interpret, mesh, use_flash, rmsnorm,
+                       prepare, positions):
+        """-> attention(lp, h) -> (h + the learned sparse attention, (the
+        layer's KL term summed over rows and positions, the pairs its
+        attend kept)) (``attn_sparse = dsa``), on ``_fold_norms``' leaves
+        (``norm1``'s gain folded, detached, into the indexer's three
+        projections)."""
+        from .ops import dsa_attention as da
+        from .ops import pallas_env
+        from .ops import qk_prep as qp
+        nh, nkv, d = self.nhead, self.nkv, self.hd
+        ih, idim, topk = self.idx_heads, self.idx_dim, self.idx_topk
+        theta = float(self.rope_theta)
+        rows = pallas_env.rows_spec(mesh)
+        # the indexer turns by the first (temporal) stream alone
+        turn = None if positions is None else qp.rope_angles(
+            positions[..., 0], idim, theta)
+
+        def attend(*ops):
+            S = ops[0].shape[1]
+            if not use_flash:
+                return da.dsa_attention_dense(*ops, nkv, topk)
+            if not da.dsa_supported(S, nh * d, nkv * d, nkv, ih * idim,
+                                    ih):
+                raise ValueError(
+                    "transformer_stack: attn_sparse = dsa on the kernels "
+                    "(attn_impl = pallas, or auto on a TPU) takes heads "
+                    "of whole 128 lanes (head_dim %d), index heads that "
+                    "fill whole lane tiles (idx_dim %d, idx_heads %d) and "
+                    "whole tiles of positions (%d); attn_impl = xla takes "
+                    "the dense twin, which holds a row's every pair"
+                    % (d, idim, ih, S))
+            return pallas_env.per_shard(
+                mesh, lambda *ops: da.flash_attention_dsa(
+                    *ops, nkv, topk, interpret=interpret),
+                (rows,) * 6, (rows,) * 3)(*ops)
+
+        def attention(lp, h):
+            b, s, _ = h.shape
+            x = rmsnorm(h, None)          # gain folded into wqkv
+            qkv = jnp.einsum("bse,fe->bsf", x, lp["wqkv"].astype(dt))
+            xb = jax.lax.stop_gradient(x)
+            proj = lambda w, **kw: jnp.einsum("bse,fe->bsf", xb,
+                                              lp[w].astype(dt), **kw)
+            qi = qp.rotate_half(proj("wiq").reshape(b, s, ih, idim), turn,
+                                theta).astype(dt).reshape(b, s, ih * idim)
+            kf = proj("wik").astype(jnp.float32)
+            mu = jnp.mean(kf, -1, keepdims=True)
+            var = jnp.mean(jnp.square(kf - mu), -1, keepdims=True)
+            kf = (kf - mu) * jax.lax.rsqrt(var + 1e-6) \
+                * lp["iknorm"][0] + lp["iknorm"][1]
+            ki = qp.rotate_half(kf[:, :, None], turn, theta
+                                )[:, :, 0].astype(dt)
+            wi = proj("wiw", preferred_element_type=jnp.float32) \
+                * (ih ** -0.5 * idim ** -0.5)
+            att, kl, kept = attend(*prepare(lp, qkv), qi, ki, wi)
+            return (h + jnp.einsum("bsf,ef->bse", att, lp["wo"].astype(dt)),
+                    (jnp.sum(kl), jnp.sum(kept)))
+        return attention
 
     def _mla_attention(self, dt, interpret, mesh, use_flash, rmsnorm):
         """-> attention(lp, h) -> h + latent attention (``attn = mla``),
@@ -2669,6 +2855,12 @@ class TransformerStackLayer(Layer):
         else:
             out["wqkv"] = (params["wqkv"]
                            * params["norm1"][:, None, :]).astype(dt)
+        if self.dsa:
+            # the indexer reads the normed input detached: the gain it
+            # folds in is a constant to it
+            g1 = jax.lax.stop_gradient(params["norm1"])[:, None, :]
+            for k in ("wiq", "wik", "wiw"):
+                out[k] = (params[k] * g1).astype(dt)
         if not self.moe:
             out["w1"] = (params["w1"]
                          * params["norm2"][:, None, :]).astype(dt)
@@ -2710,10 +2902,11 @@ class TransformerStackLayer(Layer):
         if self.grouped and pipe > 1:
             raise ValueError(
                 "transformer_stack: the grouped block (rotary positions, "
-                "grouped heads, attn_mask = block_diffusion, the sorted "
-                "dispatch) does not run under pipeline_parallel: the "
-                "stages' shard_map hands a block neither its mask's "
-                "schedule nor its counters")
+                "grouped heads, attn_mask = block_diffusion, attn_sparse = "
+                "dsa, the sorted dispatch) does not run under "
+                "pipeline_parallel: the stages' shard_map hands a block "
+                "neither its mask's schedule nor its counters nor its "
+                "KL term")
         if use_flash and not self.grouped \
                 and (not seq_sharded or self.attn_impl == "pallas"):
             fhw, bhw = fa.analytic_flops(b, self.nhead, s,
@@ -2724,10 +2917,12 @@ class TransformerStackLayer(Layer):
                 bhw * self.nlayer if ctx.train else 0.0, interp)
         # the pipeline path reshards x to P(data) in its shard_map
         # in_specs, so only the scan path runs seq-parallel attends
+        positions = inputs[1].reshape(b, s, 3) if len(inputs) > 1 \
+            and self.mrope_section else None
         block = self._block_fn(dt, interpret=interp,
                                mesh=None if pipe > 1 else mesh,
                                seq_axis=getattr(ctx, "seq_axis", None),
-                               use_flash=use_flash)
+                               use_flash=use_flash, positions=positions)
         depth = self.nlayer
         folded = self._fold_norms(params, dt)
         if self.remat:
@@ -2788,18 +2983,30 @@ class TransformerStackLayer(Layer):
             for i in range(depth):
                 lp = jax.tree.map(lambda v, i=i: v[i], folded)
                 h, a = block(lp, h)
-                auxs.append(jnp.asarray(a, jnp.float32))
-            auxs = jnp.stack(auxs)
+                auxs.append(a if self.dsa else jnp.asarray(a, jnp.float32))
+            auxs = jax.tree.map(lambda *a: jnp.stack(a), *auxs)
         else:
             def body(hh, lp):
                 h2, a = block(lp, hh)
-                return h2, jnp.asarray(a, jnp.float32)
+                return h2, a if self.dsa else jnp.asarray(a, jnp.float32)
             h, auxs = jax.lax.scan(
                 body, h, folded,
                 unroll=max(1, min(self.scan_unroll, depth)))
         # a layer's aux, one a layer: the one-hot dispatch's load-balance
         # loss, or the sorted dispatch's counters (the pipeline branch
         # rejects moe above)
+        if self.dsa:
+            # the indexer's KL term: into the step's loss (the mean over
+            # positions, summed over the layers, idx_loss times) and out
+            # as a stat a layer, beside the counters of the selection
+            kl, kept = auxs["dsa_kl"], auxs["dsa_pairs"]
+            auxs = auxs["moe"]
+            ctx.losses.append(self.idx_loss * jnp.sum(kl) / (
+                ctx.batch_size * ctx.update_period * s))
+            ctx.stats[(ctx.layer_index, "dsa_index_loss")] = kl / (b * s)
+            ctx.stats[(ctx.layer_index, "dsa_pairs")] = kept
+            ctx.stats[(ctx.layer_index, "dsa_pairs_causal")] = jnp.full(
+                kept.shape, b * (s * (s + 1) // 2), kept.dtype)
         if pipe == 1 and self.moe and self.sorted:
             from .ops.moe_sorted import STATS
             for j, name in enumerate(STATS):

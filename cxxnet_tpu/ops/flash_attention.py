@@ -1357,8 +1357,11 @@ def gq_pairs(mask: str, n: int):
             out += [(n + j, n + t) + full for t in range(j)]
             out.append((n + j, n + j, -_GQ_OPEN, 0))
     else:
-        raise ValueError("mask must be causal|block_diffusion, not %r"
-                         % mask)
+        raise ValueError(
+            "mask must be causal|block_diffusion, not %r: a static "
+            "schedule lists tile pairs on the host; a mask that is data "
+            "(a learned selection) is ops/dsa_attention.py's, which walks "
+            "the causal schedule and masks inside a tile" % mask)
     return out
 
 

@@ -359,13 +359,46 @@ def _qk_prep_bwd(plan, res, grads):
 _qk_prep.defvjp(_qk_prep_fwd, _qk_prep_bwd)
 
 
+def rope_angles(positions, d: int, theta: float, sections=None):
+    """Rotation angles (b, S, d / 2) float32 of ``positions`` (b, S) or,
+    three streams (temporal, height, width), (b, S, 3): frequency pair i
+    reads the stream ``sections`` gives it (the first ``sections[0]``
+    pairs the temporal stream, the next ``sections[1]`` the height, the
+    rest the width); ``sections`` None: every pair the first stream."""
+    inv = (theta ** (-np.arange(0, d, 2) / float(d))).astype(np.float32)
+    pos = positions.astype(jnp.float32)
+    if pos.ndim == 2:
+        return pos[..., None] * inv
+    stream = np.zeros(d // 2, np.int32) if sections is None \
+        else np.repeat(np.arange(3), sections)
+    return pos[..., stream] * inv
+
+
+def rotate_half(x, angles=None, theta: float = 0.0):
+    """(b, S, heads, d) rotated, rotate-half pairing, in float32:
+    by ``angles`` (b, S, d / 2), or by the positions 0..S-1 at base
+    ``theta`` (the tables of the kernels)."""
+    d = x.shape[-1]
+    if angles is None:
+        cos, sin = (t[None, :, None] for t in _tables(x.shape[1], d,
+                                                      float(theta)))
+    else:
+        cos, sin = (jnp.concatenate([f(angles)] * 2, -1)[:, :, None]
+                    for f in (jnp.cos, jnp.sin))
+    x = x.astype(jnp.float32)
+    turned = jnp.concatenate([-x[..., d // 2:], x[..., :d // 2]], -1)
+    return x * cos + turned * sin
+
+
 def qk_prep_plain(qkv, qnorm, knorm, heads: int, kv_heads: int, *,
-                  rope_theta: float = 0.0, segments: int = 1):
+                  rope_theta: float = 0.0, segments: int = 1, angles=None):
     """``qk_prep``'s result in plain XLA on (b, S, heads, d): the path
     off the TPU and for a head size that is not whole lane tiles, and
     the kernels' twin in the tests. It rounds the normed value to
     ``qkv``'s dtype before the gain and once more after the rotation,
-    where the kernels round once."""
+    where the kernels round once. ``angles`` (b, S, d / 2): the
+    rotation's own angles a position (``rope_angles``: positions that
+    are not 0..S-1, or three streams of them) in place of the tables."""
     b, S, W = qkv.shape
     d = W // (heads + 2 * kv_heads)
     dt = qkv.dtype
@@ -378,7 +411,9 @@ def qk_prep_plain(qkv, qnorm, knorm, heads: int, kv_heads: int, *,
                           keepdims=True)
             x = (x.astype(jnp.float32)
                  * lax.rsqrt(ms + EPS)).astype(dt) * g.astype(dt)
-        if rope_theta:
+        if rope_theta and angles is not None:
+            x = rotate_half(x, angles)
+        elif rope_theta:
             cos, sin = (np.tile(t, (segments, 1))[None, :, None]
                         for t in _tables(S // segments, d,
                                          float(rope_theta)))

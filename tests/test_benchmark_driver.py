@@ -40,6 +40,12 @@ NODES = [
     "benchmark/tests/test_joyai.py::test_rehearsal_traced",
     "benchmark/tests/test_joyai.py"
     "::test_the_readers_read_what_the_program_leaves",
+    "benchmark/tests/test_keye.py::test_rehearsal_untraced",
+    "benchmark/tests/test_keye.py::test_rehearsal_traced",
+    "benchmark/tests/test_keye.py"
+    "::test_the_readers_read_what_the_program_leaves",
+    "benchmark/tests/test_keye.py"
+    "::test_the_program_counts_what_the_reader_reads",
 ]
 
 

@@ -235,6 +235,31 @@ def test_moe_sorted_compiles(one_chip):
         assert _kernel_names(text) == {"moe_gmm", "moe_tgmm"}
 
 
+def test_dsa_attention_compiles(one_chip):
+    """The benchmark's cell train.keye_vl2_30b_a3b.seq16384: 1 row of
+    16,384 positions, 32 q heads on 4 kv heads of d 128 under an indexer
+    of 16 heads of 64 that keeps 2,048 keys a query. The selection (a
+    block of 128 queries' scores over every causal key in VMEM: 8 MB,
+    beside the keys' 8 MB twice), the three attend kernels with the kv
+    heads innermost and the KL kernel with its resident ``d kI``: each
+    under the name its roofline reader matches."""
+    from cxxnet_tpu.ops import dsa_attention as da
+    b, S, nh, nkv, d, ih, idim, topk = 1, 16384, 32, 4, 128, 16, 64, 2048
+    assert da.dsa_supported(S, nh * d, nkv * d, nkv, ih * idim, ih)
+
+    def loss(*ops):
+        o, kl, _ = da.flash_attention_dsa(*ops, nkv, topk, interpret=False)
+        return o.astype(jnp.float32).sum() + kl.sum()
+
+    text = _compile(jax.grad(loss, argnums=tuple(range(6))), one_chip,
+                    *(((b, S, w), jnp.bfloat16) for w in (
+                        nh * d, nkv * d, nkv * d, ih * idim, idim)),
+                    ((b, S, ih), jnp.float32))
+    assert _kernel_names(text) == {"dsa_select", "flash_dsa_fwd",
+                                   "flash_dsa_dq", "flash_dsa_dkv",
+                                   "dsa_kl"}
+
+
 def test_flash_attention_mla_compiles(one_chip):
     """The benchmark's cell train.joyai_llm_flash.seq4096: 2 rows of
     4,096 positions, 32 heads of 128 nope + 64 rope query-key dims and
