@@ -1986,7 +1986,13 @@ class TransformerStackLayer(Layer):
     keeps the ``idx_topk`` largest (ties to the lower index; all where
     it has no more) and the main heads attend over those alone
     (``ops.dsa_attention``: kernels where a head is whole lane tiles,
-    the dense twin elsewhere). The indexer learns from ``idx_loss``
+    the dense twin elsewhere). On the kernels the mask is decided once a
+    layer, by ``dsa_select``, which writes the kept pairs as a bit a
+    pair, and the attend's three passes and the KL term read it: the one
+    array that grows as the square of the row, ``seq^2 / 8`` bytes a row
+    and layer (33.5 MB at 16,384 positions, 134 MB at 32,768), held from
+    a layer's forward to its backward pass (a layer at a time under
+    ``remat = 1``). The indexer learns from ``idx_loss``
     times the mean over positions, summed over the layers, of ``KL(p_t
     || softmax over the kept keys of I[t, .])``, ``p_t`` the heads' mean
     probability, detached: it joins the step's loss and rides out as the

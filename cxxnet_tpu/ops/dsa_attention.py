@@ -12,33 +12,44 @@ attend into the indexer.
 
 Which keys a query sees is data, decided on the device a step: the mask
 cannot be a host-side schedule as ``flash_attention.gq_pairs`` lists
-one. Four Pallas kernels walk the causal tile pairs of that schedule
-instead and mask inside a tile, from two numbers a query row that a
-fifth kernel finds first:
+one. It is decided once, by the first of five Pallas kernels, and read
+by the four that walk the causal tile pairs of that schedule:
 
 * ``dsa_select``: a block of queries against every causal key, the
   scores held in VMEM as order-preserving integers (keys on sublanes);
   the ``topk``-th largest by bisection on the integer's bits (32 counts),
   then the index up to which keys tied with it are kept (one count a bit
-  of the index): ``tau``, ``sigma``; and the log-sum-exp of the kept
-  scores, which the KL term's softmax needs. Exact; no sort, and no
-  ``(T, T)`` array in HBM.
+  of the index); the log-sum-exp of the kept scores, which the KL term's
+  softmax needs; and one more pass that writes the kept pairs out as
+  ``words``, int32 bit planes by key tile of the attend: bit ``j`` of
+  ``words[b, g T + r, q]`` is 1 where query ``q`` keeps key ``(32 g +
+  j) T + r`` (``T`` the attend's tile, ``g`` a group of 32 key tiles;
+  keys stay on sublanes and queries on lanes, so packing is an or and a
+  shift by a scalar). Exact; no sort.
 * ``flash_dsa_fwd`` / ``flash_dsa_dq`` / ``flash_dsa_dkv``: the
   grouped-query flash kernels with the kv heads as the grid's innermost
-  axis, so that a tile pair's mask ``(I > tau) | (I == tau & s <=
-  sigma)`` is computed once, from the tile's own ``qI``, ``kI``, ``w``,
-  and serves every head. The forward kernel also counts the pairs it
-  kept (the ``dsa_pairs`` counter).
+  axis, so that a tile pair's mask, its bit of the pair's ``(T, T)``
+  block of ``words``, is unpacked once and serves every head. The
+  forward kernel also counts the pairs it kept (the ``dsa_pairs``
+  counter).
 * ``dsa_kl``: the KL term and its gradient into ``qI``, ``kI`` and ``w``
   in one pass (the gradient does not depend on the cotangent but for
   its factor): the heads' probabilities summed over the kv axis of the
   grid, then the term's rows, ``dI = pi - p`` and its three products.
-  ``d kI`` sums over queries: it is a resident output block.
+  It computes a tile's ``I`` again (it needs the scores themselves) and
+  takes the kept pairs from ``words`` as the attend does. ``d kI`` sums
+  over queries: it is a resident output block.
 
-Every kernel computes ``I`` by the same function in the same order, so
-the five agree to the bit on which keys are kept. A first version: every
-causal tile is visited (with random weights every tile holds kept keys);
-the device runs the dense causal FLOPs.
+One kernel decides and four read, so the five agree on which keys are
+kept by construction, in interpret mode too. ``words`` is the one array
+in HBM that grows as the square of the row: ``S^2 / 8`` bytes a row and
+layer (33.5 MB at 16,384 positions, 134 MB at 32,768, 537 MB at 65,536;
+a byte a pair would be eight times that), a residual of the backward
+pass, so under ``remat = 0`` every layer's lives until its backward and
+under ``remat = 1`` it is written again with ``dsa_select``'s replay and
+lives a layer at a time. A first version: every causal tile is visited
+(with random weights every tile holds kept keys); the device runs the
+dense causal FLOPs.
 
 ``dsa_attention_dense`` is the same function in plain XLA by a dense
 mask: the path off the TPU and the kernels' twin in the tests.
@@ -201,23 +212,24 @@ def _index_tile(kr, qi_ref, wt_ref, IH, P):
     return acc
 
 
-def _keep_tile(I, tau_ref, sig_ref, q0, k0):
-    """bool (keys, queries): the pairs of a tile the selection keeps;
-    ``q0``, ``k0`` the tile's first query and key."""
-    kidx = k0 + lax.broadcasted_iota(jnp.int32, I.shape, 0)
-    qidx = q0 + lax.broadcasted_iota(jnp.int32, I.shape, 1)
-    tau = tau_ref[0]
-    return ((I > tau) | ((I == tau) & (kidx <= sig_ref[0]))) \
-        & (kidx <= qidx)
+def mask_rows(S, T):
+    """Rows of ``words`` (a row of them a query): a plane of ``T`` a
+    group of 32 key tiles."""
+    return -(-(S // T) // 32) * T
 
 
-def _mask_to(bias_s, cnt_s, si, qt_ref, kt_ref, first, kr_ref, qi_ref,
-             wt_ref, tau_ref, sig_ref, IH, P, T):
+def _plane(words_ref, kt):
+    """bool (keys, queries): the pairs of key tile ``kt`` the selection
+    kept, its bit of the tile pair's block of ``dsa_select``'s
+    ``words``."""
+    return (lax.shift_right_logical(words_ref[0], kt % 32) & 1) != 0
+
+
+def _mask_to(bias_s, cnt_s, words_ref, kt, first):
     """The tile pair's mask as an additive bias (0 | NEG_INF) into
     ``bias_s``; ``cnt_s`` (if any) sums the pairs kept a query over the
     run."""
-    I = _index_tile(kr_ref[0], qi_ref, wt_ref, IH, P)
-    keep = _keep_tile(I, tau_ref, sig_ref, qt_ref[si] * T, kt_ref[si] * T)
+    keep = _plane(words_ref, kt)
     bias_s[...] = jnp.where(keep, 0.0, NEG_INF)
     if cnt_s is not None:
         c = jnp.sum(keep.astype(jnp.float32), axis=0, keepdims=True)
@@ -234,17 +246,14 @@ def _store_head(ref, h, nkv, width, value):
 
 
 def _dsa_fwd_kernel(qt_ref, kt_ref, lo_ref, hi_ref, first_ref, last_ref,
-                    q_ref, k_ref, v_ref, qi_ref, kr_ref, wt_ref, tau_ref,
-                    sig_ref, o_ref, lse_ref, cnt_ref,
-                    vt_s, m_s, l_s, acc_s, bias_s, cnt_s,
-                    *, nkv, G, d, T, IH, P):
+                    q_ref, k_ref, v_ref, words_ref, o_ref, lse_ref, cnt_ref,
+                    vt_s, m_s, l_s, acc_s, bias_s, cnt_s, *, nkv, G, d, T):
     si, h = pl.program_id(1), pl.program_id(2)
     first, last = first_ref[si] == 1, last_ref[si] == 1
 
     @pl.when(h == 0)
     def _mask():
-        _mask_to(bias_s, cnt_s, si, qt_ref, kt_ref, first, kr_ref, qi_ref,
-                 wt_ref, tau_ref, sig_ref, IH, P, T)
+        _mask_to(bias_s, cnt_s, words_ref, kt_ref[si], first)
 
     @pl.when(first)
     def _init():
@@ -276,15 +285,14 @@ def _dsa_fwd_kernel(qt_ref, kt_ref, lo_ref, hi_ref, first_ref, last_ref,
 
 def _dsa_dq_kernel(qt_ref, kt_ref, lo_ref, hi_ref, first_ref, last_ref,
                    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   qi_ref, kr_ref, wt_ref, tau_ref, sig_ref, dq_ref,
-                   kt_s, acc_s, bias_s, *, nkv, G, d, T, IH, P, scale):
+                   words_ref, dq_ref, kt_s, acc_s, bias_s,
+                   *, nkv, G, d, T, scale):
     si, h = pl.program_id(1), pl.program_id(2)
     first, last = first_ref[si] == 1, last_ref[si] == 1
 
     @pl.when(h == 0)
     def _mask():
-        _mask_to(bias_s, None, si, qt_ref, kt_ref, first, kr_ref, qi_ref,
-                 wt_ref, tau_ref, sig_ref, IH, P, T)
+        _mask_to(bias_s, None, words_ref, kt_ref[si], first)
 
     @pl.when(first)
     def _init():
@@ -308,16 +316,14 @@ def _dsa_dq_kernel(qt_ref, kt_ref, lo_ref, hi_ref, first_ref, last_ref,
 
 def _dsa_dkv_kernel(qt_ref, kt_ref, lo_ref, hi_ref, first_ref, last_ref,
                     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    qi_ref, kr_ref, wt_ref, tau_ref, sig_ref,
-                    dk_ref, dv_ref, dk_s, dv_s, bias_s,
-                    *, nkv, G, d, T, IH, P):
+                    words_ref, dk_ref, dv_ref, dk_s, dv_s, bias_s,
+                    *, nkv, G, d):
     si, h = pl.program_id(1), pl.program_id(2)
     first, last = first_ref[si] == 1, last_ref[si] == 1
 
     @pl.when(h == 0)
     def _mask():
-        _mask_to(bias_s, None, si, qt_ref, kt_ref, first, kr_ref, qi_ref,
-                 wt_ref, tau_ref, sig_ref, IH, P, T)
+        _mask_to(bias_s, None, words_ref, kt_ref[si], first)
 
     @pl.when(first)
     def _init():
@@ -344,8 +350,8 @@ def _dsa_dkv_kernel(qt_ref, kt_ref, lo_ref, hi_ref, first_ref, last_ref,
 
 
 def _dsa_kl_kernel(qt_ref, kt_ref, lo_ref, hi_ref, first_ref, last_ref,
-                   q_ref, k_ref, lse_ref, qi_ref, kr_ref, wt_ref, tau_ref,
-                   sig_ref, lsei_ref, kl_ref, dqi_ref, dkr_ref, dwt_ref,
+                   q_ref, k_ref, lse_ref, qi_ref, kr_ref, wt_ref, words_ref,
+                   lsei_ref, kl_ref, dqi_ref, dkr_ref, dwt_ref,
                    krt_s, psum_s, kl_s, dqi_s, dwt_s,
                    *, nkv, G, d, T, IH, P):
     si, h = pl.program_id(1), pl.program_id(2)
@@ -371,8 +377,7 @@ def _dsa_kl_kernel(qt_ref, kt_ref, lo_ref, hi_ref, first_ref, last_ref,
     def _term():
         kr = kr_ref[0]
         I = _index_tile(kr, qi_ref, wt_ref, IH, P)
-        keep = _keep_tile(I, tau_ref, sig_ref, qt_ref[si] * T,
-                          kt_ref[si] * T)
+        keep = _plane(words_ref, kt_ref[si])
         target = jnp.where(keep, psum_s[...] * (1.0 / (nkv * G)), 0.0)
         logpi = jnp.where(keep, I - lsei_ref[0], 0.0)
         live = target > 0.0
@@ -413,69 +418,83 @@ def _dsa_kl_kernel(qt_ref, kt_ref, lo_ref, hi_ref, first_ref, last_ref,
             dwt_ref[0] = dwt_s[...]
 
 
-def _dsa_select_kernel(qi_ref, kr_ref, wt_ref, tau_ref, sig_ref, lsei_ref,
-                       key_s, *, S, TQ, CK, IH, P, topk):
+def _dsa_select_kernel(qi_ref, kr_ref, wt_ref, lsei_ref, words_ref,
+                       key_s, *, S, TQ, CK, T, IH, P, topk):
     i = pl.program_id(1)
     nck = ((i + 1) * TQ + CK - 1) // CK      # chunks that hold a causal key
     qidx = i * TQ + lax.broadcasted_iota(jnp.int32, (1, TQ), 1)
     k = min(topk, S)
     neg_inf_key = int(np.float32(-np.inf).view(np.int32)) ^ 0x7FFFFFFF
 
-    def kidx(c):
-        return c * CK + lax.broadcasted_iota(jnp.int32, (CK, TQ), 0)
+    def kidx(k0, n=CK):
+        return k0 + lax.broadcasted_iota(jnp.int32, (n, TQ), 0)
 
     def chunk(c):
         return key_s[pl.ds(pl.multiple_of(c * CK, CK), CK), :]
 
     def fill(c, carry):
         kr = kr_ref[0, pl.ds(pl.multiple_of(c * CK, CK), CK), :]
-        I = jnp.where(kidx(c) <= qidx,
+        I = jnp.where(kidx(c * CK) <= qidx,
                       _index_tile(kr, qi_ref, wt_ref, IH, P), -jnp.inf)
         key_s[pl.ds(pl.multiple_of(c * CK, CK), CK), :] = _order_key(I)
         return carry
     lax.fori_loop(0, nck, fill, 0)
 
     def total(of):
-        """Sum over the causal chunks of ``of(chunk, chunk's number)``
+        """Sum over the causal chunks of ``of(chunk, its keys' indices)``
         (float32 (CK, TQ)) down the keys -> (1, TQ)."""
         return lax.fori_loop(
-            0, nck, lambda c, t: t + jnp.sum(of(chunk(c), c), axis=0,
-                                             keepdims=True),
+            0, nck, lambda c, t: t + jnp.sum(of(chunk(c), kidx(c * CK)),
+                                             axis=0, keepdims=True),
             jnp.zeros((1, TQ), jnp.float32))
 
     ones = lambda m: jnp.where(m, 1.0, 0.0)
 
     def bit(n, cur):
         cand = cur | lax.shift_left(jnp.int32(1), 31 - n)
-        cnt = total(lambda x, c: ones(x >= (cand ^ _MIN)))
+        cnt = total(lambda x, kid: ones(x >= (cand ^ _MIN)))
         return jnp.where(cnt >= k, cand, cur)
     kth = lax.fori_loop(0, 32, bit, jnp.zeros((1, TQ), jnp.int32)) ^ _MIN
     # a query with no more than k causal keys keeps them all
     short = qidx < k
     kth = jnp.where(short, neg_inf_key, kth)
-    need = k - total(lambda x, c: ones(x > kth))
+    need = k - total(lambda x, kid: ones(x > kth))
     nbits = max(1, (S - 1).bit_length())
 
     def index_bit(n, cur):
         cand = cur | lax.shift_left(jnp.int32(1), nbits - 1 - n)
-        cnt = total(lambda x, c: ones((x == kth) & (kidx(c) < cand)))
+        cnt = total(lambda x, kid: ones((x == kth) & (kid < cand)))
         return jnp.where(cnt < need, cand, cur)
     sigma = lax.fori_loop(0, nbits, index_bit,
                           jnp.zeros((1, TQ), jnp.int32))
     sigma = jnp.where(short, S - 1, sigma)
 
-    def kept(x, c):
-        return ((x > kth) | ((x == kth) & (kidx(c) <= sigma))) \
-            & (kidx(c) <= qidx)
+    def kept(x, kid):
+        return ((x > kth) | ((x == kth) & (kid <= sigma))) & (kid <= qidx)
     top = lax.fori_loop(
         0, nck, lambda c, m: jnp.maximum(m, jnp.max(
             _key_value(chunk(c)), axis=0, keepdims=True)),
         jnp.full((1, TQ), -jnp.inf, jnp.float32))
-    ssum = total(lambda x, c: jnp.where(
-        kept(x, c), jnp.exp(_key_value(x) - top), 0.0))
-    tau_ref[0] = _key_value(kth)
-    sig_ref[0] = sigma
+    ssum = total(lambda x, kid: jnp.where(
+        kept(x, kid), jnp.exp(_key_value(x) - top), 0.0))
     lsei_ref[0] = top + jnp.log(ssum)
+
+    # the kept pairs as bit planes by key tile of the attend: bit j of
+    # words[g T + r, q] is key (32 g + j) T + r of query q. A tile past
+    # the block's last query keeps nothing (whatever key_s holds there,
+    # the causal term of ``kept`` is false) and is not visited
+    ntile = ((i + 1) * TQ + T - 1) // T
+    for g in range(words_ref.shape[1] // T):
+        rows = slice(g * T, (g + 1) * T)
+        words_ref[0, rows, :] = jnp.zeros((T, TQ), jnp.int32)
+
+        def plane(j, carry, g=g, rows=rows):
+            k0 = pl.multiple_of((32 * g + j) * T, T)
+            bits = kept(key_s[pl.ds(k0, T), :], kidx(k0, T))
+            words_ref[0, rows, :] = words_ref[0, rows, :] | lax.shift_left(
+                bits.astype(jnp.int32), j)
+            return carry
+        lax.fori_loop(0, jnp.clip(ntile - 32 * g, 0, 32), plane, 0)
 
 
 # ----------------------------------------------------------------------
@@ -516,75 +535,78 @@ def _tile_call(name, kernel, sched, b, nkv, in_specs, out_specs, out_shape,
         interpret=interpret)
 
 
-def _specs(nkv, G, d, T, IW, IH, P):
-    """BlockSpecs by role; an index map takes the grid's (row, step, kv
-    head) and then the schedule's six rows."""
-    def at(*tail):
-        """Block index (row, *tail): "q" / "k" the step's query / key
-        tile, "h" the kv head, a number itself."""
-        def index(b, s, h, qt, kt, *_):
-            pick = {"q": qt[s], "k": kt[s], "h": h}
-            return (b,) + tuple(pick.get(x, x) for x in tail)
-        return index
+def _at(*tail):
+    """An index map of a tile call: the grid's (row, step, kv head) and
+    the schedule's six rows -> block index (row, *tail): "q" / "k" the
+    step's query / key tile, "g" its key tile's group of 32, "h" the kv
+    head, a number itself."""
+    def index(b, s, h, qt, kt, *_):
+        pick = {"q": qt[s], "k": kt[s], "g": kt[s] // 32, "h": h}
+        return (b,) + tuple(pick.get(x, x) for x in tail)
+    return index
+
+
+def _specs(nkv, G, d, T):
+    """The attend's BlockSpecs by role."""
     return {
-        "q": pl.BlockSpec((1, T, G * d), at("q", "h")),
-        "kv": pl.BlockSpec((1, T, d), at("k", "h")),
-        "wide": pl.BlockSpec((1, T, nkv * G * d), at("q", 0)),
-        "kvwide": pl.BlockSpec((1, T, nkv * d), at("k", 0)),
-        "stat": pl.BlockSpec((1, nkv, G, 1, T), at(0, 0, 0, "q")),
-        "qi": pl.BlockSpec((1, T, IW), at("q", 0)),
-        "kr": pl.BlockSpec((1, T, P * LANES), at("k", 0)),
-        "wt": pl.BlockSpec((1, IH, T), at(0, "q")),
-        "row": pl.BlockSpec((1, 1, T), at(0, "q")),
+        "q": pl.BlockSpec((1, T, G * d), _at("q", "h")),
+        "kv": pl.BlockSpec((1, T, d), _at("k", "h")),
+        "wide": pl.BlockSpec((1, T, nkv * G * d), _at("q", 0)),
+        "kvwide": pl.BlockSpec((1, T, nkv * d), _at("k", 0)),
+        "stat": pl.BlockSpec((1, nkv, G, 1, T), _at(0, 0, 0, "q")),
+        "row": pl.BlockSpec((1, 1, T), _at(0, "q")),
+        "words": pl.BlockSpec((1, T, T), _at("g", "q")),
     }
 
 
-@functools.partial(jax.jit, static_argnums=(3, 4, 5))
-def _select_call(qi, kr, wt, topk, P, interpret):
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
+def _select_call(qi, kr, wt, topk, P, T, interpret):
+    """-> (the log-sum-exp of a query's kept index scores (b, 1, S), the
+    kept pairs ``words`` (b, mask_rows(S, T), S) int32 for an attend of
+    tile ``T``)."""
     from jax.experimental.pallas import tpu as pltpu
     b, S, IW = qi.shape
     IH = wt.shape[1]
     TQ, CK = min(SELECT_ROWS, S), min(SELECT_KEYS, S)
-    row = pl.BlockSpec((1, 1, TQ), lambda b, i: (b, 0, i))
+    planes = mask_rows(S, T)
     return _named_call(
         "dsa_select",
-        functools.partial(_dsa_select_kernel, S=S, TQ=TQ, CK=CK, IH=IH,
+        functools.partial(_dsa_select_kernel, S=S, TQ=TQ, CK=CK, T=T, IH=IH,
                           P=P, topk=topk),
         grid=(b, S // TQ),
         in_specs=[pl.BlockSpec((1, TQ, IW), lambda b, i: (b, i, 0)),
                   pl.BlockSpec((1, S, P * LANES), lambda b, i: (b, 0, 0)),
                   pl.BlockSpec((1, IH, TQ), lambda b, i: (b, 0, i))],
-        out_specs=[row, row, row],
+        out_specs=[pl.BlockSpec((1, 1, TQ), lambda b, i: (b, 0, i)),
+                   pl.BlockSpec((1, planes, TQ), lambda b, i: (b, 0, i))],
         out_shape=[jax.ShapeDtypeStruct((b, 1, S), jnp.float32),
-                   jax.ShapeDtypeStruct((b, 1, S), jnp.int32),
-                   jax.ShapeDtypeStruct((b, 1, S), jnp.float32)],
+                   jax.ShapeDtypeStruct((b, planes, S), jnp.int32)],
         scratch_shapes=[pltpu.VMEM((S, TQ), jnp.int32)],
         compiler_params=_params(("parallel", "arbitrary"), 100 << 20),
         interpret=interpret)(qi, kr, wt)
 
 
-def select_vmem_bytes(S, IW, P, itemsize=2):
+def select_vmem_bytes(S, IW, P, T, itemsize=2):
     """VMEM of a ``dsa_select`` grid step: the scores, the keys' slots
-    (resident, two buffers), the queries' blocks."""
+    (resident, two buffers), the queries' blocks, the block of ``words``
+    it writes (two buffers)."""
     TQ = min(SELECT_ROWS, S)
     return S * TQ * 4 + 2 * S * P * LANES * itemsize \
-        + 2 * TQ * IW * itemsize
+        + 2 * TQ * IW * itemsize + 2 * mask_rows(S, T) * TQ * 4
 
 
-@functools.partial(jax.jit, static_argnums=(8, 9, 10, 11))
-def _fwd_call(q, k, v, qi, kr, wt, tau, sig, nkv, T, P, interpret):
+@functools.partial(jax.jit, static_argnums=(4, 5, 6))
+def _fwd_call(q, k, v, words, nkv, T, interpret):
     from jax.experimental.pallas import tpu as pltpu
     b, S, hd = k.shape
-    d, G, IH = hd // nkv, q.shape[2] // hd, wt.shape[1]
-    sp = _specs(nkv, G, d, T, qi.shape[2], IH, P)
+    d, G = hd // nkv, q.shape[2] // hd
+    sp = _specs(nkv, G, d, T)
     sched = gq_schedule("causal", S // T, "q")
     return _tile_call(
         "flash_dsa_fwd",
-        functools.partial(_dsa_fwd_kernel, nkv=nkv, G=G, d=d, T=T, IH=IH,
-                          P=P),
+        functools.partial(_dsa_fwd_kernel, nkv=nkv, G=G, d=d, T=T),
         sched, b, nkv,
-        [sp["q"], sp["kv"], sp["kv"], sp["qi"], sp["kr"], sp["wt"],
-         sp["row"], sp["row"]],
+        [sp["q"], sp["kv"], sp["kv"], sp["words"]],
         [sp["wide"], sp["stat"], sp["row"]],
         [jax.ShapeDtypeStruct(q.shape, q.dtype),
          jax.ShapeDtypeStruct((b, nkv, G, 1, S), jnp.float32),
@@ -595,27 +617,26 @@ def _fwd_call(q, k, v, qi, kr, wt, tau, sig, nkv, T, P, interpret):
          pltpu.VMEM((nkv, G, d, T), jnp.float32),       # acc
          pltpu.VMEM((T, T), jnp.float32),               # the mask's bias
          pltpu.VMEM((1, T), jnp.float32)],              # pairs kept
-        interpret)(*sched, q, k, v, qi, kr, wt, tau, sig)
+        interpret)(*sched, q, k, v, words)
 
 
-@functools.partial(jax.jit, static_argnums=(11, 12, 13, 14, 15))
-def _bwd_call(q, k, v, o, lse, do, qi, kr, wt, tau, sig, nkv, T, P, scale,
-              interpret):
+@functools.partial(jax.jit, static_argnums=(7, 8, 9, 10))
+def _bwd_call(q, k, v, o, lse, do, words, nkv, T, scale, interpret):
     from jax.experimental.pallas import tpu as pltpu
     b, S, hd = k.shape
-    d, G, IH = hd // nkv, q.shape[2] // hd, wt.shape[1]
+    d, G = hd // nkv, q.shape[2] // hd
     delta = jnp.sum((do.astype(jnp.float32) * o.astype(jnp.float32)
                      ).reshape(b, S, nkv, G, d), axis=-1)
     delta = delta.transpose(0, 2, 3, 1)[:, :, :, None, :]   # as lse
-    sp = _specs(nkv, G, d, T, qi.shape[2], IH, P)
+    sp = _specs(nkv, G, d, T)
     ins = [sp["q"], sp["kv"], sp["kv"], sp["q"], sp["stat"], sp["stat"],
-           sp["qi"], sp["kr"], sp["wt"], sp["row"], sp["row"]]
-    args = (q, k, v, do, lse, delta, qi, kr, wt, tau, sig)
+           sp["words"]]
+    args = (q, k, v, do, lse, delta, words)
     sq = gq_schedule("causal", S // T, "q")
     dq = _tile_call(
         "flash_dsa_dq",
-        functools.partial(_dsa_dq_kernel, nkv=nkv, G=G, d=d, T=T, IH=IH,
-                          P=P, scale=scale),
+        functools.partial(_dsa_dq_kernel, nkv=nkv, G=G, d=d, T=T,
+                          scale=scale),
         sq, b, nkv, ins, sp["wide"],
         jax.ShapeDtypeStruct(q.shape, q.dtype),
         [pltpu.VMEM((d, T), k.dtype),                   # k.T
@@ -625,8 +646,7 @@ def _bwd_call(q, k, v, o, lse, do, qi, kr, wt, tau, sig, nkv, T, P, scale,
     sk = gq_schedule("causal", S // T, "k")
     dk, dv = _tile_call(
         "flash_dsa_dkv",
-        functools.partial(_dsa_dkv_kernel, nkv=nkv, G=G, d=d, T=T, IH=IH,
-                          P=P),
+        functools.partial(_dsa_dkv_kernel, nkv=nkv, G=G, d=d),
         sk, b, nkv, ins, [sp["kvwide"], sp["kvwide"]],
         [jax.ShapeDtypeStruct(k.shape, k.dtype),
          jax.ShapeDtypeStruct(v.shape, v.dtype)],
@@ -637,14 +657,16 @@ def _bwd_call(q, k, v, o, lse, do, qi, kr, wt, tau, sig, nkv, T, P, scale,
     return dq, dk, dv
 
 
-@functools.partial(jax.jit, static_argnums=(9, 10, 11, 12))
-def _kl_call(q, k, lse, qi, kr, wt, tau, sig, lsei, nkv, T, P, interpret):
+@functools.partial(jax.jit, static_argnums=(8, 9, 10, 11))
+def _kl_call(q, k, lse, qi, kr, wt, words, lsei, nkv, T, P, interpret):
     """-> (the KL term a query (b, 1, S), its gradient into qi, into the
     keys' slots (b, S, 128) float32, into wt)."""
     from jax.experimental.pallas import tpu as pltpu
     b, S, hd = k.shape
     d, G, IH = hd // nkv, q.shape[2] // hd, wt.shape[1]
-    sp = _specs(nkv, G, d, T, qi.shape[2], IH, P)
+    sp = _specs(nkv, G, d, T)
+    qis = pl.BlockSpec((1, T, qi.shape[2]), _at("q", 0))
+    wts = pl.BlockSpec((1, IH, T), _at(0, "q"))
     sched = gq_schedule("causal", S // T, "q")
     whole = pl.BlockSpec((1, S, LANES), lambda b, s, h, *_: (b, 0, 0))
     return _tile_call(
@@ -652,9 +674,10 @@ def _kl_call(q, k, lse, qi, kr, wt, tau, sig, lsei, nkv, T, P, interpret):
         functools.partial(_dsa_kl_kernel, nkv=nkv, G=G, d=d, T=T, IH=IH,
                           P=P),
         sched, b, nkv,
-        [sp["q"], sp["kv"], sp["stat"], sp["qi"], sp["kr"], sp["wt"],
-         sp["row"], sp["row"], sp["row"]],
-        [sp["row"], sp["qi"], whole, sp["wt"]],
+        [sp["q"], sp["kv"], sp["stat"], qis,
+         pl.BlockSpec((1, T, P * LANES), _at("k", 0)), wts, sp["words"],
+         sp["row"]],
+        [sp["row"], qis, whole, wts],
         [jax.ShapeDtypeStruct((b, 1, S), jnp.float32),
          jax.ShapeDtypeStruct(qi.shape, jnp.float32),
          jax.ShapeDtypeStruct((b, S, LANES), jnp.float32),
@@ -664,7 +687,7 @@ def _kl_call(q, k, lse, qi, kr, wt, tau, sig, lsei, nkv, T, P, interpret):
          pltpu.VMEM((1, T), jnp.float32),               # the term's rows
          pltpu.VMEM((IH // P, LANES, T), jnp.float32),  # d qi .T
          pltpu.VMEM((IH, T), jnp.float32)],             # d wt
-        interpret)(*sched, q, k, lse, qi, kr, wt, tau, sig, lsei)
+        interpret)(*sched, q, k, lse, qi, kr, wt, words, lsei)
 
 
 def flash_attention_dsa(q, k, v, qi, ki, wi, nkv: int, topk: int,
@@ -691,14 +714,16 @@ def _flash_dsa(q, k, v, qi, ki, wi, nkv, topk, scale, interpret, tile):
 
 
 def plan_mark(kernels, S, qw, hd, nkv, iw, ih, topk, tile=0, itemsize=2):
-    """What a ``dsa.plan`` span says of a call."""
+    """What a ``dsa.plan`` span says of a call (``mask_bytes``: a row's
+    ``words``)."""
     T, n, G, d, P = _plan(S, qw, hd, nkv, iw, ih, tile)
     return {"kernels": kernels, "s": S, "heads": nkv * G, "kv_heads": nkv,
             "d": d, "idx_heads": ih, "idx_dim": iw // ih, "topk": topk,
             "block_q": T, "block_k": T,
             "tile_pairs": len(gq_pairs("causal", n)),
             "tile_pairs_dense": n * n, "select": "kernel",
-            "vmem_bytes": select_vmem_bytes(S, iw, P, itemsize)}
+            "mask": "select", "mask_bytes": mask_rows(S, T) * S * 4,
+            "vmem_bytes": select_vmem_bytes(S, iw, P, T, itemsize)}
 
 
 def _flash_dsa_fwd(q, k, v, qi, ki, wi, nkv, topk, scale, interpret, tile):
@@ -713,35 +738,35 @@ def _flash_dsa_fwd(q, k, v, qi, ki, wi, nkv, topk, scale, interpret, tile):
     wt = wi.astype(jnp.float32).transpose(0, 2, 1)
     with trace.span("dsa.plan", "kernel", plan_mark(
             "fwd", *dims, topk, tile, q.dtype.itemsize)):
-        tau, sig, lsei = _select_call(qi, kr, wt, topk, P, interpret)
-        o, lse, cnt = _fwd_call(qs, k, v, qi, kr, wt, tau, sig, nkv, T, P,
-                                interpret)
+        lsei, words = _select_call(qi, kr, wt, topk, P, T, interpret)
+        o, lse, cnt = _fwd_call(qs, k, v, words, nkv, T, interpret)
         o, lse = _kept(o, lse)
-        kl, dqi, dkr, dwt = _kl_call(qs, k, lse, qi, kr, wt, tau, sig,
-                                     lsei, nkv, T, P, interpret)
+        kl, dqi, dkr, dwt = _kl_call(qs, k, lse, qi, kr, wt, words, lsei,
+                                     nkv, T, P, interpret)
     b, S, idim = ki.shape
     grads = (dqi, dkr.reshape(b, S, P, idim).sum(2),
              dwt.transpose(0, 2, 1))
     out = (o, kl.sum((1, 2)), cnt.sum((1, 2)).astype(jnp.int32))
-    # (the last: wi's dtype, which a residual can only carry on an array)
-    return out, (qs, k, v, o, lse, qi, kr, wt, tau, sig, grads,
-                 jnp.zeros((0,), wi.dtype))
+    # (the last: the dtypes of qi, ki, wi, which a residual can only
+    # carry on an array)
+    return out, (qs, k, v, o, lse, words, grads,
+                 tuple(jnp.zeros((0,), x.dtype) for x in (qi, ki, wi)))
 
 
 def _flash_dsa_bwd(nkv, topk, scale, interpret, tile, res, g):
     from ..obs import trace
-    qs, k, v, o, lse, qi, kr, wt, tau, sig, grads, wz = res
+    qs, k, v, o, lse, words, grads, like = res
     do, dkl = g[0], g[1]
-    dims = (qs.shape[1], qs.shape[2], k.shape[2], nkv, qi.shape[2],
-            wt.shape[1])
+    dims = (qs.shape[1], qs.shape[2], k.shape[2], nkv, grads[0].shape[2],
+            grads[2].shape[2])
     T, n, G, d, P = _plan(*dims, tile)
     with trace.span("dsa.plan", "kernel", plan_mark(
             "bwd", *dims, topk, tile, qs.dtype.itemsize)):
-        dq, dk, dv = _bwd_call(qs, k, v, o, lse, do, qi, kr, wt, tau, sig,
-                               nkv, T, P, scale, interpret)
+        dq, dk, dv = _bwd_call(qs, k, v, o, lse, do, words, nkv, T, scale,
+                               interpret)
     f = dkl.astype(jnp.float32)[:, None, None]
-    return (dq, dk, dv, (grads[0] * f).astype(qi.dtype),
-            (grads[1] * f).astype(kr.dtype), (grads[2] * f).astype(wz.dtype))
+    return (dq, dk, dv) + tuple((x * f).astype(z.dtype)
+                                for x, z in zip(grads, like))
 
 
 _flash_dsa.defvjp(_flash_dsa_fwd, _flash_dsa_bwd)

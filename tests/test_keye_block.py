@@ -230,15 +230,18 @@ def test_three_adamw_steps_match_reference(ref, tiny, tiny_cell,
 # ----------------------------------------------------------------------
 # the selection alone
 
-def _exact_scores(seed, S=96):
+def _exact_scores(seed, S=96, dim=8):
     """Index operands whose products and sums are exact in float32 in
     any order (quarters and eighths), so that every path computes the
-    same scores to the bit, with many ties among them."""
+    same scores to the bit, with many ties among them. Two index heads
+    of 8 live dims, padded with zeros to ``dim`` (the kernels take index
+    heads that fill whole lane tiles: the scores are the same)."""
     rng = np.random.default_rng(seed)
-    qi = jnp.asarray(rng.integers(-2, 3, (1, S, 2 * 8)), jnp.float32) / 4
+    pad = lambda x: jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, dim - 8)])
+    qi = jnp.asarray(rng.integers(-2, 3, (1, S, 2, 8)), jnp.float32) / 4
     ki = jnp.asarray(rng.integers(-2, 3, (1, S, 8)), jnp.float32) / 4
     wi = jnp.asarray(rng.integers(-3, 4, (1, S, 2)), jnp.float32) / 8
-    return qi, ki, wi
+    return pad(qi).reshape(1, S, 2 * dim), pad(ki), wi
 
 
 @pytest.mark.parametrize("topk", [1, 7, 32, 96, 200])
@@ -270,6 +273,95 @@ def test_selection_keeps_the_topk_ties_to_the_lower_index(topk, ref):
 def test_pairs_closed_form():
     assert da.pairs_kept(16384, 2048) == 31458304
     assert da.pairs_kept(5, 8) == 15 and da.pairs_kept(4, 1) == 4
+
+
+def _twin_mask(qi, ki, wi, topk):
+    S = qi.shape[1]
+    scores = jnp.where(np.tril(np.ones((S, S), bool)),
+                       da.index_scores(qi, ki, wi), -jnp.inf)
+    return np.asarray(da.keep_mask(scores, *da.thresholds(scores, topk)))
+
+
+def _select(qi, ki, wi, topk, tile):
+    """``dsa_select`` (interpret mode) for an attend of tile ``tile`` ->
+    (lsei, words)."""
+    P = da.LANES // (qi.shape[2] // wi.shape[2])
+    return da._select_call(qi, da._key_slots(ki, P), wi.transpose(0, 2, 1),
+                           topk, P, tile, True)
+
+
+def _unpack(words, T):
+    """words (b, groups * T, S) int32 -> bool (b, queries, keys): bit
+    ``kt % 32`` of row ``(kt // 32) T + r`` is key ``kt T + r``."""
+    w = np.asarray(words)
+    s = np.arange(w.shape[2])
+    kt = s // T
+    bits = (w[:, (kt // 32) * T + s % T, :] >> (kt % 32)[None, :, None]) & 1
+    return bits.transpose(0, 2, 1).astype(bool)
+
+
+@pytest.mark.parametrize("topk", [1, 7, 32, 200])
+def test_select_kernel_writes_the_twins_mask(topk):
+    """``words``, unpacked, is the twin's dense mask bit for bit, ties
+    included (the operands of the test above, two key tiles of 128), and
+    holds nothing else: no bit of a plane past the last key tile, no
+    pair that is not causal."""
+    S, T = 256, 128
+    qi, ki, wi = _exact_scores(topk, S, dim=64)
+    want = _twin_mask(qi, ki, wi, topk)
+    _, words = _select(qi, ki, wi, topk, T)
+    assert words.shape == (1, da.mask_rows(S, T), S) == (1, T, S)
+    assert words.dtype == jnp.int32
+    np.testing.assert_array_equal(_unpack(words, T), want)
+    assert int(np.asarray(jax.lax.population_count(words)).sum()) \
+        == want.sum() == da.pairs_kept(S, topk)
+
+
+@pytest.fixture(scope="module")
+def two_groups():
+    """36 key tiles of 128: a second group of planes. One kv head of one
+    query head keeps the interpreter's 666 tile pairs short."""
+    S, T, topk = 4608, 128, 300
+    rng = np.random.default_rng(5)
+    f = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)
+    qi, ki, wi = _exact_scores(9, S, dim=64)
+    q, k, v = f(1, S, D) * 0.5, f(1, S, D) * 0.5, f(1, S, D)
+    _, words = _select(qi, ki, wi, topk, T)
+    o, lse, cnt = da._fwd_call(q * D ** -0.5, k, v, words, 1, T, True)
+    dense = da.dsa_attention_dense(q, k, v, qi, ki, wi, 1, topk)
+    return S, T, topk, words, o, cnt, dense, _twin_mask(qi, ki, wi, topk)
+
+
+def test_second_group_of_planes_is_written(two_groups):
+    S, T, topk, words, _, _, _, want = two_groups
+    assert words.shape == (1, 2 * T, S)
+    got = _unpack(words, T)
+    np.testing.assert_array_equal(got, want)
+    assert got[0, :, 32 * T:].sum() > 0           # keys of tiles 32-35
+
+
+def test_second_group_of_planes_is_read(two_groups):
+    """The forward kernel on 36 key tiles against the dense twin (float32
+    both, 5e-6 as ``test_kernels_match_twin_forward``): a query past key
+    4,096 attends over kept keys of both groups."""
+    _, _, _, _, o, _, dense, _ = two_groups
+    assert float(jnp.abs(o - dense[0]).max()) < 5e-6
+
+
+@pytest.mark.parametrize("case", ["one_tile", "two_groups"])
+def test_forward_counts_the_population_of_words(case, request):
+    """The pairs the forward kernel counts are the bits set in
+    ``words``."""
+    if case == "two_groups":
+        S, _, topk, words, _, cnt, dense, _ = request.getfixturevalue(case)
+        assert int(dense[2][0]) == da.pairs_kept(S, topk)
+    else:
+        S, T, topk = 128, 128, 40
+        q, k, v, qi, ki, wi = _operands(3, S, 4)
+        _, words = _select(qi, ki, wi, topk, T)
+        _, _, cnt = da._fwd_call(q, k, v, words, NKV, T, True)
+    pop = int(np.asarray(jax.lax.population_count(words)).sum())
+    assert int(np.asarray(cnt).sum()) == pop == da.pairs_kept(S, topk)
 
 
 # ----------------------------------------------------------------------
@@ -593,7 +685,11 @@ def test_dsa_plan_span_and_counters():
     assert a["block_q"] == a["block_k"] == 128
     assert a["tile_pairs"] == a["tile_pairs_dense"] == 1
     assert a["select"] == "kernel" and a["vmem_bytes"] > 0
+    # the mask is dsa_select's: one plane of 128 rows by 128 queries
+    assert a["mask"] == "select" and a["mask_bytes"] == 128 * 128 * 4
     big = da.plan_mark("fwd", 16384, 4096, 512, 4, 1024, 16, 2048)
+    assert big["mask_bytes"] == 16384 * 16384 // 8          # 33.5 MB
+    assert da.mask_rows(32768, 512) == 2 * 512
     assert big["tile_pairs"] == 32 * 33 // 2
     assert big["tile_pairs_dense"] == 1024
     assert big["vmem_bytes"] < 100 << 20
