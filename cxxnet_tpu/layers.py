@@ -65,6 +65,15 @@ def create_layer(type_name: str, cfg: Sequence[Tuple[str, str]],
     return layer
 
 
+def _part(name: str):
+    """``jax.named_scope(name)``: a word of ``obs.trace.PARTS`` on every
+    operation made inside it (a context manager or a decorator), so that
+    a device trace can be split by the part of the block that made each
+    (``obs.trace.scope_of``). Metadata only: the lowered program is the
+    same text without it. A Pallas kernel's own scope stays innermost."""
+    return jax.named_scope(name)
+
+
 # ----------------------------------------------------------------------
 @dataclass
 class LayerParam:
@@ -2491,6 +2500,7 @@ class TransformerStackLayer(Layer):
         if seq_sharded and self.attn_impl != "pallas":
             use_flash = False
 
+        @_part("norm")
         def rmsnorm(x, g):
             # g=None: the learned gain is folded into the following
             # weight matrix (_fold_norms — one L*e*f multiply at trace
@@ -2508,6 +2518,7 @@ class TransformerStackLayer(Layer):
         topk, cap_f = self.topk, self.capacity_factor
         nexpert = self.nexpert
 
+        @_part("mlp")
         def mlp(lp, x):
             b, s, e = x.shape
             if not moe:
@@ -2526,11 +2537,19 @@ class TransformerStackLayer(Layer):
             return self._grouped_block(dt, interpret, mesh, seq_sharded,
                                        use_flash, rmsnorm, positions)
 
+        def rest(lp, h, att):
+            """The block after its attend."""
+            with _part("attn_proj"):
+                h = h + jnp.einsum("bse,fe->bsf", att, lp["wo"].astype(dt))
+            y, aux = mlp(lp, rmsnorm(h, lp["norm2"] if moe else None))
+            return h + y, aux
+
         def block(lp, h):
             b, s, e = h.shape
             d = e // nh
             x = rmsnorm(h, None)          # gain folded into wqkv
-            qkv = jnp.einsum("bse,fe->bsf", x, lp["wqkv"].astype(dt))
+            with _part("attn_proj"):
+                qkv = jnp.einsum("bse,fe->bsf", x, lp["wqkv"].astype(dt))
             if use_flash and not seq_sharded:
                 from .ops import flash_attention as fa
                 if fa.supports_flat(s, nh, d) \
@@ -2540,43 +2559,38 @@ class TransformerStackLayer(Layer):
                     # (3, b, h, s, d) relayouts on either pass.
                     # Single-block s takes the fused backward; longer
                     # s the r5 blocked flat kernels (flat_blocked_plan)
-                    att = pallas_env.per_shard(
-                        mesh, lambda qkv: fa.flash_attention_flat(
-                            qkv, nh, causal, interpret=interpret),
-                        (rows,), rows)(qkv)
-                    h = h + jnp.einsum("bse,fe->bsf", att,
-                                       lp["wo"].astype(dt))
-                    x = rmsnorm(h, lp["norm2"] if moe else None)
-                    y, aux = mlp(lp, x)
-                    return h + y, aux
-            qkv = qkv.reshape(b, s, 3, nh, d).transpose(2, 0, 3, 1, 4)
+                    with _part("attn_core"):
+                        att = pallas_env.per_shard(
+                            mesh, lambda qkv: fa.flash_attention_flat(
+                                qkv, nh, causal, interpret=interpret),
+                            (rows,), rows)(qkv)
+                    return rest(lp, h, att)
+            with _part("attn_core"):
+                att = heads_attend(qkv.reshape(b, s, 3, nh, d).transpose(
+                    2, 0, 3, 1, 4)).transpose(0, 2, 1, 3).reshape(b, s, e)
+            return rest(lp, h, att)
+
+        def heads_attend(qkv):
+            """(3, b, heads, s, d) -> the attention (b, heads, s, d)."""
             if seq_sharded:
                 # sequence parallelism: the attend must stay sharded —
                 # calling the local kernels on seq-sharded arrays would
                 # make GSPMD all-gather the full sequence per chip
                 if use_flash:
                     from .ops import ulysses
-                    att = ulysses.sharded_ulysses(
+                    return ulysses.sharded_ulysses(
                         mesh, qkv[0], qkv[1], qkv[2], seq_axis=seq_axis,
                         causal=causal, impl="pallas", interpret=interpret)
-                else:
-                    att = ra.sharded_attention(mesh, qkv[0], qkv[1],
-                                               qkv[2], seq_axis=seq_axis,
-                                               causal=causal)
-            elif use_flash:
+                return ra.sharded_attention(mesh, qkv[0], qkv[1], qkv[2],
+                                            seq_axis=seq_axis, causal=causal)
+            if use_flash:
                 # VMEM-blocked online-softmax kernel: O(s*d) memory
                 from .ops import flash_attention as fa
-                att = pallas_env.per_shard(
+                return pallas_env.per_shard(
                     mesh, lambda q, k, v: fa.flash_attention(
                         q, k, v, causal, interpret=interpret),
                     (rows, rows, rows), rows)(qkv[0], qkv[1], qkv[2])
-            else:
-                att = ra.attention(qkv[0], qkv[1], qkv[2], causal=causal)
-            att = att.transpose(0, 2, 1, 3).reshape(b, s, e)
-            h = h + jnp.einsum("bse,fe->bsf", att, lp["wo"].astype(dt))
-            x = rmsnorm(h, lp["norm2"] if moe else None)
-            y, aux = mlp(lp, x)
-            return h + y, aux
+            return ra.attention(qkv[0], qkv[1], qkv[2], causal=causal)
         return block
 
     def _grouped_block(self, dt, interpret, mesh, seq_sharded, use_flash,
@@ -2626,9 +2640,11 @@ class TransformerStackLayer(Layer):
 
         # three position streams that differ: the plain path with the
         # angles of each (text takes the tables, and the kernels)
-        angles = None if positions is None else qp.rope_angles(
-            positions, d, float(self.rope_theta), self.mrope_section)
+        with _part("attn_prep"):
+            angles = None if positions is None else qp.rope_angles(
+                positions, d, float(self.rope_theta), self.mrope_section)
 
+        @_part("attn_prep")
         def prepare(lp, qkv):
             """qkv -> (q, k, v), q and k normed and rotated."""
             gains = (lp["qnorm"], lp["knorm"]) if self.qk_norm \
@@ -2643,6 +2659,7 @@ class TransformerStackLayer(Layer):
                     qkv, *gains, nh, nkv, interpret=interpret, **prep),
                 (rows, P()), (rows, rows, rows))(qkv, gains)
 
+        @_part("attn_core")
         def attend(q, k, v):
             if flash:
                 return pallas_env.per_shard(
@@ -2654,9 +2671,12 @@ class TransformerStackLayer(Layer):
         def attention(lp, h):
             """h + the block's attention."""
             x = rmsnorm(h, None)          # gain folded into wqkv
-            qkv = jnp.einsum("bse,fe->bsf", x, lp["wqkv"].astype(dt))
+            with _part("attn_proj"):
+                qkv = jnp.einsum("bse,fe->bsf", x, lp["wqkv"].astype(dt))
             att = attend(*prepare(lp, qkv))
-            return h + jnp.einsum("bsf,ef->bse", att, lp["wo"].astype(dt))
+            with _part("attn_proj"):
+                return h + jnp.einsum("bsf,ef->bse", att,
+                                      lp["wo"].astype(dt))
 
         if self.attn == "mla":
             attention = self._mla_attention(dt, interpret, mesh, use_flash,
@@ -2670,6 +2690,7 @@ class TransformerStackLayer(Layer):
         def mlp(lp, x):
             b, s, e = x.shape
             if self.sorted:
+                @_part("moe_dispatch")
                 def routed(x, ep):
                     # each data-parallel replica routes its own rows
                     # (the grouped products are Pallas kernels)
@@ -2691,13 +2712,15 @@ class TransformerStackLayer(Layer):
                     jnp.arange(len(ms.STATS)) == ms.STATS.index(
                         "load_max"), stats.max(0), stats.sum(0))
                 return y, stats
-            a = jnp.einsum("bse,me->bsm", x, lp["w1"].astype(dt))
-            if self.mlp_act == "swiglu":
-                a = (jax.nn.silu(a[..., :m].astype(jnp.float32))
-                     * a[..., m:].astype(jnp.float32)).astype(dt)
-            else:
-                a = jax.nn.relu(a)
-            return jnp.einsum("bsm,em->bse", a, lp["w2"].astype(dt)), 0.0
+            with _part("mlp"):
+                a = jnp.einsum("bse,me->bsm", x, lp["w1"].astype(dt))
+                if self.mlp_act == "swiglu":
+                    a = (jax.nn.silu(a[..., :m].astype(jnp.float32))
+                         * a[..., m:].astype(jnp.float32)).astype(dt)
+                else:
+                    a = jax.nn.relu(a)
+                return jnp.einsum("bsm,em->bse", a,
+                                  lp["w2"].astype(dt)), 0.0
 
         def block(lp, h):
             h = attention(lp, h)
@@ -2707,9 +2730,10 @@ class TransformerStackLayer(Layer):
                 # the leading dense layer (dense_first): a gated MLP of
                 # its own width where the others route
                 x = rmsnorm(h, lp["norm2"])
-                return h + ms.shared_expert(
-                    x.reshape(-1, x.shape[-1]), lp["w1d"], lp["w2d"],
-                    dt).reshape(x.shape), 0.0
+                with _part("mlp"):
+                    return h + ms.shared_expert(
+                        x.reshape(-1, x.shape[-1]), lp["w1d"], lp["w2d"],
+                        dt).reshape(x.shape), 0.0
             # the routed layer gates on the gained activations: its
             # gain is applied, not folded (_fold_norms)
             y, aux = mlp(lp, rmsnorm(h, lp["norm2"] if self.moe
@@ -2735,9 +2759,11 @@ class TransformerStackLayer(Layer):
         theta = float(self.rope_theta)
         rows = pallas_env.rows_spec(mesh)
         # the indexer turns by the first (temporal) stream alone
-        turn = None if positions is None else qp.rope_angles(
-            positions[..., 0], idim, theta)
+        with _part("idx"):
+            turn = None if positions is None else qp.rope_angles(
+                positions[..., 0], idim, theta)
 
+        @_part("attn_core")
         def attend(*ops):
             S = ops[0].shape[1]
             if not use_flash:
@@ -2760,24 +2786,29 @@ class TransformerStackLayer(Layer):
         def attention(lp, h):
             b, s, _ = h.shape
             x = rmsnorm(h, None)          # gain folded into wqkv
-            qkv = jnp.einsum("bse,fe->bsf", x, lp["wqkv"].astype(dt))
+            with _part("attn_proj"):
+                qkv = jnp.einsum("bse,fe->bsf", x, lp["wqkv"].astype(dt))
             xb = jax.lax.stop_gradient(x)
-            proj = lambda w, **kw: jnp.einsum("bse,fe->bsf", xb,
-                                              lp[w].astype(dt), **kw)
-            qi = qp.rotate_half(proj("wiq").reshape(b, s, ih, idim), turn,
-                                theta).astype(dt).reshape(b, s, ih * idim)
-            kf = proj("wik").astype(jnp.float32)
-            mu = jnp.mean(kf, -1, keepdims=True)
-            var = jnp.mean(jnp.square(kf - mu), -1, keepdims=True)
-            kf = (kf - mu) * jax.lax.rsqrt(var + 1e-6) \
-                * lp["iknorm"][0] + lp["iknorm"][1]
-            ki = qp.rotate_half(kf[:, :, None], turn, theta
-                                )[:, :, 0].astype(dt)
-            wi = proj("wiw", preferred_element_type=jnp.float32) \
-                * (ih ** -0.5 * idim ** -0.5)
+            proj = _part("idx_proj")(lambda w, **kw: jnp.einsum(
+                "bse,fe->bsf", xb, lp[w].astype(dt), **kw))
+            with _part("idx"):
+                qi = qp.rotate_half(
+                    proj("wiq").reshape(b, s, ih, idim), turn,
+                    theta).astype(dt).reshape(b, s, ih * idim)
+                kf = proj("wik").astype(jnp.float32)
+                mu = jnp.mean(kf, -1, keepdims=True)
+                var = jnp.mean(jnp.square(kf - mu), -1, keepdims=True)
+                kf = (kf - mu) * jax.lax.rsqrt(var + 1e-6) \
+                    * lp["iknorm"][0] + lp["iknorm"][1]
+                ki = qp.rotate_half(kf[:, :, None], turn, theta
+                                    )[:, :, 0].astype(dt)
+                wi = proj("wiw", preferred_element_type=jnp.float32) \
+                    * (ih ** -0.5 * idim ** -0.5)
             att, kl, kept = attend(*prepare(lp, qkv), qi, ki, wi)
-            return (h + jnp.einsum("bsf,ef->bse", att, lp["wo"].astype(dt)),
-                    (jnp.sum(kl), jnp.sum(kept)))
+            with _part("attn_proj"):
+                out = h + jnp.einsum("bsf,ef->bse", att,
+                                     lp["wo"].astype(dt))
+            return out, (jnp.sum(kl), jnp.sum(kept))
         return attention
 
     def _mla_attention(self, dt, interpret, mesh, use_flash, rmsnorm):
@@ -2796,6 +2827,7 @@ class TransformerStackLayer(Layer):
         flash = use_flash and fa.mla_supported(nh, self.d_nope, dr,
                                                self.d_v)
 
+        @_part("attn_core")
         def attend(*ops):
             if flash:
                 return pallas_env.per_shard(
@@ -2808,19 +2840,22 @@ class TransformerStackLayer(Layer):
 
         def attention(lp, h):
             b, s, _ = h.shape
-            proj = lambda x, w: jnp.einsum("bse,fe->bsf", x,
-                                           lp[w].astype(dt))
+            proj = _part("attn_proj")(lambda x, w: jnp.einsum(
+                "bse,fe->bsf", x, lp[w].astype(dt)))
             pos = jnp.arange(s)
             x = rmsnorm(h, lp["norm1"])
             cq = rmsnorm(proj(x, "wqa"), lp["qanorm"])
             ckv = rmsnorm(proj(x, "wkc"), lp["kvnorm"])
-            qr = fa.rope_pairs(proj(cq, "wqr").reshape(b, s, nh, dr), pos,
-                               theta, True).reshape(b, s, nh * dr)
-            kr = fa.rope_pairs(proj(x, "wkr")[:, :, None], pos, theta,
-                               True)[:, :, 0]
+            with _part("attn_prep"):
+                qr = fa.rope_pairs(
+                    proj(cq, "wqr").reshape(b, s, nh, dr), pos, theta,
+                    True).reshape(b, s, nh * dr)
+                kr = fa.rope_pairs(proj(x, "wkr")[:, :, None], pos, theta,
+                                   True)[:, :, 0]
             att = attend(proj(cq, "wqn"), qr, proj(ckv, "wkn"), kr,
                          proj(ckv, "wv"))
-            return h + proj(att, "wo")
+            with _part("attn_proj"):
+                return h + proj(att, "wo")
         return attention
 
     def _fold_norms(self, params, dt):

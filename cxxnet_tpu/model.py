@@ -219,13 +219,16 @@ class Network:
         has_grad = [False] * self.cfg.num_nodes
         for li, (info, mod) in enumerate(zip(self.cfg.layers, self.modules)):
             upstream = any(has_grad[ni] for ni in info.nindex_in)
-            layer_ctx = dataclasses.replace(
-                ctx, layer_index=li, needs_input_grad=upstream,
-                rng=(jax.random.fold_in(rng, li)
-                     if rng is not None else None))
             inputs = [values[ni] for ni in info.nindex_in]
-            outputs = mod.apply(self._layer_params(params, li),
-                                inputs, layer_ctx)
+            # the layer's type on every operation it makes (metadata:
+            # obs.trace.scope_of reads it off a device trace)
+            with jax.named_scope(mod.type_name):
+                layer_ctx = dataclasses.replace(
+                    ctx, layer_index=li, needs_input_grad=upstream,
+                    rng=(jax.random.fold_in(rng, li)
+                         if rng is not None else None))
+                outputs = mod.apply(self._layer_params(params, li),
+                                    inputs, layer_ctx)
             for no, v in zip(info.nindex_out, outputs):
                 values[no] = v
             flag = upstream or mod.has_params

@@ -48,6 +48,7 @@ import contextlib
 import json
 import numbers
 import os
+import re
 import sys
 import threading
 import time
@@ -484,6 +485,11 @@ COMPILE_PHASES = {
     "/jax/compilation_cache/cache_retrieval_time_sec": "cache_read",
 }
 _compile_log: deque = deque(maxlen=4096)
+# traces under a millisecond, in a ring of their own: one lowering of a
+# train step fires thousands, each before the trace that contains it,
+# and in the ring above they pushed set-up's events out before a reader
+# came (after ``device_scopes()`` every time)
+_short_traces: deque = deque(maxlen=4096)
 _compile_seconds = _compiles = None     # registry counters, made with
                                         # the listener (_attach_jax)
 
@@ -519,18 +525,20 @@ def _on_compile(event: str, secs: float, **kw) -> None:
         return
     t1 = time.perf_counter()
     cause = getattr(_tls, "phase", None)
+    short = what == "trace" and secs < 1e-3
     if what == "trace":
         # JAX fires this for every jitted function traced inside
         # another's trace, thousands of them in one train step, each
         # before the one that contains it: the log keeps the outermost
-        while _compile_log and _compile_log[-1][0] == "trace" \
-                and _compile_log[-1][3] == cause \
-                and _compile_log[-1][2] - _compile_log[-1][1] >= t1 - secs:
-            _compile_log.pop()
-    _compile_log.append((what, secs, t1, cause))
+        for log in (_compile_log, _short_traces):
+            while log and log[-1][0] == "trace" and log[-1][3] == cause \
+                    and log[-1][2] - log[-1][1] >= t1 - secs:
+                log.pop()
+    (_short_traces if short else _compile_log).append(
+        (what, secs, t1, cause))
     _compile_seconds.inc(secs, phase=what)
     _compiles.inc(phase=what)
-    if what == "trace" and secs < 1e-3:
+    if short:
         return      # the nested ones: not worth a span each
     s = sink()
     if s is not None:
@@ -540,12 +548,136 @@ def _on_compile(event: str, secs: float, **kw) -> None:
 
 def compile_events() -> List[tuple]:
     """``(phase, seconds, t_end, cause)`` of JAX's compile events since
-    jax was first seen (the newest 4096; of traces nested in one
-    another the outermost): phase is trace | lower | backend |
-    cache_read, t_end on ``perf_counter``, cause the ``(name,
-    step_num)`` of the program phase open on the compiling thread
-    (``phase``), or None."""
-    return list(_compile_log)
+    jax was first seen, oldest first (the newest 4096, and as many of
+    the traces under a millisecond, which cannot push the others out;
+    of traces nested in one another the outermost): phase is trace |
+    lower | backend | cache_read, t_end on ``perf_counter``, cause the
+    ``(name, step_num)`` of the program phase open on the compiling
+    thread (``phase``), or None."""
+    return sorted(list(_compile_log) + list(_short_traces),
+                  key=lambda e: e[2])
+
+
+# ----------------------------------------------------------------------
+# device time by the program's own names
+#
+# The program opens a ``jax.named_scope`` at the seams it has (one a
+# layer by the layer's type in ``model.py``; the words of ``PARTS``
+# inside the transformer block, ``opt`` around the update), JAX puts the
+# scopes into every HLO instruction's ``op_name`` and wraps them by what
+# made the instruction (``jvp(..)``, ``transpose(jvp(..))``,
+# ``rematted_computation``). A device trace names an operation by its
+# instruction alone (``%fusion.123``): ``device_scopes()`` is the table
+# from there to the ``op_name``, ``scope_of`` reads part and phase off
+# one. Metadata only: the lowered program is the same text without it.
+
+PARTS = (
+    "attn_proj",     # the wqkv / wo products; MLA's wqa, wqb, wkva, wkvb
+    "attn_prep",     # q/k norms and rotation, kernel or plain; MLA's
+                     # rope; q * scale
+    "attn_core",     # the attend, its custom_vjp whole: the kernels and
+                     # the pads, delta, transposes XLA runs around them
+    "idx_proj",      # the learned selection's three projections
+    "idx",           # the rest of the indexer: its LayerNorm, rotation,
+                     # key slots, dsa_select, dsa_kl
+    "norm",          # the block's rmsnorm passes, MLA's latent norms
+    "mlp",           # the dense MLP, dense_first, the shared expert
+    "router",        # the router's product, scores, bias, top-k
+    "moe_dispatch",  # the sorted dispatch outside its grouped products:
+                     # sort, gather, scatter-add, pair weights, zero-fills
+    "moe_experts",   # the grouped products and the activation between
+    "opt",           # updater.py: the clip's norm and the update; the
+                     # one word outside any layer
+)
+PHASES = ("fwd", "bwd", "replay", "opt", "other")
+
+_programs: Dict[str, tuple] = {}        # name -> (jitted, abstract args)
+_scopes: Dict[str, Dict[str, str]] = {}  # name -> {instruction: op_name}
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = ")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+
+def note_program(name: str, jitted, specs) -> None:
+    """``jitted`` (a ``jax.jit`` function) was dispatched on arguments
+    of the shapes ``specs`` (``ShapeDtypeStruct`` trees, never a device
+    array) under ``name``: what ``device_scopes()`` lowers when somebody
+    asks. The newest of a name replaces the older. The note keeps the
+    function, and through its closure its owner's host objects, alive
+    until then: one slot a name, no device memory."""
+    _programs[name] = (jitted, specs)
+    _scopes.pop(name, None)
+
+
+def _layer_types():
+    """The layer types ``model.py`` opens a scope for, where the process
+    has loaded the layers at all."""
+    mod = sys.modules.get(__name__.rsplit(".", 2)[0] + ".layers")
+    return getattr(mod, "_REGISTRY", ())
+
+
+def scope_of(op_name: str):
+    """-> (part, phase) of an instruction by its ``op_name``. part: the
+    innermost word of ``PARTS`` in it, else the type of the layer whose
+    scope it lies in, else None. phase: ``replay`` (recomputed in the
+    backward pass under ``jax.checkpoint``), ``bwd``, ``fwd``, ``opt``
+    (the part ``opt``) or ``other`` (in no pass: the step's own
+    arithmetic around them)."""
+    # a component is a scope, wrapped by what transformed it
+    # (``transpose(jvp(stack))``), or a jitted function's own name
+    # (``jit(relu)``), which is no scope; the last is the primitive
+    # (``split``, ``tanh``: layer types too)
+    words = [w[-1] for w in (re.findall(r"\w+", c)
+                             for c in op_name.split("/")[:-1]
+                             if not c.startswith("jit(")) if w]
+    part = next((w for w in reversed(words) if w in PARTS), None)
+    if part is None:
+        layers = _layer_types()
+        part = next((w for w in words if w in layers), None)
+    if "transpose(" in op_name:
+        phase_ = "replay" if "rematted_computation" in words else "bwd"
+    elif "jvp(" in op_name:
+        phase_ = "fwd"
+    else:
+        phase_ = "opt" if part == "opt" else "other"
+    return part, phase_
+
+
+def _compiled_table(jitted, specs) -> Dict[str, str]:
+    """{instruction: op_name} of the optimized HLO of ``jitted`` on
+    ``specs``, compiled under a cache key that holds the metadata: JAX
+    leaves it out by default, and an entry built before a scope was
+    opened would answer with the old names."""
+    import jax
+    key = "jax_compilation_cache_include_metadata_in_key"
+    was = getattr(jax.config, key)
+    jax.config.update(key, True)
+    try:
+        text = jitted.lower(*specs).compile().as_text()
+    finally:
+        jax.config.update(key, was)
+    table = {}
+    for line in text.splitlines():
+        m, op = _INSTRUCTION.match(line), _OP_NAME.search(line)
+        if m and op:
+            table[m.group(1)] = op.group(1)
+    return table
+
+
+def device_scopes() -> Dict[str, Dict[str, str]]:
+    """-> {program: {HLO instruction: op_name}} of every noted program's
+    executable, fusions included (a fusion carries its root's). Built at
+    the first call and kept: a lowering, a compilation (the first time
+    in a cache directory; a read of the persistent cache after) and a
+    parse, paid by whoever asks, never by a step. The lowering is a new
+    one (its private functions are numbered otherwise than the
+    dispatch's were, so the step's own cache entry does not answer it):
+    the same graph, to which XLA gives the same instruction names."""
+    for name, (jitted, specs) in list(_programs.items()):
+        if name not in _scopes:
+            with phase("trace.device_scopes", "compile",
+                       {"program": name}):
+                _scopes[name] = _compiled_table(jitted, specs)
+    return dict(_scopes)
 
 
 def active() -> Optional[Tracer]:

@@ -733,19 +733,23 @@ def _flash_dsa_fwd(q, k, v, qi, ki, wi, nkv, topk, scale, interpret, tile):
     T, n, G, d, P = _plan(*dims, tile)
     # the scale folded into q once; the scaled q is what the backward
     # kernels take (the chain rule's factor goes on dq at its flush)
-    qs = q * jnp.asarray(scale, q.dtype)
-    kr = _key_slots(ki, P)
-    wt = wi.astype(jnp.float32).transpose(0, 2, 1)
+    # (the scopes: obs.trace.PARTS; the rest is the caller's attend)
+    with jax.named_scope("attn_prep"):
+        qs = q * jnp.asarray(scale, q.dtype)
+    b, S, idim = ki.shape
     with trace.span("dsa.plan", "kernel", plan_mark(
             "fwd", *dims, topk, tile, q.dtype.itemsize)):
-        lsei, words = _select_call(qi, kr, wt, topk, P, T, interpret)
+        with jax.named_scope("idx"):
+            kr = _key_slots(ki, P)
+            wt = wi.astype(jnp.float32).transpose(0, 2, 1)
+            lsei, words = _select_call(qi, kr, wt, topk, P, T, interpret)
         o, lse, cnt = _fwd_call(qs, k, v, words, nkv, T, interpret)
         o, lse = _kept(o, lse)
-        kl, dqi, dkr, dwt = _kl_call(qs, k, lse, qi, kr, wt, words, lsei,
-                                     nkv, T, P, interpret)
-    b, S, idim = ki.shape
-    grads = (dqi, dkr.reshape(b, S, P, idim).sum(2),
-             dwt.transpose(0, 2, 1))
+        with jax.named_scope("idx"):
+            kl, dqi, dkr, dwt = _kl_call(qs, k, lse, qi, kr, wt, words,
+                                         lsei, nkv, T, P, interpret)
+            grads = (dqi, dkr.reshape(b, S, P, idim).sum(2),
+                     dwt.transpose(0, 2, 1))
     out = (o, kl.sum((1, 2)), cnt.sum((1, 2)).astype(jnp.int32))
     # (the last: the dtypes of qi, ki, wi, which a residual can only
     # carry on an array)
@@ -764,9 +768,10 @@ def _flash_dsa_bwd(nkv, topk, scale, interpret, tile, res, g):
             "bwd", *dims, topk, tile, qs.dtype.itemsize)):
         dq, dk, dv = _bwd_call(qs, k, v, o, lse, do, words, nkv, T, scale,
                                interpret)
-    f = dkl.astype(jnp.float32)[:, None, None]
-    return (dq, dk, dv) + tuple((x * f).astype(z.dtype)
-                                for x, z in zip(grads, like))
+    with jax.named_scope("idx"):
+        f = dkl.astype(jnp.float32)[:, None, None]
+        return (dq, dk, dv) + tuple((x * f).astype(z.dtype)
+                                    for x, z in zip(grads, like))
 
 
 _flash_dsa.defvjp(_flash_dsa_fwd, _flash_dsa_bwd)

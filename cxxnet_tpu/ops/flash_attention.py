@@ -1680,8 +1680,9 @@ def _flash_gq_fwd(q, k, v, nkv, mask, block_len, scale, interpret, tile):
     segs, L, T, n, shift = plan
     # the scale folded into q once; the scaled q is what the backward
     # kernels take (the chain rule's factor goes on dq at its flush)
-    qs, ks, vs = (_gq_pad(x, segs, L, n * T)
-                  for x in (q * jnp.asarray(scale, q.dtype), k, v))
+    with jax.named_scope("attn_prep"):      # obs.trace.PARTS
+        qs = q * jnp.asarray(scale, q.dtype)
+    qs, ks, vs = (_gq_pad(x, segs, L, n * T) for x in (qs, k, v))
     with trace.span("flash.plan", "kernel",
                     _gq_mark("fwd", *dims, nkv, mask, block_len, plan)):
         o, lse = _kept(*_gq_fwd_call(qs, ks, vs, nkv, mask, n, shift,

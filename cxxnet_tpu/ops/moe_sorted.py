@@ -115,8 +115,9 @@ def _gmm(lhs, rhs, sizes, interpret, transpose_rhs=False):
     by expert with ``sizes`` rows each. Rows past their sum are not
     computed and hold nothing defined."""
     k, n = rhs.shape[1:][::-1] if transpose_rhs else rhs.shape[1:]
-    return _kernel("gmm", lhs, rhs, sizes, None, jnp.float32, _tile(k, n),
-                   transpose_rhs, interpret)
+    with jax.named_scope("moe_experts"):
+        return _kernel("gmm", lhs, rhs, sizes, None, jnp.float32,
+                       _tile(k, n), transpose_rhs, interpret)
 
 
 def _tgmm(lhs, rhs, sizes, into, interpret):
@@ -125,9 +126,10 @@ def _tgmm(lhs, rhs, sizes, into, interpret):
     (out, in) layout made XLA keep the masters and both moments
     transposed inside the step and copy them at its edges, 22 ms of a 355
     ms step: my chip run, PR 27)."""
-    return _kernel("tgmm", lhs.swapaxes(0, 1), rhs, sizes, into,
-                   jnp.float32, _tile(lhs.shape[1], rhs.shape[1]), False,
-                   interpret)
+    with jax.named_scope("moe_experts"):
+        return _kernel("tgmm", lhs.swapaxes(0, 1), rhs, sizes, into,
+                       jnp.float32, _tile(lhs.shape[1], rhs.shape[1]),
+                       False, interpret)
 
 
 def _walk(x, w1, order, counts, topk, chunk, interpret):
@@ -169,7 +171,8 @@ def experts(x, wp, w1, w2, order, counts, topk: int, chunk: int,
 
     def body(i, out):
         pair, tok, valid, sizes, _, gate, up = piece(i)
-        h = (jax.nn.silu(gate) * up).astype(dt)
+        with jax.named_scope("moe_experts"):
+            h = (jax.nn.silu(gate) * up).astype(dt)
         ys = _gmm(h, w2, sizes, interpret) * jnp.take(wp, pair)[:, None]
         return out.at[tok].add(jnp.where(valid, ys, 0))
 
@@ -191,8 +194,9 @@ def _experts_bwd(topk, chunk, interpret, res, g):
     def body(i, carry):
         dx, dwp, dw1, dw2 = carry
         pair, tok, valid, sizes, xs, gate, up = piece(i)
-        sg = jax.nn.sigmoid(gate)
-        h = (gate * sg * up).astype(dt)
+        with jax.named_scope("moe_experts"):
+            sg = jax.nn.sigmoid(gate)
+            h = (gate * sg * up).astype(dt)
         w = jnp.take(wp, pair)[:, None]
         gs = jnp.where(valid, jnp.take(g, tok, axis=0), 0)
         # g W2^T, once: with h it is the pair's weight's gradient, times
@@ -203,9 +207,10 @@ def _experts_bwd(topk, chunk, interpret, res, g):
         dw2 = _tgmm(h, (gs.astype(jnp.float32) * w).astype(dt), sizes, dw2,
                     interpret)
         dh = dhu * w
-        da = jnp.concatenate(
-            [dh * up * sg * (1 + gate * (1 - sg)), dh * gate * sg],
-            -1).astype(dt)
+        with jax.named_scope("moe_experts"):
+            da = jnp.concatenate(
+                [dh * up * sg * (1 + gate * (1 - sg)), dh * gate * sg],
+                -1).astype(dt)
         dw1 = _tgmm(xs, da, sizes, dw1, interpret)
         dxs = jnp.where(valid, _gmm(da, w1, sizes, interpret, True), 0)
         return dx.at[tok].add(dxs), dwp, dw1, dw2
@@ -250,11 +255,18 @@ def moe_sorted(x, lp, *, topk: int, total: int, first: int, held: int,
             "tokens": x.shape[0], "rows": n, "chunk": chunk,
             "tile": GMM_TILE[0], "score": score,
             "shared": int("ws1" in lp)}):
-        w, idx = route(x, lp["gate"], topk, norm_topk, score,
-                       lp.get("gbias"), scale)
-        order, counts, stats = plan(idx, first, held, GMM_TILE[0])
-    y = experts(x.astype(dt), w.reshape(-1), lp["w1"].astype(dt),
-                lp["w2"].astype(dt), order, counts, topk, chunk, interpret)
+        with jax.named_scope("router"):
+            w, idx = route(x, lp["gate"], topk, norm_topk, score,
+                           lp.get("gbias"), scale)
+        with jax.named_scope("moe_dispatch"):
+            order, counts, stats = plan(idx, first, held, GMM_TILE[0])
+    # the words of obs.trace.PARTS: what is not a grouped product or the
+    # activation between two (moe_experts, inside) is the dispatch's
+    with jax.named_scope("moe_dispatch"):
+        y = experts(x.astype(dt), w.reshape(-1), lp["w1"].astype(dt),
+                    lp["w2"].astype(dt), order, counts, topk, chunk,
+                    interpret)
     if "ws1" in lp:
-        y = y + shared_expert(x.astype(dt), lp["ws1"], lp["ws2"], dt)
+        with jax.named_scope("mlp"):
+            y = y + shared_expert(x.astype(dt), lp["ws1"], lp["ws2"], dt)
     return y, stats

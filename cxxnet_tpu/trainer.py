@@ -1097,6 +1097,10 @@ class Trainer:
                     lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
                     (self._params, self._opt_state, self._rng,
                      self._epoch_dev, self._maccum, data, extras, labels))
+                # what a reader of a device trace lowers again to name
+                # the step's instructions (obs.trace.device_scopes)
+                _trace.note_program("train_step", self._train_step,
+                                    self._step_specs)
             (self._params, self._opt_state, self._rng, self._epoch_dev,
              self._maccum, loss, stats) = self._train_step(
                 self._params, self._opt_state, self._rng, self._epoch_dev,
@@ -1189,13 +1193,16 @@ class Trainer:
         if self._step_specs is None:
             # per-step abstract specs (group element 0), so
             # step_cost_analysis reports ONE step's flops either path
-            elem = jax.tree.map(
-                lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype),
-                (data_s, extras_s, labels_s))
-            self._step_specs = jax.tree.map(
-                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
-                (self._params, self._opt_state, self._rng,
-                 self._epoch_dev, self._maccum)) + elem
+            def specs(tree, lead=0):
+                return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+                    x.shape[lead:], x.dtype), tree)
+            state = specs((self._params, self._opt_state, self._rng,
+                           self._epoch_dev, self._maccum))
+            self._step_specs = state + specs(group.device, 1)
+            # (the program that runs is the group's: what a reader of a
+            # device trace lowers again, obs.trace.device_scopes)
+            _trace.note_program("train_step", self._train_multi,
+                                state + specs(group.device))
         (self._params, self._opt_state, self._rng, self._epoch_dev,
          self._maccum, self.last_loss) = self._train_multi(
             self._params, self._opt_state, self._rng, self._epoch_dev,

@@ -24,6 +24,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 
 ConfigEntry = Tuple[str, str]
@@ -366,7 +367,12 @@ class NetUpdater:
         return states
 
     def apply(self, params, grads, opt_state, epoch):
-        """One optimizer step over the whole net (pure)."""
+        """One optimizer step over the whole net (pure), under the one
+        word of ``obs.trace.PARTS`` outside any layer."""
+        with jax.named_scope("opt"):
+            return self._apply(params, grads, opt_state, epoch)
+
+    def _apply(self, params, grads, opt_state, epoch):
         if self.clip_global_norm > 0.0:
             sq = jnp.zeros((), jnp.float32)
             for li, g in enumerate(grads):

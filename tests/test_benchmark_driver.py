@@ -46,6 +46,7 @@ NODES = [
     "::test_the_readers_read_what_the_program_leaves",
     "benchmark/tests/test_keye.py"
     "::test_the_program_counts_what_the_reader_reads",
+    "benchmark/tests/test_scope_time.py",
 ]
 
 

@@ -323,6 +323,18 @@ def test_mla_blocks_under_remat_run_their_forward_kernel_once(one_chip):
     assert _kernel_calls(text) == {
         "flash_mla_fwd": blocks, "flash_mla_dq": blocks,
         "flash_mla_dkv": blocks}
+    # each kernel's instruction carries the block's part and its pass
+    # (obs.trace.scope_of): the attend, forward once and never replayed
+    import re
+    scopes = {m.group(1): obs_trace.scope_of(m.group(2))
+              for m in re.finditer(
+                  r'^\s*(?:ROOT )?%?([\w.\-]+) = .*tpu_custom_call.*'
+                  r'op_name="([^"]*)"', text, re.M)}
+    assert len(scopes) == 3 * blocks
+    assert {(re.sub(r"[.\d]+$", "", k), v) for k, v in scopes.items()} == {
+        ("flash_mla_fwd", ("attn_core", "fwd")),
+        ("flash_mla_dq", ("attn_core", "bwd")),
+        ("flash_mla_dkv", ("attn_core", "bwd"))}
     assert plan["blocks"] == blocks and plan["kept"] == "attn_out,attn_lse"
     assert plan["kept_bytes"] / blocks \
         == b * S * nh * dv * 2 + b * nh * S * 4 == 68157440
