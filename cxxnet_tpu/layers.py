@@ -26,6 +26,7 @@ convs sit directly on the MXU via ``jnp.dot`` / ``lax.conv_general_dilated``.
 
 from __future__ import annotations
 
+import collections
 import math
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
@@ -1930,16 +1931,40 @@ class TransformerStackLayer(Layer):
     width ``nhidden_mlp`` (default 4*embed). Config: ``nlayer``,
     ``nhead``, ``causal``, ``nhidden_mlp``, ``n_microbatch`` (pipeline
     microbatches per local batch, default = pipe size), ``remat``
-    (rematerialize each block's intermediates in the backward pass —
-    jax.checkpoint — except its attend: two (b, s, e)-sized activations
-    per layer are kept instead of every intra-block tensor, the block's
-    input and its attention kernel's output (with the log-sum-exp, a
-    head's row a position), so the backward pass replays the
-    projections and the MLP but never the forward kernel, the costliest
-    value of a block per byte kept. Where no kernel is taken — the XLA
-    twins, ring, ulysses — only the input is kept and the whole block is
-    replayed. The standard FLOPs-for-HBM trade for deep stacks; a traced
-    step says what it keeps in a ``remat.plan`` span).
+    (rematerialize each block in the backward pass — jax.checkpoint —
+    except what its body hands out under the names ``ops.kept.KEPT``, a
+    constant of the code and the one list of what is kept beside the
+    block's input: (1) its attention kernel's output and log-sum-exp (a
+    head's row a position), so the backward pass never replays the
+    forward kernel, the costliest value of a block per byte kept: with
+    the input two (b, s, e)-sized activations a layer, all that the
+    plain and the grouped-query block keep — their ``wqkv`` / ``w1``
+    results have no name, because a user who sets ``remat = 1`` there
+    does so for the 3e- and 4e-wide hidden; where no kernel is taken —
+    the XLA twins, ring, ulysses — the attend is replayed with the
+    rest; (2) the narrow values of latent attention's block and of the
+    sorted dispatch that cost a replay much, each as it is made:
+    ``attn = mla``'s ``wqa``, ``wkc`` and ``wkr`` products
+    (``attn_latent``: the ``q_rank`` + ``kv_rank`` + ``d_rope`` wide
+    latents and shared key) and its ``wo`` product (``attn_wo``: embed
+    wide), ``moe_dispatch = sorted``'s router logits
+    (``router_logits``: float32, ``nexpert`` wide) with the chosen
+    experts and their scores (``router_topk``: no product, but the
+    top-k and the gather that make them cost more than the router's
+    product) and its shared expert's first product (``mlp_gate_up``:
+    twice that MLP's width; ``dense_first``'s layer hands its own out
+    under the same name, ``2 nhidden_dense`` wide, once a stack). The
+    kernel's operands (the ``wqn``, ``wqr``, ``wkn`` and ``wv``
+    products, ``nhead (2 d_nope + d_rope + d_v)`` wide) and the routed
+    experts' rows are replayed: they are what ``remat = 1`` is rid of.
+    In compute-dtype values a position and block that is ``embed +
+    nhead d_v`` before (2) and ``q_rank + kv_rank + d_rope + nhead d_v
+    + 2 embed + 2 nexpert + 2 moe_shared nhidden_mlp`` (and 12 bytes a
+    chosen expert) with it: 12.4 kB -> 24.9 kB a position at
+    DeepSeek-V3-style widths in bfloat16 (docs/config.md), against
+    every intra-block tensor under ``remat = 0``. The standard
+    FLOPs-for-HBM trade for deep stacks; a traced step says what it
+    keeps, by name and in bytes, in a ``remat.plan`` span).
 
     Options of the same block (each off by default; any of them takes
     the grouped block, ``_block_fn``'s second body):
@@ -2820,7 +2845,7 @@ class TransformerStackLayer(Layer):
         their even dims first (``ops.flash_attention.rope_pairs``)."""
         from jax.sharding import PartitionSpec as P
         from .ops import flash_attention as fa
-        from .ops import pallas_env
+        from .ops import kept, pallas_env
         nh, dr = self.nhead, self.d_rope
         theta = float(self.rope_theta)
         rows = pallas_env.rows_spec(mesh)
@@ -2840,22 +2865,29 @@ class TransformerStackLayer(Layer):
 
         def attention(lp, h):
             b, s, _ = h.shape
-            proj = _part("attn_proj")(lambda x, w: jnp.einsum(
-                "bse,fe->bsf", x, lp[w].astype(dt)))
+            @_part("attn_proj")
+            def proj(x, w, name=None):
+                # the narrow products go out under the name a block
+                # under remat = 1 keeps them by (ops/kept.py), as they
+                # leave the matmul: the norms, the rotation and the
+                # kernel's wide operands are what a replay computes
+                y = jnp.einsum("bse,fe->bsf", x, lp[w].astype(dt))
+                return kept.keep(y, name) if name else y
             pos = jnp.arange(s)
             x = rmsnorm(h, lp["norm1"])
-            cq = rmsnorm(proj(x, "wqa"), lp["qanorm"])
-            ckv = rmsnorm(proj(x, "wkc"), lp["kvnorm"])
+            cq = rmsnorm(proj(x, "wqa", "attn_latent"), lp["qanorm"])
+            ckv = rmsnorm(proj(x, "wkc", "attn_latent"), lp["kvnorm"])
             with _part("attn_prep"):
                 qr = fa.rope_pairs(
                     proj(cq, "wqr").reshape(b, s, nh, dr), pos, theta,
                     True).reshape(b, s, nh * dr)
-                kr = fa.rope_pairs(proj(x, "wkr")[:, :, None], pos, theta,
-                                   True)[:, :, 0]
+                kr = fa.rope_pairs(
+                    proj(x, "wkr", "attn_latent")[:, :, None], pos, theta,
+                    True)[:, :, 0]
             att = attend(proj(cq, "wqn"), qr, proj(ckv, "wkn"), kr,
                          proj(ckv, "wv"))
             with _part("attn_proj"):
-                return h + proj(att, "wo")
+                return h + proj(att, "wo", "attn_wo")
         return attention
 
     def _fold_norms(self, params, dt):
@@ -2928,6 +2960,7 @@ class TransformerStackLayer(Layer):
         pipe = mesh.shape.get("pipe", 1) if mesh is not None else 1
         from .obs import trace
         from .ops import flash_attention as fa
+        from .ops import kept
         use_flash = fa.resolve_impl(self.attn_impl, ctx.platform,
                                     s) == "pallas"
         interp = ctx.platform != "tpu"
@@ -2967,19 +3000,14 @@ class TransformerStackLayer(Layer):
         depth = self.nlayer
         folded = self._fold_norms(params, dt)
         if self.remat:
-            # replayed in the backward pass except the attend: the
-            # forward kernel's two results are kept by name (the class
-            # docstring), so it runs once a block
+            # replayed in the backward pass except what a block hands
+            # out under kept.KEPT's names (the class docstring): the
+            # attend's two results, so the forward kernel runs once a
+            # block, and the narrow values that cost a replay much
             block = jax.checkpoint(
                 block, policy=jax.checkpoint_policies.save_only_these_names(
-                    *fa.KEPT))
-            with trace.span("remat.plan", "kernel") as sp:
-                if sp is not trace.NOOP_SPAN:
-                    one = jax.tree.map(lambda v: jax.ShapeDtypeStruct(
-                        v.shape[1:], v.dtype), folded)
-                    sp.note(layer=ctx.layer_index, blocks=depth,
-                            kept=",".join(fa.KEPT),
-                            kept_bytes=depth * fa.kept_bytes(block, one, h))
+                    *kept.KEPT))
+        lp0 = None
         if self.dense_first:
             # layer 0, with the dense MLP's leaves; the scan (or the
             # unrolled loop) takes the rest, whose expert leaves are
@@ -2987,6 +3015,23 @@ class TransformerStackLayer(Layer):
             lp0 = {k: v[0] for k, v in folded.items()
                    if k not in self._EXPERT_TAGS}
             lp0.update(w1d=params["w1d"], w2d=params["w2d"])
+        if self.remat:
+            with trace.span("remat.plan", "kernel") as sp:
+                if sp is not trace.NOOP_SPAN:
+                    # layer 0's own names once, the others' by a block
+                    # of theirs (a leaf's shape less its depth)
+                    one = jax.tree.map(lambda v: jax.ShapeDtypeStruct(
+                        v.shape[1:], v.dtype), folded)
+                    held = collections.Counter()
+                    for n, lp in ((1, lp0), (depth - bool(lp0), one)):
+                        if lp:
+                            for name, nbytes in kept.kept_bytes(
+                                    block, lp, h).items():
+                                held[name] += n * nbytes
+                    sp.note(layer=ctx.layer_index, blocks=depth,
+                            kept=",".join(k for k in kept.KEPT if k in held),
+                            kept_bytes=sum(held.values()))
+        if lp0:
             h, _ = block(lp0, h)
             folded = {k: v if k in self._EXPERT_TAGS else v[1:]
                       for k, v in folded.items()}

@@ -39,45 +39,18 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
-NEG_INF = -1e30
+from .kept import KEPT
 
-# The names under which every forward rule below hands out its attend's
-# output and log-sum-exp: ``jax.checkpoint(...,
-# policy=save_only_these_names(*KEPT))`` (``transformer_stack`` under
-# ``remat = 1``) keeps the two and replays the rest of its block, so the
-# forward kernel runs once a block. They are the costliest values of a
-# block per byte kept, and as wide as the boundary activation remat
-# already keeps.
-KEPT = ("attn_out", "attn_lse")
+NEG_INF = -1e30
 
 
 def _kept(o, lse):
-    """A forward kernel's two results under their ``KEPT`` names. What
-    comes back is what goes into the primal output AND the residuals, so
+    """A forward kernel's two results under their ``KEPT`` names
+    (``ops/kept.py``: what ``remat = 1`` keeps of a block). What comes
+    back is what goes into the primal output AND the residuals, so
     nothing downstream holds an unnamed copy. Outside a ``jax.checkpoint``
     a name is an identity that lowers to nothing."""
     return checkpoint_name(o, KEPT[0]), checkpoint_name(lse, KEPT[1])
-
-
-def _eqns(jaxpr):
-    """Every equation of ``jaxpr``, those of the jaxprs it holds (a
-    ``custom_vjp``'s, a ``jit``'s, a ``shard_map``'s) included."""
-    for eqn in jaxpr.eqns:
-        yield eqn
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from _eqns(sub)
-
-
-def kept_bytes(fn, *args) -> int:
-    """Bytes of the values one call of ``fn(*args)`` (arrays or their
-    shapes) hands out under the ``KEPT`` names: what a ``jax.checkpoint``
-    keeping those names holds of it beside its input. 0 where no kernel
-    of this file is taken. Traces ``fn`` once more: for a span, not for
-    a step's path."""
-    return sum(v.aval.size * v.aval.dtype.itemsize
-               for eqn in _eqns(jax.make_jaxpr(fn)(*args).jaxpr)
-               if eqn.primitive.name == "name" and eqn.params["name"] in KEPT
-               for v in eqn.outvars)
 
 
 def _named_call(name, kernel, **kw):

@@ -29,6 +29,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from . import kept
+
 GMM_TILE = (512, 512, 512)      # rows, contraction, columns a tile
 CHUNK = 8192                    # rows of the sorted order a piece
 STATS = ("pairs", "load_max", "rows_computed")
@@ -42,14 +44,23 @@ def route(x, router_w, topk: int, norm_topk: bool, score: str = "softmax",
     scores for the choice alone (a selection bias: the weights are the
     unbiased scores of the chosen, and no gradient reaches it);
     ``scale`` multiplies the weights last. Experts are not grouped: a
-    router with ``n_group = topk_group = 1`` limits nothing."""
-    logits = jnp.dot(x.astype(jnp.float32),
-                     router_w.astype(jnp.float32).T,
-                     precision=lax.Precision.HIGHEST)
+    router with ``n_group = topk_group = 1`` limits nothing. The logits
+    go out under the name ``router_logits`` and the choice, the chosen
+    experts and their scores as the top-k and the gather leave them,
+    under ``router_topk`` (``kept.KEPT``): a block under ``remat = 1``
+    keeps them and replays neither the float32 product nor the top-k
+    and the gather, which cost nearly eight times the product on the
+    TPU (4.7 against 0.6 ms a step of five routers over 8,192
+    positions: PERF.md §6, PR 37), only the scores for their
+    derivative."""
+    logits = kept.keep(jnp.dot(
+        x.astype(jnp.float32), router_w.astype(jnp.float32).T,
+        precision=lax.Precision.HIGHEST), "router_logits")
     if score == "softmax" and bias is None:
         # (what the branch below computes for these arguments, kept in
         # the form the softmax router's compiled step already has)
         w, idx = lax.top_k(jax.nn.softmax(logits, axis=-1), topk)
+        w, idx = (kept.keep(v, "router_topk") for v in (w, idx))
         if norm_topk:
             w = w / jnp.sum(w, axis=-1, keepdims=True)
     else:
@@ -57,8 +68,8 @@ def route(x, router_w, topk: int, norm_topk: bool, score: str = "softmax",
             else jax.nn.softmax(logits, axis=-1)
         chosen = sc if bias is None else sc + lax.stop_gradient(
             bias.astype(jnp.float32))
-        _, idx = lax.top_k(chosen, topk)
-        w = jnp.take_along_axis(sc, idx, axis=-1)
+        idx = kept.keep(lax.top_k(chosen, topk)[1], "router_topk")
+        w = kept.keep(jnp.take_along_axis(sc, idx, axis=-1), "router_topk")
         if norm_topk:
             w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
     return (w * scale if scale != 1.0 else w), idx
@@ -228,9 +239,11 @@ experts.defvjp(_experts_fwd, _experts_bwd)
 def shared_expert(x, ws1, ws2, dt):
     """x (P, e) -> (P, e): the expert every token passes, a gated SiLU
     MLP as the routed ones, in plain XLA: ``ws1`` (2m, e) the gate
-    projection's rows then the up projection's, ``ws2`` (e, m)."""
+    projection's rows then the up projection's, ``ws2`` (e, m). The
+    first product goes out under the name ``mlp_gate_up``
+    (``kept.KEPT``), for a block under ``remat = 1`` to keep."""
     m = ws2.shape[1]
-    a = jnp.dot(x, ws1.astype(dt).T)
+    a = kept.keep(jnp.dot(x, ws1.astype(dt).T), "mlp_gate_up")
     a = (jax.nn.silu(a[:, :m].astype(jnp.float32))
          * a[:, m:].astype(jnp.float32)).astype(dt)
     return jnp.dot(a, ws2.astype(dt).T)

@@ -283,25 +283,28 @@ def test_flash_attention_mla_compiles(one_chip):
                                    "flash_mla_dkv"}
 
 
-def test_mla_blocks_under_remat_run_their_forward_kernel_once(one_chip):
+MLA_BLOCKS = dict(b=2, S=4096, nh=32, dn=128, dr=64, dv=128, q_rank=1536,
+                  kv_rank=512, e=2048, blocks=2)
+
+
+@pytest.fixture(scope="module")
+def mla_remat_blocks(one_chip):
     """The same cell's blocks as they train, ``remat = 1``: two layers of
     the stack at the published widths (a dense MLP in the experts'
-    place), bfloat16, the gradient compiled. The backward pass replays a
-    block's projections and not its attend: ``flash_mla_fwd`` is called
-    once a block (three times in all with nothing kept by name: XLA
-    merges the last block's replay with the forward pass it follows, and
-    no other), and the ``remat.plan`` span counts what is kept for it: a
-    block's ``o``, (2, 4096, 32 x 128) bfloat16, and its log-sum-exp, a
-    float32 a head a position."""
+    place), bfloat16, the gradient compiled once for the tests below:
+    -> (the compiled text, the ``remat.plan`` span's fields, the
+    compiler's count of the program's temporaries in bytes)."""
     from cxxnet_tpu import layers as L
     from cxxnet_tpu.obs import trace as obs_trace
-    b, S, e, nh, dv, blocks = 2, 4096, 2048, 32, 128, 2
+    b, S, e, blocks = (MLA_BLOCKS[k] for k in ("b", "S", "e", "blocks"))
     st = L.create_layer("transformer_stack", [
         (k, str(v)) for k, v in dict(
-            nlayer=blocks, scan_unroll=blocks, nhead=nh, causal=1,
-            attn="mla", q_rank=1536, kv_rank=512, d_nope=128, d_rope=64,
-            d_v=dv, rope_theta=32000000, mlp_act="swiglu",
-            nhidden_mlp=768, remat=1).items()])
+            nlayer=blocks, scan_unroll=blocks, nhead=MLA_BLOCKS["nh"],
+            causal=1, attn="mla", q_rank=MLA_BLOCKS["q_rank"],
+            kv_rank=MLA_BLOCKS["kv_rank"], d_nope=MLA_BLOCKS["dn"],
+            d_rope=MLA_BLOCKS["dr"], d_v=MLA_BLOCKS["dv"],
+            rope_theta=32000000, mlp_act="swiglu", nhidden_mlp=768,
+            remat=1).items()])
     st.infer_shape([(b, 1, S, e)])
 
     def loss(p, x):
@@ -315,11 +318,26 @@ def test_mla_blocks_under_remat_run_their_forward_kernel_once(one_chip):
         jax.ShapeDtypeStruct((b, 1, S, e), jnp.float32)))
     tr = obs_trace.start()
     try:
-        text = jax.jit(jax.grad(loss)).lower(*shapes).compile().as_text()
+        compiled = jax.jit(jax.grad(loss)).lower(*shapes).compile()
         (plan,) = [ev["args"] for ev in tr.trace_events()
                    if ev.get("name") == "remat.plan"]
     finally:
         obs_trace.stop()
+    return (compiled.as_text(), plan,
+            compiled.memory_analysis().temp_size_in_bytes)
+
+
+def test_mla_blocks_under_remat_run_their_forward_kernel_once(
+        mla_remat_blocks):
+    """The backward pass replays no attend: ``flash_mla_fwd`` is called
+    once a block (three times in all with nothing kept by name: XLA
+    merges the last block's replay with the forward pass it follows, and
+    no other), and the ``remat.plan`` span counts what is kept for it
+    among the rest: a block's ``o``, (2, 4096, 32 x 128) bfloat16, and
+    its log-sum-exp, a float32 a head a position."""
+    from cxxnet_tpu.obs import trace as obs_trace
+    text, plan, _ = mla_remat_blocks
+    blocks = MLA_BLOCKS["blocks"]
     assert _kernel_calls(text) == {
         "flash_mla_fwd": blocks, "flash_mla_dq": blocks,
         "flash_mla_dkv": blocks}
@@ -335,9 +353,104 @@ def test_mla_blocks_under_remat_run_their_forward_kernel_once(one_chip):
         ("flash_mla_fwd", ("attn_core", "fwd")),
         ("flash_mla_dq", ("attn_core", "bwd")),
         ("flash_mla_dkv", ("attn_core", "bwd"))}
-    assert plan["blocks"] == blocks and plan["kept"] == "attn_out,attn_lse"
-    assert plan["kept_bytes"] / blocks \
-        == b * S * nh * dv * 2 + b * nh * S * 4 == 68157440
+    assert plan["blocks"] == blocks
+    assert plan["kept"].split(",")[:2] == ["attn_out", "attn_lse"]
+
+
+def test_mla_blocks_under_remat_replay_the_operands_alone(mla_remat_blocks):
+    """The same compile: of a block's eight projections the four narrow
+    ones are kept by name (``kept.KEPT``: the two latents, the shared
+    key, ``wo``), so the replayed pass of the compiled text holds the
+    kernel's four operands' products alone (``wqr``'s, ``nh * dr`` =
+    2,048 wide; ``wqn``'s, ``wkn``'s and ``wv``'s, 4,096 wide), where
+    the forward pass holds all eight a block; it replays the dense
+    MLP's first product too, which has no name here. The span counts
+    the kept values' bytes a block, and the compiler's count of the
+    program's temporaries holds them (766 MB over 273 MB kept; 998 over
+    742 with the operands kept too)."""
+    import collections
+    import re
+    from cxxnet_tpu.obs import trace as obs_trace
+    text, plan, temp = mla_remat_blocks
+    c = MLA_BLOCKS
+    products = collections.Counter(
+        obs_trace.scope_of(m.group(2)) + (int(np.prod(
+            [int(n) for n in m.group(1).split(",")][2:])),)
+        for m in re.finditer(
+            r'^.* = bf16\[([\d,]+)\]\S* convolution\(.*op_name="([^"]*)"',
+            text, re.M))
+    replayed = {width: n for (part, phase, width), n in products.items()
+                if (part, phase) == ("attn_proj", "replay")}
+    assert replayed == {c["nh"] * c["dr"]: c["blocks"],
+                        c["nh"] * c["dn"]: 3 * c["blocks"]}
+    assert sum(n for (part, phase, _), n in products.items()
+               if (part, phase) == ("attn_proj", "fwd")) == 8 * c["blocks"]
+    assert {phase for part, phase, _ in products if part == "mlp"} \
+        == {"fwd", "bwd", "replay"}
+    assert plan["kept"] == "attn_out,attn_lse,attn_wo,attn_latent"
+    rows = c["b"] * c["S"]
+    assert plan["kept_bytes"] / c["blocks"] == 2 * rows * (
+        c["nh"] * c["dv"] + c["e"] + c["q_rank"] + c["kv_rank"] + c["dr"]) \
+        + 4 * rows * c["nh"] == 136314880
+    assert plan["kept_bytes"] < temp
+
+
+# ``jax.devices()[0].memory_stats()["bytes_limit"]`` of a v5e chip (read
+# on the chip, PR 37), 15.75 GiB: what the compiler refuses a step over,
+# by an account of its own ("Used 16.10G of 15.75G hbm") that is lower
+# than ``memory_analysis()``'s state + temporaries
+V5E_BYTES_LIMIT = 16909336064
+
+
+def test_joyai_step_under_remat_fits_the_chip(one_chip):
+    """``train.joyai_llm_flash.seq4096``'s whole train step at its real
+    size (``examples/transformer/joyai_llm_flash.conf``: 2 rows of 4,096
+    positions, ``remat = 1``), compiled from shapes alone: the loss and
+    its gradient, the clip and AdamW, parameters and moments donated, as
+    ``Trainer``'s step has them. It compiles (the compiler refuses a
+    step over the chip's memory), and by ``memory_analysis()`` the state
+    and the program's temporaries leave 0.4 GB of the chip's memory to
+    spare, with what ``kept.KEPT`` keeps of the six blocks among the
+    temporaries (a sum that counts a schedule's slack too: 15.0 GB here,
+    PERF.md §7 j)."""
+    import os
+    from cxxnet_tpu import config as conf_parser
+    from cxxnet_tpu.graph import NetConfig
+    from cxxnet_tpu.trainer import Trainer, _strip_nones
+    from cxxnet_tpu.updater import NetUpdater
+    tr = Trainer()
+    for k, v in conf_parser.parse_file(os.path.join(
+            os.path.dirname(__file__), os.pardir, "examples", "transformer",
+            "joyai_llm_flash.conf")):
+        tr.set_param(k, v)
+    tr.set_param("dev", "cpu:0")
+    tr.net_cfg = NetConfig()
+    tr.net_cfg.configure(tr.cfg)
+    tr._build_network()
+    net, rows, seq = tr.net, 2, 4096
+    net.platform = "tpu"
+    opt = NetUpdater(net)
+
+    def step(params, moments, data, labels, rng, epoch):
+        loss, grads = jax.value_and_grad(net.loss_fn)(
+            params, data, labels, rng, epoch)
+        return opt.apply(params, _strip_nones(grads), moments, epoch) \
+            + (loss,)
+
+    on = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    params = jax.eval_shape(net.init_params, jax.random.PRNGKey(0))
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
+    mem = jax.jit(step, donate_argnums=(0, 1)).lower(*jax.tree.map(on, (
+        params, jax.eval_shape(opt.init_state, params),
+        f32(rows, 1, seq, 1),
+        [f32(rows, 1)] * tr.net_cfg.label_name_map["label"]
+        + [f32(rows, seq)],
+        jax.eval_shape(lambda: jax.random.PRNGKey(0)),
+        jax.ShapeDtypeStruct((), jnp.int32)))).compile().memory_analysis()
+    state = mem.argument_size_in_bytes + mem.output_size_in_bytes \
+        - mem.alias_size_in_bytes
+    assert 8.1e9 < state < 8.3e9        # masters and two moments, float32
+    assert state + mem.temp_size_in_bytes < V5E_BYTES_LIMIT - 0.4e9
 
 
 def test_routed_layer_with_sigmoid_bias_and_shared_expert_compiles(

@@ -654,10 +654,11 @@ def test_remat_keeps_the_attend_and_replays_no_forward_kernel():
     """Under ``remat = 1`` the forward kernel runs once a block: its
     output and log-sum-exp go out under ``KEPT``'s names."""
     import collections
+    from cxxnet_tpu.ops import kept
     st, p, x = _layer(1)
     jaxpr = jax.make_jaxpr(jax.grad(_layer_loss(st)))(p, x).jaxpr
     calls = collections.Counter(
-        eqn.params["name"] for eqn in fa._eqns(jaxpr)
+        eqn.params["name"] for eqn in kept.eqns(jaxpr)
         if eqn.primitive.name == "pallas_call")
     assert calls["flash_dsa_fwd"] == 2
     assert calls["flash_dsa_dq"] == calls["flash_dsa_dkv"] == 2

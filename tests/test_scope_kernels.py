@@ -13,14 +13,14 @@ import pytest
 
 from cxxnet_tpu import layers as L
 from cxxnet_tpu.obs import trace as obs_trace
-from cxxnet_tpu.ops import flash_attention as fa
+from cxxnet_tpu.ops import kept
 
 
 def _pallas_scopes(fn, *args):
     """{kernel name: the innermost scopes its ``pallas_call`` equations
     lie in} over the jaxpr of ``fn(*args)``."""
     out = collections.defaultdict(set)
-    for eqn in fa._eqns(jax.make_jaxpr(fn)(*args).jaxpr):
+    for eqn in kept.eqns(jax.make_jaxpr(fn)(*args).jaxpr):
         if eqn.primitive.name == "pallas_call":
             # (megablox gives its calls no name of their own)
             out[eqn.params["name"] or "megablox"].add(
