@@ -123,3 +123,27 @@ def make_packfile(img_root, lst_path, bin_path, n, seed=0, side=48,
         f.write("\n".join(lines) + "\n")
     binpage.pack_images(str(lst_path), str(img_root), str(bin_path),
                         silent=True)
+
+
+def run_benchmark_node(node):
+    """Run one node of ``benchmark/tests`` in a child pytest from the
+    checkout's root and fail with its output unless it passes. The
+    child gets no virtual mesh: this file forces 8 host devices into
+    this process's environment, while ``benchmark/tests/conftest.py``
+    describes no topology and the driver counts the devices its weights
+    sit on."""
+    import subprocess
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    flags = [f for f in env.get("XLA_FLAGS", "").split()
+             if not f.startswith("--xla_force_host_platform_device_count")]
+    env["XLA_FLAGS"] = " ".join(flags)
+    # the thread pool force_host_cpu sizes for the 8 virtual devices
+    env.pop("PJRT_NPROC", None)
+    r = subprocess.run(
+        [sys.executable, "-m", "pytest", node, "-q",
+         "-p", "no:cacheprovider"],
+        cwd=root, env=env, capture_output=True, text=True, timeout=600)
+    assert r.returncode == 0, \
+        "%s exited %d\n%s\n%s" % (node, r.returncode,
+                                  r.stdout[-4000:], r.stderr[-2000:])

@@ -10,21 +10,20 @@ seed=)``, the spans of ``obs/trace.py`` and the registry's counters. A
 rename of any of them would otherwise pass here and fail on the chip.
 
 Each case runs one node of ``benchmark/tests`` as it stands, in a child
-process from the checkout's root: nothing under ``benchmark/`` is
-copied, edited or re-derived here, and a node that no longer exists
-exits 4 and fails. The child gets no virtual mesh: ``tests/conftest.py``
-forces 8 host devices into this process's environment, while
-``benchmark/tests/conftest.py`` describes no topology and the driver
-counts the devices its weights sit on.
-"""
+process from the checkout's root (``conftest.run_benchmark_node``):
+nothing under ``benchmark/`` is copied, edited or re-derived here, and a
+node that no longer exists exits 4 and fails.
 
-import os
-import subprocess
-import sys
+The nodes are spread over three files, this one (the harness's own
+tiny cell and the SDAR cell), ``test_benchmark_driver_joyai.py`` and
+``test_benchmark_driver_keye_lfm2.py``: ``--dist loadfile`` runs a file
+on one worker, and the sixteen nodes in one file made the longest
+stretch of the suite.
+"""
 
 import pytest
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from conftest import run_benchmark_node
 
 NODES = [
     "benchmark/tests/test_rehearsal.py"
@@ -36,41 +35,11 @@ NODES = [
     "benchmark/tests/test_program_spans.py",
     "benchmark/tests/test_sdar.py"
     "::test_the_counters_readers_read_what_the_program_leaves",
-    "benchmark/tests/test_joyai.py::test_rehearsal_untraced",
-    "benchmark/tests/test_joyai.py::test_rehearsal_traced",
-    "benchmark/tests/test_joyai.py"
-    "::test_the_readers_read_what_the_program_leaves",
-    "benchmark/tests/test_keye.py::test_rehearsal_untraced",
-    "benchmark/tests/test_keye.py::test_rehearsal_traced",
-    "benchmark/tests/test_keye.py"
-    "::test_the_readers_read_what_the_program_leaves",
-    "benchmark/tests/test_keye.py"
-    "::test_the_program_counts_what_the_reader_reads",
     "benchmark/tests/test_scope_time.py",
-    "benchmark/tests/test_lfm2.py::test_rehearsal_untraced",
-    "benchmark/tests/test_lfm2.py"
-    "::test_the_readers_read_what_the_program_leaves",
 ]
-
-
-def _child_env():
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    flags = [f for f in env.get("XLA_FLAGS", "").split()
-             if not f.startswith("--xla_force_host_platform_device_count")]
-    env["XLA_FLAGS"] = " ".join(flags)
-    # the thread pool force_host_cpu sizes for the 8 virtual devices
-    env.pop("PJRT_NPROC", None)
-    return env
 
 
 @pytest.mark.parametrize("node", NODES,
                          ids=[n.split("/")[-1] for n in NODES])
 def test_benchmark_rehearsal_node(node):
-    r = subprocess.run(
-        [sys.executable, "-m", "pytest", node, "-q",
-         "-p", "no:cacheprovider"],
-        cwd=REPO, env=_child_env(), capture_output=True, text=True,
-        timeout=600)
-    assert r.returncode == 0, \
-        "%s exited %d\n%s\n%s" % (node, r.returncode,
-                                  r.stdout[-4000:], r.stderr[-2000:])
+    run_benchmark_node(node)

@@ -215,8 +215,13 @@ def test_qk_prep_fwd_bwd_compiles(one_chip):
 def test_moe_sorted_compiles(one_chip):
     """The same cell's routed layer: 16,384 positions, top 8 of 128
     experts, 16 held, walked in pieces; megablox's kernels under the
-    names ``moe_expert_roofline.train`` matches, with or without a
-    ``jax.checkpoint`` around the layer."""
+    names ``moe_expert_roofline.train`` matches and ``moe_combine`` (a
+    piece's rows into their tokens', each pass), with or without a
+    ``jax.checkpoint`` around the layer. The combine of both passes is
+    one shape, traced and lowered once: its Mosaic body is in the
+    lowered program once, called from the forward and the backward
+    walk; and each of megablox's six products once, the forward's first
+    too, which the backward walk computes again."""
     from cxxnet_tpu.ops import moe_sorted as ms
     P, e, m, total, held, topk = 16384, 2048, 768, 128, 16, 8
 
@@ -228,11 +233,21 @@ def test_moe_sorted_compiles(one_chip):
 
     shapes = (((P, e), jnp.bfloat16), ((total, e), jnp.float32),
               ((held, e, 2 * m), jnp.float32), ((held, m, e), jnp.float32))
-    for fn in (layer, jax.checkpoint(layer)):
-        text = _compile(jax.grad(
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+
+    def lower(fn):
+        return jax.jit(jax.value_and_grad(
             lambda *a: fn(*a).astype(jnp.float32).sum(),
-            argnums=(0, 1, 2, 3)), one_chip, *shapes)
-        assert _kernel_names(text) == {"moe_gmm", "moe_tgmm"}
+            argnums=(0, 1, 2, 3))).lower(*args)
+    for fn in (layer, jax.checkpoint(layer)):
+        lowered = lower(fn)
+        text = lowered.as_text()
+        assert text.count('kernel_name = "moe_combine"') == 1
+        assert text.count("kernel_name = ") == 7
+        calls = _kernel_calls(lowered.compile().as_text())
+        assert set(calls) == {"moe_gmm", "moe_tgmm", "moe_combine"}
+        assert calls["moe_combine"] == 2
 
 
 def test_dsa_attention_compiles(one_chip):
@@ -457,7 +472,8 @@ def test_routed_layer_with_sigmoid_bias_and_shared_expert_compiles(
         one_chip):
     """The same cell's routed layer: 8,192 positions, top 8 of 256
     experts by sigmoid scores and a selection bias, 16 held, the shared
-    expert beside the grouped products (plain XLA: no third kernel)."""
+    expert beside the grouped products in plain XLA (no kernel of its
+    own), the pieces' rows into their tokens' by ``moe_combine``."""
     from cxxnet_tpu.ops import moe_sorted as ms
     P, e, m, total, held, topk = 8192, 2048, 768, 256, 16, 8
 
@@ -475,7 +491,7 @@ def test_routed_layer_with_sigmoid_bias_and_shared_expert_compiles(
         ((total,), jnp.float32), ((held, e, 2 * m), jnp.float32),
         ((held, m, e), jnp.float32), ((2 * m, e), jnp.float32),
         ((e, m), jnp.float32))
-    assert _kernel_names(text) == {"moe_gmm", "moe_tgmm"}
+    assert _kernel_names(text) == {"moe_gmm", "moe_tgmm", "moe_combine"}
 
 
 def test_lfm2_step_fits_the_chip(one_chip):
